@@ -7,12 +7,17 @@ is deterministic and repeated across workers, sessions, and bench runs
 -- so this package wires JAX's persistent XLA compilation cache into
 every execution path and makes its behavior observable:
 
-  - ``enable()``          one idempotent entrypoint that points
-                          ``jax_compilation_cache_dir`` at
-                          ``$DPRF_COMPILE_CACHE_DIR`` (default
-                          ``~/.cache/dprf/xla``, beside the tune cache)
-                          with the persistence thresholds lowered so
-                          our step compiles always persist.  Called
+  - ``enable()``          one idempotent entrypoint that turns the
+                          cache on with the persistence thresholds
+                          lowered so our step compiles always persist.
+                          Where ``$JAX_COMPILATION_CACHE_DIR`` is set
+                          the cache lives there -- JAX reads the
+                          variable itself and no code here sets
+                          another directory; otherwise it lives at ONE
+                          fixed path inside the checkout
+                          (``<repo>/.cache/xla``, git-ignored: the
+                          path is part of the cache key, so a
+                          directory that moves never hits).  Called
                           from the CLI (crack/serve/worker/bench/tune/
                           prewarm), dprf_tpu/bench.py, and the batch
                           autotuner.  Advisory: an unwritable dir or a
@@ -30,12 +35,12 @@ every execution path and makes its behavior observable:
                           image (the ``dprf prewarm`` subcommand; see
                           compilecache/prewarm.py).
 
-Classification: on jaxes with the ``jax_explain_cache_misses`` config
-(``explain_capable``), the observer captures the compiler's own
-per-compile "Persistent compilation cache hit/MISS" log lines -- the
-EXACT classification (ISSUE 15).  The heuristic below stays the
-fallback for windows the watch saw nothing in and for older jaxes: a
-compile that wrote new entries into the cache dir is
+Classification: the observer captures the compiler's own per-compile
+"Persistent compilation cache hit/MISS" log lines -- the EXACT
+classification (ISSUE 15).  The heuristic below stays the fallback for
+windows the watch saw nothing in (every executable already live in
+jax's in-memory cache): a compile that wrote new entries into the
+cache dir is
 a miss (exact -- JAX persists every compile at these thresholds); one
 that wrote nothing and finished under the cold-compile floor
 (``$DPRF_COMPILE_COLD_FLOOR_S``, default 5 s) is a hit.  A no-write
@@ -57,7 +62,14 @@ from typing import Optional
 
 from dprf_tpu.utils import env as envreg
 
-CACHE_DIR_ENV = "DPRF_COMPILE_CACHE_DIR"
+#: JAX's own variable: read by jax.config at import, and by
+#: default_cache_dir() below so both agree on where the cache lives
+CACHE_DIR_ENV = "JAX_COMPILATION_CACHE_DIR"
+#: where the cache lives when the variable is unset: one fixed path
+#: inside the checkout, never under $HOME and never a temp name
+_REPO_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+CHECKOUT_CACHE_DIR = os.path.join(_REPO_ROOT, ".cache", "xla")
 #: kill switch: DPRF_COMPILE_CACHE=0 disables the persistent cache
 DISABLE_ENV = "DPRF_COMPILE_CACHE"
 COLD_FLOOR_ENV = "DPRF_COMPILE_COLD_FLOOR_S"
@@ -71,12 +83,20 @@ COLD_FLOOR_ENV = "DPRF_COMPILE_COLD_FLOOR_S"
 #: seconds to minutes).  5 s splits those populations with headroom.
 DEFAULT_COLD_FLOOR_S = 5.0
 
-_lock = threading.Lock()
-_state: dict = {"dir": None}
-#: exact-classifier log-watch bookkeeping (ISSUE 15): refcounted
-#: install of the jax._src.compiler capture handler, so nested
-#: observers restore the logger's level/propagate exactly once
-_watch_state: dict = {"count": 0, "saved": None}
+#: re-entrant: the log filter takes it too, on whichever thread
+#: the compiler logs from, enable()/disable() included
+_lock = threading.RLock()
+#: "watch": the process-lifetime log watch counting EVERY compile's
+#: persistent-cache hit/miss line while the cache is on
+#: (process_cache_counts), whichever site compiled
+_state: dict = {"dir": None, "watch": None}
+#: exact-classifier log-watch bookkeeping (ISSUE 15): the watches the
+#: jax._src.compiler filter counts into.  "saved" is the logger's own
+#: level to restore when the last watch goes, "passes" the effective
+#: level it had, below which the filter drops records while the logger
+#: sits at DEBUG
+_watch_state: dict = {"watches": [], "saved": logging.NOTSET,
+                      "passes": logging.WARNING}
 
 #: `dprf check` locks analyzer: module-global cache state, written by
 #: enable()/disable() and read from every compile site -- the serve
@@ -87,13 +107,8 @@ GUARDED_BY = {
 
 
 def default_cache_dir() -> str:
-    """$DPRF_COMPILE_CACHE_DIR, or ~/.cache/dprf/xla (deliberately
-    beside the tuning cache: one directory tree to bake into a fleet
-    image carries both the tuned batches and their compiled steps)."""
-    d = envreg.get_path(CACHE_DIR_ENV)
-    if d:
-        return d
-    return os.path.join(os.path.expanduser("~"), ".cache", "dprf", "xla")
+    """$JAX_COMPILATION_CACHE_DIR, or the fixed in-checkout path."""
+    return os.environ.get(CACHE_DIR_ENV) or CHECKOUT_CACHE_DIR
 
 
 def cache_dir() -> Optional[str]:
@@ -107,11 +122,13 @@ def enabled() -> bool:
         return _state["dir"] is not None
 
 
-def enable(dir: Optional[str] = None, log=None) -> Optional[str]:
+def enable(log=None) -> Optional[str]:
     """Turn on the persistent XLA compilation cache; returns the cache
-    directory, or None when disabled/unusable.  Idempotent: re-calls
-    with the same (or default) dir are no-ops; an explicit different
-    dir re-points the cache (tests, ``prewarm --cache-dir``).
+    directory, or None when disabled/unusable.  Idempotent.
+
+    Where ``$JAX_COMPILATION_CACHE_DIR`` is set, JAX has already taken
+    the directory from it and this function sets no other; where it is
+    not, the cache is pointed at the fixed in-checkout path.
 
     The persistence thresholds are lowered to "persist everything":
     the default min-compile-time gate (1 s) would silently drop the
@@ -120,7 +137,8 @@ def enable(dir: Optional[str] = None, log=None) -> Optional[str]:
     """
     if not envreg.get_bool(DISABLE_ENV):
         return None
-    d = os.path.abspath(dir or default_cache_dir())
+    from_env = bool(os.environ.get(CACHE_DIR_ENV))
+    d = os.path.abspath(default_cache_dir())
     with _lock:
         if _state["dir"] == d:
             return d
@@ -134,54 +152,67 @@ def enable(dir: Optional[str] = None, log=None) -> Optional[str]:
             _warn(log, "compile cache dir unwritable; persistent "
                   "compilation cache DISABLED", dir=d, error=str(e))
             return None
-        try:
-            import jax
+        import jax
+        if not from_env:
             jax.config.update("jax_compilation_cache_dir", d)
-            jax.config.update(
-                "jax_persistent_cache_min_compile_time_secs", 0.0)
-            jax.config.update(
-                "jax_persistent_cache_min_entry_size_bytes", -1)
-            # jax materializes its cache object AT MOST ONCE, at the
-            # first compile -- a dir set (or changed) after that is
-            # silently ignored unless the cache is reset.  Without
-            # this, an enable() after any prior jit dispatch in the
-            # process is a no-op that still *reports* enabled.
-            _reset_backend_cache()
-        except Exception as e:   # noqa: BLE001 -- an old jax without
-            # these options must degrade, not kill the job
-            _warn(log, "jax compilation-cache config rejected; "
-                  "persistent compilation cache DISABLED", error=str(e))
-            return None
+        jax.config.update("jax_enable_compilation_cache", True)
+        jax.config.update(
+            "jax_persistent_cache_min_compile_time_secs", 0.0)
+        jax.config.update(
+            "jax_persistent_cache_min_entry_size_bytes", -1)
+        # jax materializes its cache object AT MOST ONCE, at the first
+        # compile -- a setting changed after that is silently ignored
+        # unless the cache is reset.  Without this, an enable() after
+        # any prior jit dispatch in the process is a no-op that still
+        # *reports* enabled.
+        _reset_backend_cache()
         _state["dir"] = d
-        if log is not None:
-            log.info("persistent compile cache enabled", dir=d)
-        return d
+        if _state["watch"] is None:
+            _state["watch"] = watch = _CacheLogWatch()
+        else:
+            watch = None
+    if watch is not None:
+        _watch_install(watch)
+    if log is not None:
+        log.info("persistent compile cache enabled", dir=d,
+                 placed_by=CACHE_DIR_ENV if from_env else "checkout")
+    return d
+
+
+def process_cache_counts() -> dict:
+    """{"cache_hits": n, "cache_misses": m}: every XLA compile of this
+    process since enable(), by the cache layer's own per-compile log
+    line -- observed site or not (lazily compiled steps of workers
+    with their own sweep loops included).  A job whose log shows
+    misses == 0 loaded every executable it ran."""
+    with _lock:
+        w = _state["watch"]
+    return {"cache_hits": w.hits if w else 0,
+            "cache_misses": w.misses if w else 0}
 
 
 def _reset_backend_cache() -> None:
     """Drop jax's in-memory cache OBJECT so the next compile
-    re-initializes it against the current config dir (on-disk entries
-    are untouched)."""
-    try:
-        from jax._src import compilation_cache as _cc
-        _cc.reset_cache()
-    except Exception:   # noqa: BLE001 -- internal API; a jax that
-        # moved it initializes lazily anyway on first-ever compile
-        pass
+    re-initializes it against the current config (on-disk entries are
+    untouched)."""
+    from jax.experimental.compilation_cache import (
+        compilation_cache as _cc)
+    _cc.reset_cache()
 
 
 def disable() -> None:
-    """Undo enable() (tests).  Leaves on-disk entries alone."""
+    """Undo enable() (tests).  Leaves the directory setting and the
+    on-disk entries alone: the cache is switched off, not moved."""
     with _lock:
         if _state["dir"] is None:
             return
-        try:
-            import jax
-            jax.config.update("jax_compilation_cache_dir", None)
-            _reset_backend_cache()
-        except Exception:   # noqa: BLE001
-            pass
+        import jax
+        jax.config.update("jax_enable_compilation_cache", False)
+        _reset_backend_cache()
         _state["dir"] = None
+        watch, _state["watch"] = _state["watch"], None
+    if watch is not None:
+        _watch_remove(watch)
 
 
 def _warn(log, msg: str, **kw) -> None:
@@ -250,64 +281,61 @@ _HIT_MSG = "Persistent compilation cache hit"
 _MISS_MSG = "PERSISTENT COMPILATION CACHE MISS"
 
 
-def explain_capable() -> bool:
-    """Newer-jax capability probe: the ``jax_explain_cache_misses``
-    config option landed alongside the per-compile persistent-cache
-    log lines this classifier captures (0.4.x era).  When absent, the
-    entry-delta + wall-floor heuristic below stays the classifier."""
-    try:
-        import jax
-        return hasattr(jax.config, "jax_explain_cache_misses")
-    except Exception:   # noqa: BLE001 -- jax-less host
-        return False
+class _CacheLogWatch:
+    """Counts of the compiler's per-compile hit/miss log lines while it
+    is installed -- the EXACT classification (one line per XLA compile,
+    emitted by the cache layer itself), replacing the entry-delta +
+    wall-floor guess whenever it saw anything."""
 
-
-class _CacheLogWatch(logging.Handler):
-    """Captures the compiler's per-compile hit/miss log lines for one
-    observed window -- the EXACT classification (one line per XLA
-    compile, emitted by the cache layer itself), replacing the
-    entry-delta + wall-floor guess whenever it saw anything."""
+    __slots__ = ("hits", "misses")
 
     def __init__(self):
-        super().__init__(level=logging.DEBUG)
         self.hits = 0
         self.misses = 0
 
-    def emit(self, record: logging.LogRecord) -> None:
-        msg = record.msg if isinstance(record.msg, str) else \
-            str(record.msg)
-        if _HIT_MSG in msg:
-            self.hits += 1
-        elif _MISS_MSG in msg:
-            self.misses += 1
+
+def _watch_filter(record: logging.LogRecord) -> bool:
+    """The ONE filter on the compiler's logger while any watch is
+    installed: counts the line into every installed watch, then lets
+    through exactly the records the logger's own level would have let
+    through before it was dropped to DEBUG (the hit line logs at DEBUG
+    unless ``jax_log_compiles`` is on).  A filter, not a handler with
+    propagation off: the compiler's WARNING and ERROR records still
+    reach the operator's handlers.  One filter for all watches, because
+    a logger stops at the first filter that rejects a record."""
+    msg = record.msg if isinstance(record.msg, str) else str(record.msg)
+    hit, miss = _HIT_MSG in msg, _MISS_MSG in msg
+    with _lock:
+        if hit or miss:
+            for watch in _watch_state["watches"]:
+                watch.hits += hit
+                watch.misses += miss
+        return record.levelno >= _watch_state["passes"]
 
 
 def _watch_install(watch: _CacheLogWatch) -> None:
-    """Attach a watch to the compiler logger.  The hit line logs at
-    DEBUG unless ``jax_log_compiles`` is on, so the logger is dropped
-    to DEBUG with propagation OFF for the window (the records land in
-    our handler, not on the operator's console); the refcount restores
-    both exactly once when the last nested observer exits."""
+    """Start counting into `watch`; the first one in lowers the
+    compiler logger to DEBUG and attaches the filter."""
     logger = logging.getLogger(_JAX_COMPILER_LOGGER)
     with _lock:
-        if _watch_state["count"] == 0:
-            _watch_state["saved"] = (logger.level, logger.propagate)
-            if logger.getEffectiveLevel() > logging.DEBUG:
+        if not _watch_state["watches"]:
+            _watch_state["saved"] = logger.level
+            _watch_state["passes"] = logger.getEffectiveLevel()
+            if _watch_state["passes"] > logging.DEBUG:
                 logger.setLevel(logging.DEBUG)
-            logger.propagate = False
-        _watch_state["count"] += 1
-    logger.addHandler(watch)
+            logger.addFilter(_watch_filter)
+        _watch_state["watches"].append(watch)
 
 
 def _watch_remove(watch: _CacheLogWatch) -> None:
+    """Stop counting into `watch`; the last one out restores the
+    logger's level and detaches the filter."""
     logger = logging.getLogger(_JAX_COMPILER_LOGGER)
-    logger.removeHandler(watch)
     with _lock:
-        _watch_state["count"] -= 1
-        if _watch_state["count"] == 0 and _watch_state["saved"]:
-            logger.setLevel(_watch_state["saved"][0])
-            logger.propagate = _watch_state["saved"][1]
-            _watch_state["saved"] = None
+        _watch_state["watches"].remove(watch)
+        if not _watch_state["watches"]:
+            logger.removeFilter(_watch_filter)
+            logger.setLevel(_watch_state["saved"])
 
 
 def compile_histogram(registry=None):
@@ -353,12 +381,11 @@ class compile_observer:
     entries, which would misread a hit as a miss.
 
     Classification prefers the EXACT per-compile log lines the cache
-    layer itself emits (``explain_capable`` jaxes; ISSUE 15): a
-    window whose watch saw any line classifies from it alone -- any
-    miss makes the window a miss, hits-only is a hit.  A window the
-    watch saw nothing in (every executable already live in jax's
-    in-memory cache, or an older jax) falls back to the entry-delta +
-    wall-floor heuristic.
+    layer itself emits (ISSUE 15): a window whose watch saw any line
+    classifies from it alone -- any miss makes the window a miss,
+    hits-only is a hit.  A window the watch saw nothing in (every
+    executable already live in jax's in-memory cache) falls back to
+    the entry-delta + wall-floor heuristic.
 
     Attributes after exit: ``seconds``, ``cache``.  Nothing is
     published when the body raises (a failed compile is not a compile
@@ -376,7 +403,7 @@ class compile_observer:
         self._watch: Optional[_CacheLogWatch] = None
 
     def __enter__(self) -> "compile_observer":
-        if enabled() and explain_capable():
+        if enabled():
             self._watch = _CacheLogWatch()
             _watch_install(self._watch)
         self._before = entry_count()
@@ -401,9 +428,9 @@ class compile_observer:
         return False
 
 
-__all__ = ["CACHE_DIR_ENV", "DISABLE_ENV", "COLD_FLOOR_ENV",
-           "DEFAULT_COLD_FLOOR_S", "cache_dir", "classify_compile",
-           "classify_delta", "cold_floor_s", "compile_histogram",
-           "compile_observer", "default_cache_dir", "disable",
-           "enable", "enabled", "entry_count", "explain_capable",
-           "observe_compile"]
+__all__ = ["CACHE_DIR_ENV", "CHECKOUT_CACHE_DIR", "DISABLE_ENV",
+           "COLD_FLOOR_ENV", "DEFAULT_COLD_FLOOR_S", "cache_dir",
+           "classify_compile", "classify_delta", "cold_floor_s",
+           "compile_histogram", "compile_observer",
+           "default_cache_dir", "disable", "enable", "enabled",
+           "entry_count", "observe_compile", "process_cache_counts"]

@@ -346,18 +346,12 @@ def _run_children(specs: List[PrewarmSpec], jobs: int,
                   log=None) -> List[PrewarmResult]:
     import subprocess
 
-    from dprf_tpu import compilecache
     shards = [specs[i::jobs] for i in range(min(jobs, len(specs)))]
     procs = []
     for shard in shards:
         cmd = [sys.executable, "-m", "dprf_tpu", "prewarm", "--jobs",
                "1", "-q", "--spec-json",
                json.dumps([s.as_dict() for s in shard])]
-        if compilecache.cache_dir():
-            # children must write the SAME cache the parent enabled
-            # (an explicit --cache-dir would otherwise be lost: env
-            # resolution in the child picks the default)
-            cmd += ["--cache-dir", compilecache.cache_dir()]
         procs.append(subprocess.Popen(cmd, stdout=subprocess.PIPE,
                                       stderr=subprocess.PIPE, text=True))
     results: List[PrewarmResult] = []

@@ -1,25 +1,18 @@
-"""Shared per-kind Pallas-step fallback for the per-target-sweep
-workers (pdf, 7z): build the kernel step AND force its compile inside
-one try, so both trace-time errors and Mosaic compile failures (the
-SIGABRT/HTTP-500 class — engines.py wraps worker.warmup() for exactly
-this reason) degrade to the XLA step instead of aborting mid-job.
-Silent compile HANGS (TPU_PROBE_LOG_r04 finding 8 / r05 finding 12)
-cannot be caught client-side; risky shapes stay gated off by their
-eligibility predicates until measured."""
+"""Shared per-kind Pallas-step builder for the per-target-sweep
+workers (pdf, 7z, krb5aes): build the kernel step AND force its
+compile at worker construction, so a trace-time error or a Mosaic
+compile failure raises there with the compiler's message -- not
+mid-job, and never as a silent switch to the much slower XLA step.
+Risky shapes stay gated off by their eligibility predicates until
+measured."""
 
 from __future__ import annotations
 
 
-def kind_kernel_step(name: str, build, warmup):
+def kind_kernel_step(build, warmup):
     """build() -> lazily-jitted step; warmup(step) must invoke it once
     (hard_sync'd) to force the device compile.  Returns the warmed
-    step, or None for the caller's XLA fallback."""
-    try:
-        step = build()
-        warmup(step)
-        return step
-    except Exception as e:  # noqa: BLE001 -- any compiler/runtime error
-        from dprf_tpu.utils.logging import DEFAULT as log
-        log.warn(f"{name} kernel failed to build/compile; using the "
-                 "XLA step", error=str(e))
-        return None
+    step."""
+    step = build()
+    warmup(step)
+    return step

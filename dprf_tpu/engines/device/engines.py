@@ -52,8 +52,8 @@ class GenericWorkerFactories:
         PALLAS KERNEL as the per-shard compute (parallel/sharded.
         make_sharded_kernel_mask_step) -- the single-chip
         make_mask_worker routing ladder at mesh scale, with the XLA
-        sharded runtime as the not-eligible / build-failure fallback.
-        Bulk lists (probe_eligible) stay on the XLA probe-table
+        sharded runtime for jobs that are not kernel-eligible.  A
+        kernel that fails to build or compile raises.  Bulk lists (probe_eligible) stay on the XLA probe-table
         compute; the in-kernel blocked probe covers 2..MAX_TARGETS
         and needs an oracle to verify its sentinel survivors."""
         from dprf_tpu.ops.pallas_mask import kernel_eligible, pallas_mode
@@ -75,19 +75,13 @@ class GenericWorkerFactories:
                      "verify probe survivors; using the XLA pipeline",
                      engine=self.name, targets=len(targets))
         elif mode is not None:
-            try:
-                worker = ShardedMaskWorker(
-                    self, gen, targets, mesh,
-                    batch_per_device=batch_per_device,
-                    hit_capacity=hit_capacity, oracle=oracle,
-                    kernel=dict(mode))
-                worker.warmup()
-                return worker
-            except Exception as e:
-                log.warn("sharded kernel compute failed to "
-                         "build/compile; falling back to the XLA "
-                         "pipeline", engine=self.name,
-                         error=f"{type(e).__name__}: {e}")
+            worker = ShardedMaskWorker(
+                self, gen, targets, mesh,
+                batch_per_device=batch_per_device,
+                hit_capacity=hit_capacity, oracle=oracle,
+                kernel=dict(mode))
+            worker.warmup()
+            return worker
         return ShardedMaskWorker(self, gen, targets, mesh,
                                  batch_per_device=batch_per_device,
                                  hit_capacity=hit_capacity, oracle=oracle)
@@ -172,9 +166,10 @@ class JaxEngineBase(GenericWorkerFactories, DeviceHashEngine, HashEngine):
         stays on the generic fused XLA pipeline).
 
         A kernel that fails to build or compile (a Mosaic lowering
-        regression, an unexpected shape) must not abort the job: the
-        construction + warmup compile is wrapped, and on failure the
-        job degrades to the generic XLA pipeline with a loud warning.
+        regression, an unexpected shape) raises with the compiler's
+        message: the XLA pipeline is 1-2 orders of magnitude slower,
+        so a silent switch to it would be a wrong result that still
+        "passes".  The warmup here forces the compile at construction.
         """
         from dprf_tpu.ops.pallas_mask import kernel_eligible, pallas_mode
         from dprf_tpu.targets import probe as probe_mod
@@ -205,17 +200,11 @@ class JaxEngineBase(GenericWorkerFactories, DeviceHashEngine, HashEngine):
             sub = tune_mod.lookup_tuned_value(
                 self.name, "sub", attack="mask",
                 extras={"hit_cap": int(hit_capacity)})
-            try:
-                worker = PallasMaskWorker(self, gen, targets, batch=batch,
-                                          hit_capacity=hit_capacity,
-                                          oracle=oracle, sub=sub, **mode)
-                worker.warmup()
-                return worker
-            except Exception as e:
-                log.warn("pallas kernel failed to build/compile; "
-                         "falling back to the XLA pipeline",
-                         engine=self.name,
-                         error=f"{type(e).__name__}: {e}")
+            worker = PallasMaskWorker(self, gen, targets, batch=batch,
+                                      hit_capacity=hit_capacity,
+                                      oracle=oracle, sub=sub, **mode)
+            worker.warmup()
+            return worker
         from dprf_tpu.runtime.worker import DeviceMaskWorker
         return DeviceMaskWorker(self, gen, targets, batch=batch,
                                 hit_capacity=hit_capacity, oracle=oracle)
@@ -224,8 +213,8 @@ class JaxEngineBase(GenericWorkerFactories, DeviceHashEngine, HashEngine):
                              hit_capacity: int, oracle=None):
         """Fused wordlist+rules worker (config 3's on-device expansion).
         Single-target jobs whose rule set the in-VMEM interpreter
-        kernel supports get the Pallas path (ops/pallas_rules.py),
-        with the XLA pipeline as build-failure fallback."""
+        kernel supports get the Pallas path (ops/pallas_rules.py);
+        a kernel build/compile failure raises."""
         from dprf_tpu.ops.pallas_mask import pallas_mode
         from dprf_tpu.ops.pallas_rules import kernel_rules_eligible
         from dprf_tpu.runtime.worker import DeviceWordlistWorker
@@ -234,18 +223,12 @@ class JaxEngineBase(GenericWorkerFactories, DeviceHashEngine, HashEngine):
         if (mode is not None
                 and kernel_rules_eligible(self.name, gen, len(targets))):
             from dprf_tpu.runtime.worker import PallasWordlistWorker
-            try:
-                worker = PallasWordlistWorker(
-                    self, gen, targets, batch=batch,
-                    hit_capacity=hit_capacity, oracle=oracle, **mode)
-                worker.warmup()
-                return worker
-            except Exception as e:
-                log.warn("rules kernel failed to build/compile; "
-                         "falling back to the XLA pipeline",
-                         engine=self.name,
-                         error=f"{type(e).__name__}: {e}")
-        elif mode is not None:
+            worker = PallasWordlistWorker(
+                self, gen, targets, batch=batch,
+                hit_capacity=hit_capacity, oracle=oracle, **mode)
+            worker.warmup()
+            return worker
+        if mode is not None:
             log.info("rules kernel not eligible for this job; "
                      "using the XLA pipeline", engine=self.name,
                      targets=len(targets))

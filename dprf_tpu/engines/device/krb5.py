@@ -201,9 +201,8 @@ class Krb5MaskWorker(PhpassMaskWorker):
 
 class PallasKrb5MaskWorker(PhpassMaskWorker):
     """Mask sweep over the RC4 prefilter KERNEL (ops/pallas_krb5.py):
-    the XLA step's per-lane serial RC4 swaps measured 21 kH/s on chip
-    (TPU_RESULTS_r04 krb5-20); the kernel's sublane layout makes them
-    vector ops.  Target scalars are runtime, so one compiled kernel
+    the XLA step's RC4 swaps are per-lane serial gathers; the
+    kernel's sublane layout makes them vector ops.  Target scalars are runtime, so one compiled kernel
     serves the whole hashlist (both msg types).  Sweep loop, rescan,
     and the hit contract come from PhpassMaskWorker."""
 
@@ -221,8 +220,8 @@ class PallasKrb5MaskWorker(PhpassMaskWorker):
             gen, batch, hit_capacity, interpret=interpret)
 
     def warmup(self) -> None:
-        """One launch so Mosaic compile failures surface in the
-        factory (which then falls back to the XLA step), not mid-job."""
+        """One launch so a Mosaic compile failure raises in the
+        factory, not mid-job."""
         import jax.numpy as jnp
 
         from dprf_tpu.utils.sync import hard_sync
@@ -233,25 +232,20 @@ class PallasKrb5MaskWorker(PhpassMaskWorker):
 def maybe_pallas_krb5_worker(engine, gen, targets, batch: int,
                              hit_capacity: int, oracle):
     """PallasKrb5MaskWorker when the job is kernel-eligible (warmed so
-    compile failures surface here), else None -> XLA-step worker."""
+    a compile failure raises here, at construction), else None ->
+    XLA-step worker."""
     from dprf_tpu.ops import pallas_krb5
     from dprf_tpu.ops.pallas_mask import pallas_mode
-    from dprf_tpu.utils.logging import DEFAULT as log
 
     mode = pallas_mode()
     if mode is None or not pallas_krb5.krb5_kernel_eligible(gen):
         return None
-    try:
-        worker = PallasKrb5MaskWorker(
-            engine, gen, targets, batch=batch,
-            hit_capacity=hit_capacity, oracle=oracle,
-            interpret=mode.get("interpret", False))
-        worker.warmup()
-        return worker
-    except Exception as e:  # noqa: BLE001 -- compiler errors
-        log.warn("krb5 kernel failed to build/compile; using the "
-                 "XLA step", engine=engine.name, error=str(e))
-        return None
+    worker = PallasKrb5MaskWorker(
+        engine, gen, targets, batch=batch,
+        hit_capacity=hit_capacity, oracle=oracle,
+        interpret=mode.get("interpret", False))
+    worker.warmup()
+    return worker
 
 
 class Krb5WordlistWorker(PhpassWordlistWorker):

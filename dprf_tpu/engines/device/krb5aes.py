@@ -202,10 +202,10 @@ from dprf_tpu.engines.device.phpass import (PhpassMaskWorker,  # noqa: E402
 def kdf_kernel_enabled(interpret: bool) -> bool:
     """The PBKDF2 kernel route is DEFAULT-OFF on real hardware until a
     recorded planted-crack run exists (DPRF_KRB5AES_KERNEL=1 enables
-    it for the measuring session): the shape matches the
-    hardware-proven PMKID kernel, but this repo records first compiles
-    of new kernel variants before trusting them (TPU_PROBE_LOG_r05
-    finding 12's lesson).  Interpret mode (tests) is ungated."""
+    it for the measuring session): the shape matches the PMKID
+    kernel, but a new kernel variant is not trusted before its first
+    compile and run on a chip are on record.  Interpret mode (tests)
+    is ungated."""
     from dprf_tpu.utils import env as envreg
     return interpret or envreg.get_bool("DPRF_KRB5AES_KERNEL")
 
@@ -249,7 +249,8 @@ class Krb5AesMaskWorker(PhpassMaskWorker):
     one-block PBKDF2 budget) gets a HOST pseudo-step (full oracle over
     the unit) instead of demoting the whole job: mixed hashlists keep
     every eligible target on the device path.  On TPU the PBKDF2 runs
-    on the fused Pallas kernel (warmup-gated, XLA fallback)."""
+    on the fused Pallas kernel where DPRF_KRB5AES_KERNEL enables it
+    (compiled at construction; a compile failure raises)."""
 
     def __init__(self, engine, gen, targets, batch: int = 1 << 13,
                  hit_capacity: int = 64, oracle=None):
@@ -289,12 +290,11 @@ class Krb5AesMaskWorker(PhpassMaskWorker):
                     return s
 
                 step = kind_kernel_step(
-                    "krb5aes pbkdf2", build,
+                    build,
                     lambda s, tw=tw: hard_sync(s(
                         jnp.zeros((gen.length,), jnp.int32),
                         jnp.int32(0), tw)))
-                if step is not None and "kdf" in built:
-                    kdf_cache[kind] = built["kdf"]
+                kdf_cache[kind] = built["kdf"]
             if step is None:
                 fb = make_krb5aes_filter(
                     t.params, getattr(engine, "iterations", 4096))

@@ -256,7 +256,6 @@ class PdfMaskWorker(PhpassMaskWorker):
                     from dprf_tpu.utils.sync import hard_sync
                     scalars = target_scalars(t)
                     step = kind_kernel_step(
-                        "pdf",
                         lambda: pallas_pdf.make_pdf_crack_step(
                             gen, batch, *kind,
                             hit_capacity=hit_capacity,

@@ -86,8 +86,8 @@ def _group_targets(targets: Sequence[Target]):
 def _pmkid_match(engine, targets, by_essid, twords, key, valid):
     """Per-lane match scan, memory FLAT in target count: accumulates a
     match count and the first matching target index per lane instead of
-    a [T, B] mask (VERDICT r2 weak #4 -- a 1k-target list at batch 2^14
-    must not build a 16M-lane buffer).
+    a [T, B] mask (a 1k-target list at batch 2^14 must not build a
+    16M-lane buffer).
 
     A lane matching >= 2 targets (same passphrase cracking two captures)
     reports only its first target here; the worker resolves the rest
@@ -147,10 +147,10 @@ def make_sharded_pmkid_crack_step(engine: JaxPmkidEngine,
         lanes[n_dev, cap] super-batch-global, tpos[n_dev, cap],
         n_multi_total)."""
     import jax as _jax
-    from jax import lax
+    from jax import lax, shard_map
     from jax.sharding import PartitionSpec as P
 
-    from dprf_tpu.parallel.mesh import SHARD_AXIS, shard_map
+    from dprf_tpu.parallel.mesh import SHARD_AXIS
 
     flat = gen.flat_charsets
     length = gen.length
@@ -222,14 +222,21 @@ class PallasPmkidWorker:
                        for n in lens}
         self.batch = self.stride = next(iter(self._steps.values())).batch
 
+    #: the steps are always the compiled kernel (describe_worker):
+    #: maybe_pallas_pmkid_worker builds this worker on a real chip only
+    _interpret = False
+
     def warmup(self) -> None:
+        from dprf_tpu.compilecache import compile_observer
         from dprf_tpu.utils.sync import hard_sync
         base = jnp.asarray(self.gen.digits(0), dtype=jnp.int32)
         by_len = {a[0]: a for a in self._targs}
-        for n, (el, essid, msg5, tgt) in by_len.items():
-            hard_sync(self._steps[n](base, jnp.int32(0),
-                                     jnp.int32(self.engine.iterations),
-                                     essid, msg5, tgt))
+        with compile_observer(self.engine.name) as obs:
+            for n, (el, essid, msg5, tgt) in by_len.items():
+                hard_sync(self._steps[n](
+                    base, jnp.int32(0), jnp.int32(self.engine.iterations),
+                    essid, msg5, tgt))
+        self.compile_seconds, self.compile_cache = obs.seconds, obs.cache
 
     def process(self, unit) -> list:
         from dprf_tpu.runtime.worker import CpuWorker, Hit
@@ -307,19 +314,13 @@ def maybe_pallas_pmkid_worker(engine, gen, targets, batch: int,
     if mode is None or mode.get("interpret", False):
         # TPU-only: the 14 statically-unrolled SHA-1 compressions
         # don't compile on XLA:CPU in reasonable time (the sha256
-        # kernel rule); hardware proof in TPU_RESULTS_r04
+        # kernel rule)
         return None
-    try:
-        worker = PallasPmkidWorker(engine, gen, targets, batch=batch,
-                                   hit_capacity=hit_capacity,
-                                   oracle=oracle)
-        worker.warmup()
-        return worker
-    except Exception as e:
-        log.warn("pmkid pallas kernel failed to build/compile; "
-                 "falling back to the XLA step",
-                 error=f"{type(e).__name__}: {e}")
-        return None
+    worker = PallasPmkidWorker(engine, gen, targets, batch=batch,
+                               hit_capacity=hit_capacity,
+                               oracle=oracle)
+    worker.warmup()
+    return worker
 
 
 class PmkidDeviceWorker(DeviceMaskWorker):

@@ -224,9 +224,8 @@ class _SaltedWorkerBase:
     #: wide fusion bounds (see runtime/worker.py MaskWorkerBase): a
     #: wide-capable subclass overrides _wide_invoke to rebuild its
     #: per-target step at inner*stride lanes -- one device program per
-    #: ~100 batches instead of per batch, the same link-amortization
-    #: the Pallas mask workers use (scan-wrapping is not an option on
-    #: this backend; TPU_PROBE_LOG_r04.md finding 8).
+    #: ~100 batches instead of per batch, the same dispatch
+    #: amortization the Pallas mask workers use.
     SUPER_CAP = 256
     SUPER_MIN = 8
 
@@ -245,8 +244,7 @@ class _SaltedWorkerBase:
             cap = self._wide_cap = (
                 0 if not envreg.get_bool("DPRF_SUPERSTEP")
                 else max_inner(self.stride, self.SUPER_CAP))
-        if getattr(self, "_wide_disabled", False) or \
-                cap < self.SUPER_MIN or \
+        if cap < self.SUPER_MIN or \
                 remaining_strides < self.SUPER_MIN:
             return 0
         return min(cap, 1 << (remaining_strides.bit_length() - 1))
@@ -482,33 +480,22 @@ class PallasSaltedMaskWorker(SaltedMaskWorker):
         """Wide kernel step at sbatch lanes, cached per (salt length,
         sbatch) -- salt/target stay RUNTIME scalars, so one wide
         program per salt length serves the whole hashlist, exactly
-        like the per-batch kernels.  A build failure degrades this
-        worker to per-batch dispatch (never a scan wrapper)."""
+        like the per-batch kernels.  jit/Mosaic compile lazily, so a
+        wide program the compiler refuses raises at its first call,
+        with the compiler's message."""
         from dprf_tpu.ops import pallas_ext
         slen, salt, tgt = self._kargs[ti]
         key = (slen, sbatch)
-        try:
-            step = self._wide_ksteps.get(key)
-            if step is None:
-                scale = max(1, sbatch // self.batch)
-                cap = max(self.hit_capacity,
-                          min(self.hit_capacity * scale, 1024))
-                step = self._wide_ksteps[key] = \
-                    pallas_ext.make_salted_crack_step(
-                        self._algo, self.engine.order, self.gen,
-                        sbatch, slen, cap, interpret=self._interpret)
-            # the CALL stays inside the try: jit/Mosaic compile
-            # lazily, so a wide program that exceeds VMEM surfaces
-            # HERE, not in the factory -- it must degrade this worker
-            # to per-batch dispatch, not kill the WorkUnit
-            return step(base, n_valid, salt, tgt)
-        except Exception as e:  # noqa: BLE001 -- compiler errors
-            from dprf_tpu.utils.logging import DEFAULT as log
-            self._wide_disabled = True
-            log.warn("wide salted kernel failed to build/compile; "
-                     "falling back to per-batch dispatch",
-                     sbatch=sbatch, error=str(e))
-            return None
+        step = self._wide_ksteps.get(key)
+        if step is None:
+            scale = max(1, sbatch // self.batch)
+            cap = max(self.hit_capacity,
+                      min(self.hit_capacity * scale, 1024))
+            step = self._wide_ksteps[key] = \
+                pallas_ext.make_salted_crack_step(
+                    self._algo, self.engine.order, self.gen,
+                    sbatch, slen, cap, interpret=self._interpret)
+        return step(base, n_valid, salt, tgt)
 
 
 #: device base class -> kernel core algo for the extended salted
@@ -530,9 +517,9 @@ def _kernel_algo(engine) -> str | None:
 def maybe_pallas_salted_worker(engine, gen, targets, batch: int,
                                hit_capacity: int, oracle):
     """PallasSaltedMaskWorker when the job is kernel-eligible (warmed,
-    so compile failures surface here), else None -- the factory then
+    so a compile failure raises here), else None -- the factory then
     builds the XLA-step worker.  Mirrors JaxEngineBase's pallas
-    selection + fallback pattern."""
+    selection."""
     from dprf_tpu.ops import pallas_ext
     from dprf_tpu.ops.pallas_mask import pallas_mode
     from dprf_tpu.utils.logging import DEFAULT as log
@@ -548,19 +535,12 @@ def maybe_pallas_salted_worker(engine, gen, targets, batch: int,
                  "using the XLA pipeline", engine=engine.name,
                  targets=len(targets))
         return None
-    try:
-        worker = PallasSaltedMaskWorker(
-            engine, gen, targets, algo, batch=batch,
-            hit_capacity=hit_capacity, oracle=oracle,
-            interpret=mode.get("interpret", False))
-        worker.warmup()
-        return worker
-    except Exception as e:
-        log.warn("salted pallas kernel failed to build/compile; "
-                 "falling back to the XLA pipeline",
-                 engine=engine.name,
-                 error=f"{type(e).__name__}: {e}")
-        return None
+    worker = PallasSaltedMaskWorker(
+        engine, gen, targets, algo, batch=batch,
+        hit_capacity=hit_capacity, oracle=oracle,
+        interpret=mode.get("interpret", False))
+    worker.warmup()
+    return worker
 
 
 class ShardedSaltedMaskWorker(SaltedMaskWorker):

@@ -99,10 +99,10 @@ def make_scrypt_wordlist_step(gen, word_batch: int, n: int, r: int,
 def make_sharded_scrypt_mask_step(gen, mesh, batch_per_device: int,
                                   n: int, r: int, p: int,
                                   hit_capacity: int = 64):
-    from jax import lax
+    from jax import lax, shard_map
     from jax.sharding import PartitionSpec as P
 
-    from dprf_tpu.parallel.mesh import SHARD_AXIS, shard_map
+    from dprf_tpu.parallel.mesh import SHARD_AXIS
 
     flat = gen.flat_charsets
     length = gen.length

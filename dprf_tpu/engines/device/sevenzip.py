@@ -242,7 +242,6 @@ class SevenZipMaskWorker(PhpassMaskWorker):
                 from dprf_tpu.utils.sync import hard_sync
                 tw = _crc_word(t)
                 step = kind_kernel_step(
-                    "7z KDF",
                     lambda t=t: _make_kernel_step(
                         gen, batch, t.params, hit_capacity,
                         interpret=mode.get("interpret", False)),

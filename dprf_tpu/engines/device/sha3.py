@@ -195,18 +195,12 @@ class _KeccakDeviceMixin(GenericWorkerFactories):
                      "using the XLA pipeline", engine=self.name,
                      targets=len(targets))
         elif mode is not None:
-            try:
-                w = PallasKeccakMaskWorker(self, gen, targets,
-                                           batch=batch,
-                                           hit_capacity=hit_capacity,
-                                           oracle=oracle, **mode)
-                w.warmup()
-                return w
-            except Exception as e:   # build/compile failure -> XLA
-                log.warn("keccak kernel failed to build/compile; "
-                         "falling back to the XLA pipeline",
-                         engine=self.name,
-                         error=f"{type(e).__name__}: {e}")
+            w = PallasKeccakMaskWorker(self, gen, targets,
+                                       batch=batch,
+                                       hit_capacity=hit_capacity,
+                                       oracle=oracle, **mode)
+            w.warmup()
+            return w
         return KeccakMaskWorker(self, gen, targets, batch=batch,
                                 hit_capacity=hit_capacity, oracle=oracle)
 

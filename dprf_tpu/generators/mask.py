@@ -146,8 +146,8 @@ class MaskGenerator(CandidateGenerator):
         # few contiguous runs (every builtin: ?l/?u/?d/?b/?a are one
         # run, ?s is four) decodes with a handful of vector
         # compare/selects instead of a per-position batch-sized
-        # gather -- the gather is the measured XLA mask bottleneck on
-        # TPU (BASELINE.md).  None = too many runs (e.g.
+        # gather -- the gather is the XLA mask path's bottleneck on
+        # TPU.  None = too many runs (e.g.
         # markov-scrambled order): keep the gather.
         self._segments = tuple(
             segs if len(segs) <= MAX_SEGMENTS else None

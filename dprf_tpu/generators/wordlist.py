@@ -118,6 +118,8 @@ class WordlistRulesGenerator(CandidateGenerator):
         else:
             words, skipped = load_words(wordlist_path, max_len)
             gen = cls(words, rules, max_len=max_len)
+        #: which reader built the tables (the job log names it)
+        gen.native_reader = loaded is not None
         gen.n_skipped_long = skipped
         return gen
 

@@ -7,14 +7,19 @@ tables the device consumes at memory bandwidth instead of a Python
 per-line loop.
 
 The shared library is compiled on first use with the system compiler
-and cached next to the sources (keyed on source mtime).  Everything
-degrades gracefully: if no compiler is available the callers fall back
-to the pure-Python implementations.
+and cached next to the sources, under a name made from the SOURCE'S
+CONTENT (``libdprf_native-<sha256[:12]>.so``, git-ignored).  A copy
+of the disk and a fresh checkout of git therefore run the same code:
+a library built from other source has another name and is never
+loaded, whatever its mtime says.  Everything degrades gracefully: if
+no compiler is available the callers fall back to the pure-Python
+implementations.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import tempfile
@@ -24,18 +29,25 @@ import numpy as np
 
 _DIR = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_DIR, "wordlist.cpp")
-_LIB = os.path.join(_DIR, "libdprf_native.so")
 
 _lib: Optional[ctypes.CDLL] = None
 _tried = False
 
 
+def _lib_path() -> str:
+    """The library's path for the source as it is on disk now."""
+    with open(_SRC, "rb") as fh:
+        digest = hashlib.sha256(fh.read()).hexdigest()[:12]
+    return os.path.join(_DIR, f"libdprf_native-{digest}.so")
+
+
 def _compile() -> Optional[str]:
-    """(Re)build the shared library if stale; returns its path or None."""
+    """Build the shared library for this source unless it is already
+    there; returns its path or None."""
     try:
-        if (os.path.exists(_LIB)
-                and os.path.getmtime(_LIB) >= os.path.getmtime(_SRC)):
-            return _LIB
+        lib = _lib_path()
+        if os.path.exists(lib):
+            return lib
         for cc in ("c++", "g++", "cc", "gcc"):
             # build to a temp name then rename: concurrent importers
             # must never dlopen a half-written .so
@@ -46,8 +58,8 @@ def _compile() -> Optional[str]:
                     [cc, "-O3", "-shared", "-fPIC", "-o", tmp, _SRC],
                     capture_output=True, timeout=120)
                 if res.returncode == 0:
-                    os.replace(tmp, _LIB)
-                    return _LIB
+                    os.replace(tmp, lib)
+                    return lib
             except (OSError, subprocess.TimeoutExpired):
                 continue
             finally:

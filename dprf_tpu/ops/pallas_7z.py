@@ -17,8 +17,7 @@ layout); the kernel output is uint32[B, 8] key states consumed by
 the engine's `_check_from_state`.
 
 The group loop is `lax.fori_loop` with an 8-register carry — the
-small-carry shape proven to lower (TPU_PROBE_LOG_r04 finding 2 /
-the PBKDF2 kernel); the bpg compress calls inside the body are
+small-carry shape the PBKDF2 kernel uses too; the bpg compress calls inside the body are
 statically unrolled.
 """
 

@@ -1,11 +1,8 @@
 """Pallas EksBlowfish advance kernel: vector-rate S-box gathers.
 
-VERDICT r3 #4 asked for a real Pallas bcrypt attempt before accepting
-the XLA form's throughput as the chip's ceiling.  The XLA batched form
-(ops/blowfish.py) lowers each Feistel round's four per-candidate S-box
-reads to per-lane SERIAL gathers -- measured 0.29 H/s at cost 12
-(TPU_RESULTS_r03/r04), ~80M scalar gathers/s, far below any
-bandwidth or ALU limit.
+The XLA batched form (ops/blowfish.py) lowers each Feistel round's
+four per-candidate S-box reads to per-lane SERIAL gathers, far below
+any bandwidth or ALU limit.
 
 The kernel reshapes the problem so the gather is the hardware's native
 per-sublane dynamic gather (the same `take_along_axis` shape the Bloom
@@ -49,11 +46,10 @@ from dprf_tpu.ops import blowfish as bf_ops
 from dprf_tpu.utils import env as envreg
 
 #: candidates (sublanes) per grid cell.  VMEM per cell is
-#: SUBC * (4 KB S + padded P/key) ~= SUBC * 5 KB.  The r4 hardware
-#: sweep (tools/tpu_case.py pallaseks cases, B=64): 19.6 / 11.9 /
-#: 10.1 / 7.7 ms per cost round at SUBC 8/16/32/64 -- per-candidate
-#: op count is SUBC-independent, so the gain is loop/control overhead
-#: amortization; 64 is the measured winner (~320 KB VMEM).
+#: SUBC * (4 KB S + padded P/key) ~= SUBC * 5 KB (~320 KB at 64).
+#: The per-candidate op count is SUBC-independent, so a larger cell
+#: amortizes loop/control overhead; 64 won an SUBC 8..64 sweep on an
+#: earlier installation and has not been re-swept on this one.
 SUBC = envreg.get_int("DPRF_BCRYPT_SUBC")
 
 
@@ -220,35 +216,29 @@ def make_pallas_eks_advance(batch: int, interpret: bool = False,
 
 
 def make_best_eks_advance(batch: int):
-    """The fastest available ChunkedEks advance for this batch: the
-    Pallas kernel when the kernel path is on (measured 8x the XLA form
-    at cost 12 on TPU v5 lite -- 1.59/2.32 H/s at B=64/512 vs 0.29,
-    TPU_RESULTS_r04 session3 -- and per-round time scales linearly
-    with batch where the XLA gathers serialize), else the donating
-    jitted XLA form.
+    """(advance, impl) for this batch's ChunkedEks: impl "pallas", the
+    compiled Pallas kernel, when the kernel path is on a real chip
+    (per-round time scales linearly with batch there, where the XLA
+    form's gathers serialize); else impl "xla", the donating jitted
+    XLA form.  The workers publish impl (describe_worker), so a job's
+    log says which one ran.
 
     Mosaic raises lowering errors at the first CALL, not at build, so
-    the kernel is proven here with a 1-round run on zero state before
-    being returned -- a lowering failure falls back to the XLA advance
-    instead of crashing mid-job (the r4 dev loop hit exactly this with
-    an unsupported dynamic_slice)."""
+    the kernel is run here for 1 round on zero state before being
+    returned: a lowering failure raises at worker construction with
+    the compiler's message, not mid-job."""
     from dprf_tpu.ops.pallas_mask import pallas_mode
     mode = pallas_mode()
     # real Mosaic only: the interpret path exists for the dedicated
     # equivalence test (make_pallas_eks_advance directly); a 2**cost
     # chain through interpreted Pallas would be slower than the oracle
     if mode is not None and not mode.get("interpret", False):
-        try:
-            adv = make_pallas_eks_advance(batch)
-            Z = jnp.zeros
-            out = adv(Z((batch, 18), jnp.uint32),
-                      Z((batch, 1024), jnp.uint32),
-                      Z((batch, 18), jnp.uint32),
-                      Z((18,), jnp.uint32), jnp.int32(1))
-            jax.device_get(out[0][0, 0])     # force the compile+run
-            return adv
-        except Exception as e:   # lowering failure -> proven XLA form
-            from dprf_tpu.utils.logging import DEFAULT as log
-            log.warn("pallas eks kernel failed to build/lower; using "
-                     "the XLA advance", error=f"{type(e).__name__}: {e}")
-    return jax.jit(bf_ops.eks_rounds, donate_argnums=(0, 1))
+        adv = make_pallas_eks_advance(batch)
+        Z = jnp.zeros
+        out = adv(Z((batch, 18), jnp.uint32),
+                  Z((batch, 1024), jnp.uint32),
+                  Z((batch, 18), jnp.uint32),
+                  Z((18,), jnp.uint32), jnp.int32(1))
+        jax.device_get(out[0][0, 0])     # force the compile+run
+        return adv, "pallas"
+    return jax.jit(bf_ops.eks_rounds, donate_argnums=(0, 1)), "xla"

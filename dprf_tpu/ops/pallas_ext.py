@@ -1,10 +1,9 @@
 """Extended fused mask kernels: salted, nested, and mysql41 variants.
 
-VERDICT r3 #3: the hand-written Pallas kernel path covered only the
-four unsalted single-block engines, leaving every other fast engine on
-the XLA pipeline whose per-byte charset gather runs ~300x slower than
-the kernel decode (12.6 MH/s vs 4.1 GH/s measured on TPU v5 lite).
-The families this module covers all consume one or two 64-byte blocks
+The hand-written kernels of pallas_mask cover only the unsalted
+single-block engines; every other fast engine would otherwise stay on
+the XLA pipeline, whose per-byte charset gather runs orders of
+magnitude slower than the kernel decode.  The families this module covers all consume one or two 64-byte blocks
 of the exact same compression cores, so they reuse pallas_mask's
 decode machinery with a different message build / digest chain:
 
@@ -24,14 +23,14 @@ VPU ops per worst-case Markov ?a position would have cost up to 2x.
 - **nested** ``outer(hex(inner(password)))`` (hashcat 2600/4500/4400/
   4700/20800/20700): the inner digest is hex-encoded in registers
   (nibble->char arithmetic, no gather) and fed to the outer
-  compression.  Single- and multi-target (Bloom) compare both work,
+  compression.  Single- and multi-target (probe) compare both work,
   so these slot into the existing PallasMaskWorker unchanged.
 - **mysql41** sha1(sha1($p)) over the RAW inner digest (hashcat 300):
   the inner digest words ARE the outer block words.
 
 The kernel bodies follow pallas_mask's contract exactly -- pure
 (pid, base digits, n_valid, [runtime scalars]) -> (count, hit_lane)
--- and reuse its packed (8, 128) output trick, tile reducers, Bloom
+-- and reuse its packed (8, 128) output trick, tile reducers, probe
 prefilter, and eligibility plumbing (pallas_mask.kernel_eligible and
 the step factories dispatch here for non-CORES engine names).
 """
@@ -46,12 +45,11 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from dprf_tpu.ops.pallas_mask import (CORES, MAX_TARGETS, SET_SIZE, SUB,
-                                      _pack_message, bloom_found,
-                                      bloom_tables,
-                                      check_batch,
+from dprf_tpu.ops.pallas_mask import (CORES, MAX_TARGETS, SUB,
+                                      _pack_message, check_batch,
                                       decode_candidate_bytes,
-                                      position_tables,
+                                      kernel_probe_rows,
+                                      position_tables, probe_block_found,
                                       mask_supported, reduce_tile_hits,
                                       reduce_tile_maybes)
 
@@ -179,12 +177,14 @@ def _inner_big_endian(name: str) -> bool:
 
 def _build_ext_body(name: str, radices, seg_tables, length: int,
                     target, sub: int, order: Optional[str] = None,
-                    salt_len: int = 0, has_lut: bool = False):
+                    salt_len: int = 0, has_lut: bool = False,
+                    probe=None):
     """Kernel math as a pure function.  Two shapes:
 
     - nested/mysql41 (order None): (pid, base, n_valid[, tables])
-      -> (count, hit_lane); target is trace-time (uint32[W] single or
-      uint32[N, W] Bloom multi), exactly like pallas_mask.
+      -> (count, hit_lane); target is trace-time (uint32[W] single, or
+      uint32[N, W] multi with `probe` the kernel_probe_rows geometry
+      and `tables` its rows), exactly like pallas_mask.
     - salted (order 'ps'/'sp'): (pid, base, n_valid, salt, tgt)
       -> (count, hit_lane); salt bytes (int32[>=salt_len]) and target
       words (uint32[W]) are RUNTIME scalar refs, salt_len is static.
@@ -202,7 +202,6 @@ def _build_ext_body(name: str, radices, seg_tables, length: int,
         target = np.asarray(target)
         multi = target.ndim == 2 and target.shape[0] > 1
         if multi:
-            n_sets = -(-target.shape[0] // SET_SIZE)
             tw = None
         else:
             tw = [int(w) for w in target.reshape(-1)]
@@ -243,7 +242,8 @@ def _build_ext_body(name: str, radices, seg_tables, length: int,
             for got, want in zip(digest, tw):
                 found = found & (got == jnp.uint32(want))
         else:
-            found = bloom_found(digest, rest[0], valid, n_sets, shape)
+            found = probe_block_found(digest, rest[0], valid, *probe,
+                                      shape)
         count = jnp.sum(found.astype(jnp.int32))
         hit_lane = jnp.max(jnp.where(found, lane, -1))
         return count, hit_lane
@@ -268,8 +268,13 @@ def make_ext_pallas_fn(name: str, gen, target_words, batch: int,
         raise ValueError(f"{name} mask job not ext-kernel-eligible")
     seg_tables, luts_np = position_tables(gen.charsets)
     has_lut = luts_np is not None
+    probe = None
+    if multi:
+        tables, block_bits, k, n_grp, _ = kernel_probe_rows(target_words)
+        probe = (block_bits, k, n_grp)
     body = _build_ext_body(name, gen.radices, seg_tables, gen.length,
-                           target_words, sub, has_lut=has_lut)
+                           target_words, sub, has_lut=has_lut,
+                           probe=probe)
 
     def kernel(base_ref, nvalid_ref, *rest):
         out_ref = rest[-1]
@@ -284,7 +289,6 @@ def make_ext_pallas_fn(name: str, gen, target_words, batch: int,
         pl.BlockSpec((1,), lambda i: (0,), memory_space=pltpu.SMEM),
     ]
     if multi:
-        tables = bloom_tables(target_words)
         in_specs.append(pl.BlockSpec((tables.shape[0], 128),
                                      lambda i: (0, 0)))
     if has_lut:
@@ -392,8 +396,8 @@ def make_ext_multi_crack_step(name: str, gen, target_words, batch: int,
                               hit_capacity: int = 64,
                               rescan_capacity: int = 16,
                               interpret: bool = False):
-    """Multi-target (Bloom) nested/mysql41 crack step; contract of
-    pallas_mask.make_pallas_multi_crack_step."""
+    """Multi-target nested/mysql41 crack step; contract and prefilter
+    of pallas_mask.make_pallas_multi_crack_step."""
     tile = SUB * 128
     fn = make_ext_pallas_fn(name, gen, target_words, batch,
                             interpret=interpret)
@@ -455,10 +459,14 @@ def emulate_ext_kernel(name: str, gen, target_words, batch: int,
     else:
         target_words = np.asarray(target_words)
         multi = target_words.ndim == 2 and target_words.shape[0] > 1
-        body = _build_ext_body(name, gen.radices, seg_tables, gen.length,
-                               target_words, sub, has_lut=has_lut)
+        probe = None
         if multi:
-            tables = jnp.asarray(bloom_tables(target_words))
+            rows, block_bits, k, n_grp, _ = kernel_probe_rows(target_words)
+            tables = jnp.asarray(rows)
+            probe = (block_bits, k, n_grp)
+        body = _build_ext_body(name, gen.radices, seg_tables, gen.length,
+                               target_words, sub, has_lut=has_lut,
+                               probe=probe)
         extra = (tables,) if multi else ()
     if has_lut:
         extra = extra + (jnp.asarray(luts_np),)

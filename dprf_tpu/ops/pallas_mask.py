@@ -59,12 +59,11 @@ from dprf_tpu.ops import sha256 as sha256_ops
 from dprf_tpu.ops import sha512 as sha512_ops
 
 #: sublane count per grid cell; TILE = SUB * 128 candidate lanes.
-#: DPRF_PALLAS_SUB overrides for tuning (tools/tpu_session.py sweeps
-#: it on real hardware).  The round-3 sweep on TPU v5 lite
-#: (TPU_RESULTS_r03.json) measured the md5 kernel at 0.91/1.75/2.97/
-#: 3.97/4.14 GH/s for SUB 8/16/32/64/128: bigger tiles amortize the
-#: per-grid-cell scalar work, so the packed-output format's maximum
-#: (128) is the default.
+#: DPRF_PALLAS_SUB overrides for tuning (`dprf tune --rungs sub`
+#: sweeps it).  Bigger tiles amortize the per-grid-cell scalar work --
+#: the md5 kernel's rate rose monotonically over SUB 8..128 in a sweep
+#: on an earlier installation -- so the packed-output format's
+#: maximum (128) is the default.
 SUB = envreg.get_int("DPRF_PALLAS_SUB")
 TILE = SUB * 128
 #: charsets needing more piecewise segments than MAX_SEGMENTS use the
@@ -74,20 +73,9 @@ TILE = SUB * 128
 from dprf_tpu.generators.mask import (MAX_SEGMENTS,  # noqa: E402,F401
                                       charset_segments, segment_mux)
 
-# -- multi-target Bloom prefilter parameters --------------------------------
-#: probes per target set; each probe consumes 12 digest bits (7 bits
-#: word index into a 128-word bitmap row + 5 bits bit index), so 8
-#: probes use 96 bits -- available in every CORES digest (>= 128 bits).
-K_PROBES = 8
-#: targets per Bloom set.  Fill factor per 4096-bit set bitmap is
-#: <= 1024/4096 = 0.25, so a non-matching lane passes all 8 probes of
-#: one set with p <= 0.25**8 ~ 1.5e-5: ~0.06 false maybe-lanes per
-#: 4096-lane tile per set.  False maybes cost one host oracle hash
-#: (single) or one 4096-candidate tile rescan (collision) -- both
-#: negligible at those rates.
-SET_SIZE = 1024
-#: hard cap on kernel-path targets (gather cost grows one probe row per
-#: set: ceil(N/1024) * 8 gathers per tile).
+# -- multi-target prefilter parameters ---------------------------------------
+#: hard cap on kernel-path targets: past it the capped probe bitmap
+#: (KERNEL_PROBE_GROUPS) no longer reaches its false-positive budget.
 MAX_TARGETS = 8192
 
 #: 128-block groups the in-kernel blocked-probe bitmap may span.  The
@@ -331,43 +319,6 @@ def kernel_eligible(engine_name: str, gen, n_targets: int) -> bool:
     return gen.length <= max_len and mask_supported(gen.charsets)
 
 
-def bloom_tables(twords: np.ndarray) -> np.ndarray:
-    """Target digest words uint32[N, W] -> Bloom bitmap rows
-    uint32[n_sets * K_PROBES, 128].
-
-    Set s, probe p lives in row s*K_PROBES + p: a 4096-bit bitmap over
-    128 uint32 words, with one bit set per target in the set, keyed by
-    12 bits of the target's own digest (targets ARE uniform hash
-    outputs, so no extra hashing is needed).
-    """
-    N = twords.shape[0]
-    if N > MAX_TARGETS:
-        raise ValueError(f"kernel path supports <= {MAX_TARGETS} targets")
-    n_sets = -(-N // SET_SIZE)
-    T = np.zeros((n_sets * K_PROBES, 128), np.uint32)
-    for s in range(n_sets):
-        chunk = twords[s * SET_SIZE:(s + 1) * SET_SIZE]
-        for p in range(K_PROBES):
-            o = 12 * p
-            j, sh = divmod(o, 32)
-            bits = (chunk[:, j] >> np.uint32(sh)).astype(np.uint64)
-            if sh > 20:
-                bits |= chunk[:, j + 1].astype(np.uint64) << np.uint64(32 - sh)
-            bits = (bits & np.uint64(0xFFF)).astype(np.uint32)
-            np.bitwise_or.at(T[s * K_PROBES + p], bits >> 5,
-                             np.uint32(1) << (bits & np.uint32(31)))
-    return T
-
-
-def _probe_bits(digest, p: int):
-    """12 Bloom-probe bits [12p, 12p+12) of the digest bit string."""
-    j, sh = divmod(12 * p, 32)
-    bits = digest[j] >> jnp.uint32(sh)
-    if sh > 20:
-        bits = bits | (digest[j + 1] << jnp.uint32(32 - sh))
-    return bits & jnp.uint32(0xFFF)
-
-
 def kernel_probe_rows(twords: np.ndarray, fp: Optional[float] = None):
     """Target digest words uint32[N, W] -> the PR 14 blocked-Bloom
     probe bitmap in the kernel's lane-major layout.
@@ -391,6 +342,8 @@ def kernel_probe_rows(twords: np.ndarray, fp: Optional[float] = None):
     if fp is None:
         fp = envreg.get_float("DPRF_PALLAS_PROBE_FP")
     n = int(twords.shape[0])
+    if n > MAX_TARGETS:
+        raise ValueError(f"kernel path supports <= {MAX_TARGETS} targets")
     max_bits = KERNEL_PROBE_GROUPS * 128 * probe_mod.BLOCK_BITS
     m_bits, k, fp_est = probe_mod.kernel_bloom_geometry(n, fp, max_bits)
     words = probe_mod.bloom_fill(np.ascontiguousarray(twords), m_bits, k)
@@ -484,30 +437,6 @@ def decode_candidate_bytes(radices, seg_tables, length: int, base, carry,
     return byts
 
 
-def bloom_found(digest, tables, valid, n_sets: int, shape):
-    """Bloom prefilter shared by the kernel bodies: a lane survives if
-    it passes ALL K_PROBES of ANY target set.  Real hits always
-    survive (their probe bits come from the matching target's own
-    digest); false maybes are rare enough that the caller verifies
-    single maybes with one host oracle hash and exactly rescans
-    collided tiles (see reduce_tile_maybes)."""
-    probes = []
-    for p in range(K_PROBES):
-        bits = _probe_bits(digest, p)
-        probes.append(((bits >> jnp.uint32(5)).astype(jnp.int32),
-                       (bits & jnp.uint32(31))))
-    found = jnp.zeros(shape, jnp.bool_)
-    for s in range(n_sets):
-        m_set = valid
-        for p, (idx7, bit5) in enumerate(probes):
-            row = jnp.broadcast_to(tables[s * K_PROBES + p][None, :],
-                                   shape)
-            word = jnp.take_along_axis(row, idx7, axis=1)
-            m_set = m_set & (((word >> bit5) & jnp.uint32(1)) == 1)
-        found = found | m_set
-    return found
-
-
 def _pack_message(byts, length: int, shape, big_endian: bool,
                   widen_utf16: bool, block_words: int = 16):
     """Candidate bytes -> the padded single-block message words
@@ -540,10 +469,10 @@ def _build_kernel_body(engine_name: str, radices, seg_tables, length: int,
     SHA-256 graph in reasonable time, so correctness tests drive this
     body op-by-op).
 
-    probe: None for the per-set Bloom prefilter, or the
-    (block_bits, k, n_grp) geometry from kernel_probe_rows -- the
-    multi-target compare then runs the blocked probe (`tables` holds
-    the probe rows) and every survivor is a sentinel maybe.
+    probe: the (block_bits, k, n_grp) geometry from kernel_probe_rows
+    for a multi-target job -- the compare runs the blocked probe
+    (`tables` holds the probe rows) and every survivor is a maybe the
+    caller verifies on the host; None for a single target.
 
     An `offset` scalar (the sharded/superstep window start) shifts
     both the decoded keyspace index and the validity bound, so ONE
@@ -554,7 +483,9 @@ def _build_kernel_body(engine_name: str, radices, seg_tables, length: int,
     target = np.asarray(target)
     multi = target.ndim == 2 and target.shape[0] > 1
     if multi:
-        n_sets = -(-target.shape[0] // SET_SIZE)
+        if probe is None:
+            raise ValueError("a multi-target kernel needs its probe "
+                             "geometry (kernel_probe_rows)")
         tw = None
     else:
         # plain python ints: jnp scalars here would be captured closure
@@ -585,11 +516,9 @@ def _build_kernel_body(engine_name: str, radices, seg_tables, length: int,
             found = valid
             for got, want in zip(digest, tw):
                 found = found & (got == jnp.uint32(want))
-        elif probe is not None:
+        else:
             found = probe_block_found(digest, tables, valid, *probe,
                                       shape)
-        else:
-            found = bloom_found(digest, tables, valid, n_sets, shape)
         count = jnp.sum(found.astype(jnp.int32))
         # single-hit extraction: max lane among hits (-1 if none); the
         # caller rescans any tile whose count exceeds 1.
@@ -605,8 +534,8 @@ def _build_kernel(engine_name: str, radices, seg_tables, length: int,
                   probe=None):
     """pallas_call kernel wrapper around the pure body.  Optional
     positional inputs follow (base, n_valid) in a fixed order: the
-    window offset scalar (sharded/superstep callers), then the Bloom
-    or probe tables (multi-target), then the charset LUT rows (masks
+    window offset scalar (sharded/superstep callers), then the probe
+    rows (multi-target), then the charset LUT rows (masks
     with positions past the segment budget -- pallas_call forbids
     captured vector constants, so the LUT is a real input)."""
     body = _build_kernel_body(engine_name, radices, seg_tables, length,
@@ -642,23 +571,19 @@ def emulate_mask_kernel(engine_name: str, gen, target_words: np.ndarray,
     grid cell; returns (counts int32[G,1], hit_lanes int32[G,1]) with
     the exact layout pallas_call produces.  Test/validation vehicle.
 
-    offset / probe_fp mirror make_mask_pallas_fn's with_offset and
-    probe-compare modes, so the sharded kernel bodies validate through
-    the same eager loop off-TPU."""
+    offset / probe_fp mirror make_mask_pallas_fn's, so the sharded
+    kernel bodies validate through the same eager loop off-TPU."""
     tile = sub * 128
     if batch % tile:
         raise ValueError(f"batch {batch} not a multiple of tile {tile}")
     target_words = np.asarray(target_words)
     multi = target_words.ndim == 2 and target_words.shape[0] > 1
-    probe = None
-    if multi and probe_fp is not None:
+    tables = probe = None
+    if multi:
         rows, block_bits, k, n_grp, _ = kernel_probe_rows(
             target_words, probe_fp)
         tables = jnp.asarray(rows)
         probe = (block_bits, k, n_grp)
-    else:
-        tables = (jnp.asarray(bloom_tables(target_words))
-                  if multi else None)
     seg_tables, luts_np = position_tables(gen.charsets)
     luts = jnp.asarray(luts_np) if luts_np is not None else None
     body = _build_kernel_body(engine_name, gen.radices, seg_tables,
@@ -686,16 +611,15 @@ def make_mask_pallas_fn(engine_name: str, gen, target_words: np.ndarray,
     `batch`-lane sweep.  batch must be a multiple of sub*128.
 
     target_words uint32[W] (single target: counts are exact hit counts)
-    or uint32[N, W] (multi target: counts are Bloom maybe-counts; see
+    or uint32[N, W] (multi target: the compare is the blocked-probe
+    bitmap, kernel_probe_rows, sized for probe_fp -- default
+    DPRF_PALLAS_PROBE_FP -- and counts are maybe-counts; see
     reduce_tile_maybes for the caller contract).
 
     with_offset adds the traced window-start scalar (SMEM, like
     n_valid): candidates decode from base + offset + lane and validity
     checks against the WINDOW n_valid, so sharded shards and superstep
-    iterations reuse one compiled kernel.  probe_fp switches the
-    multi-target compare to the blocked-probe bitmap
-    (kernel_probe_rows): counts become probe maybe-counts at that fp
-    budget."""
+    iterations reuse one compiled kernel."""
     tile = sub * 128
     grid = check_batch(batch, sub)
     target_words = np.asarray(target_words)
@@ -707,12 +631,10 @@ def make_mask_pallas_fn(engine_name: str, gen, target_words: np.ndarray,
     seg_tables, luts_np = position_tables(gen.charsets)
     has_lut = luts_np is not None
     probe = None
-    if multi and probe_fp is not None:
+    if multi:
         tables, block_bits, k, n_grp, _ = kernel_probe_rows(
             target_words, probe_fp)
         probe = (block_bits, k, n_grp)
-    elif multi:
-        tables = bloom_tables(target_words)
     kernel = _build_kernel(engine_name, gen.radices, seg_tables,
                            gen.length, target_words, sub, multi=multi,
                            has_lut=has_lut, with_offset=with_offset,
@@ -820,12 +742,18 @@ def make_pallas_multi_crack_step(engine_name: str, gen,
      n_collided, collided_tiles int32[rescan_capacity]).
 
     Contract (see PallasMaskWorker): each maybe lane holds >= 0
-    candidates that passed the Bloom prefilter and must be verified by
-    ONE host oracle hash; each collided tile (>= 2 maybes) must be
-    exactly rescanned over its TILE-candidate range.  n_single >
-    hit_capacity or n_collided > rescan_capacity means the whole batch
-    needs the exact rescan (astronomically rare at the Bloom FP rates
-    documented at SET_SIZE).
+    candidates that passed the in-kernel prefilter and must be
+    verified by ONE host oracle hash; each collided tile (>= 2 maybes)
+    must be exactly rescanned over its TILE-candidate range.
+    n_single > hit_capacity or n_collided > rescan_capacity means the
+    whole batch needs the exact rescan.
+
+    The prefilter is the blocked-probe bitmap at DPRF_PALLAS_PROBE_FP
+    (kernel_probe_rows), the compare the sharded kernel step uses:
+    with 1,000 uniform targets it passes about 1e-6 of all lanes
+    (counted on the CPU: 16 of 16,777,216), which at the production
+    tile (16,384 lanes) leaves collided tiles and window-buffer
+    overflows rare enough for a 2^33-candidate window to finish.
 
     with_offset / sub: as make_pallas_mask_crack_step (loop-superstep
     fusion and the tune tile rung)."""
@@ -864,7 +792,7 @@ def make_pallas_multi_crack_step(engine_name: str, gen,
 
 def reduce_tile_maybes(counts: jnp.ndarray, hit_lanes: jnp.ndarray,
                        hit_capacity: int, rescan_capacity: int, tile: int):
-    """Per-tile Bloom maybe-counts -> (n_single, maybe_lanes,
+    """Per-tile probe maybe-counts -> (n_single, maybe_lanes,
     n_collided, collided_tiles) for the multi-target worker."""
     from dprf_tpu.ops import compare as cmp_ops
 
@@ -902,13 +830,19 @@ def make_shard_mask_compute(engine_name: str, gen,
     sharded superstep's only host traffic is the base digit vector.
 
     Single target: found marks exactly-one-hit tiles; payload is tpos
-    0.  Multi target (2..MAX_TARGETS): the compare is the blocked
-    PR 14 probe bitmap (kernel_probe_rows) and every surviving lane
-    comes back SENTINEL-tagged (payload == n_targets, out of range) --
-    the workers' lane decode verifies each with one oracle hash.  Any
-    tile holding 2+ hits/maybes can only report one lane, so the
-    count is inflated past hit_capacity and the workers' existing
-    overflow redrive re-covers the window exactly."""
+    0; a tile holding 2+ hits can only report one lane, so the count
+    is inflated past hit_capacity and the workers' existing overflow
+    redrive re-covers the window exactly.  Multi target
+    (2..MAX_TARGETS): the compare is the blocked PR 14 probe bitmap
+    (kernel_probe_rows) and every surviving lane comes back
+    SENTINEL-tagged (payload == n_targets, out of range) -- the
+    workers' lane decode verifies each with one oracle hash.  A tile
+    holding 2+ maybes comes back tagged n_targets + 1 with the TILE'S
+    first lane, and the worker rescans that one tile on the host: at
+    the probe's false-positive rate a collided tile turns up every few
+    hundred batches, and redriving down to a stride-wide host rescan
+    (n_dev x batch candidates on the oracle) for each would never
+    end."""
     if engine_name not in CORES:
         raise ValueError(f"{engine_name}: sharded kernel computes "
                          "cover the CORES engines only")
@@ -920,10 +854,7 @@ def make_shard_mask_compute(engine_name: str, gen,
     sentinel = int(target_words.shape[0]) if multi else 0
     fn = make_mask_pallas_fn(
         engine_name, gen, target_words, batch_per_device, sub=sub,
-        interpret=interpret, with_offset=True,
-        probe_fp=(probe_fp if probe_fp is not None
-                  else envreg.get_float("DPRF_PALLAS_PROBE_FP"))
-        if multi else None)
+        interpret=interpret, with_offset=True, probe_fp=probe_fp)
     tile_starts = jnp.arange(grid, dtype=jnp.int32) * tile
 
     def compute(offset, base_digits, n_valid):
@@ -932,6 +863,14 @@ def make_shard_mask_compute(engine_name: str, gen,
             jnp.reshape(n_valid, (1,)).astype(jnp.int32),
             jnp.reshape(offset, (1,)).astype(jnp.int32))
         c = counts[:, 0]
+        if multi:
+            found = c >= 1
+            collided = c > 1
+            rel = offset + tile_starts + jnp.where(collided, 0,
+                                                   hit_lanes[:, 0])
+            payload = jnp.where(collided, sentinel + 1,
+                                sentinel).astype(jnp.int32)
+            return found, payload, rel, jnp.sum(found.astype(jnp.int32))
         found = c == 1
         rel = offset + tile_starts + hit_lanes[:, 0]
         payload = jnp.full((grid,), sentinel, jnp.int32)

@@ -9,8 +9,7 @@ chain in VMEM/registers per candidate lane:
   mask decode -> one-block HMAC key states (K^ipad / K^opad) ->
   two PBKDF2 blocks of `iterations` HMAC-SHA1 rounds (the fori_loop
   carries 10 digest-word registers -- small carries DO lower, unlike
-  the big SoA tuples that crash the backend compiler, see
-  TPU_PROBE_LOG_r04) -> PMK -> PMKID = HMAC(PMK, "PMK Name"|AP|STA)
+  big SoA tuples) -> PMK -> PMKID = HMAC(PMK, "PMK Name"|AP|STA)
   -> compare.
 
 Per-target runtime inputs (SMEM scalars): ESSID bytes (length static

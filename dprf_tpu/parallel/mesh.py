@@ -19,20 +19,6 @@ from jax.sharding import Mesh
 SHARD_AXIS = "candidates"
 
 
-def shard_map(f, mesh, in_specs, out_specs, check_vma: bool = True):
-    """Version-portable shard_map: ``jax.shard_map`` where it exists
-    (jax >= 0.6), else ``jax.experimental.shard_map.shard_map`` with
-    ``check_vma`` translated to its older ``check_rep`` spelling.  All
-    sharded steps route through here so an installed-jax skew breaks
-    ONE function, not fifteen call sites."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, check_rep=check_vma)
-
-
 def make_mesh(n_devices: Optional[int] = None,
               devices: Optional[Sequence] = None) -> Mesh:
     """Build the 1-D keyspace mesh over `n_devices` (default: all)."""
@@ -67,16 +53,7 @@ def init_multihost(coordinator: Optional[str] = None,
     already initialized (idempotent -- safe to call from the CLI on
     every invocation).
     """
-    import jax as _jax
-
-    is_init = getattr(_jax.distributed, "is_initialized", None)
-    if is_init is None:
-        # jax < 0.5 has no is_initialized(); the client handle on the
-        # internal global state is the same answer
-        def is_init():
-            from jax._src import distributed as _dist
-            return getattr(_dist.global_state, "client", None) is not None
-    if is_init():
+    if jax.distributed.is_initialized():
         return False      # already initialized: idempotent no-op
     kwargs = {}
     if coordinator is not None:
@@ -85,5 +62,5 @@ def init_multihost(coordinator: Optional[str] = None,
         kwargs["num_processes"] = num_processes
     if process_id is not None:
         kwargs["process_id"] = process_id
-    _jax.distributed.initialize(**kwargs)
+    jax.distributed.initialize(**kwargs)
     return True

@@ -40,11 +40,11 @@ from typing import Callable, Optional
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import PartitionSpec as P
 
 from dprf_tpu.ops import compare as cmp_ops
-from dprf_tpu.parallel.mesh import SHARD_AXIS, shard_map
+from dprf_tpu.parallel.mesh import SHARD_AXIS
 
 
 def _append_hits(carry, found, payload, rel, capacity: int,

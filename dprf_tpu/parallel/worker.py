@@ -24,8 +24,9 @@ from typing import Optional, Sequence
 import numpy as np
 
 from dprf_tpu.engines.base import HashEngine, Target
-from dprf_tpu.runtime.worker import (Hit, MaskWorkerBase, PendingUnit,
-                                     WordlistWorkerBase, word_cover_range)
+from dprf_tpu.runtime.worker import (CpuWorker, Hit, MaskWorkerBase,
+                                     PendingUnit, WordlistWorkerBase,
+                                     word_cover_range)
 from dprf_tpu.runtime.workunit import WorkUnit
 from dprf_tpu.telemetry import coverage
 
@@ -45,21 +46,8 @@ def shard_super_cap(default: int = 256) -> int:
 
 
 class _ShardedSuperstepMixin:
-    """Superstep dispatch + ahead-of-time compile shared by the
-    sharded workers (one degradation policy, one prewarm path)."""
-
-    def _superstep_dispatch(self, inner: int, *args):
-        """One superstep dispatch, or None if its program will not
-        build -- the degradation target is per-batch dispatch (the
-        program the factory already warmed), never a third shape."""
-        try:
-            return self.step.superstep(inner)(*args)
-        except Exception as e:        # noqa: BLE001 -- compiler errors
-            from dprf_tpu.utils.logging import DEFAULT as log
-            self._super_disabled = True
-            log.warn("sharded superstep failed to build; falling back "
-                     "to per-batch dispatch", inner=inner, error=str(e))
-            return None
+    """Ahead-of-time compile shared by the sharded workers (one
+    prewarm path)."""
 
     def _aot_chunks(self) -> int:
         """Per-batch chunks this job's whole keyspace could fill --
@@ -117,8 +105,9 @@ class ShardedMaskWorker(_ShardedSuperstepMixin, MaskWorkerBase):
     (parallel/sharded.make_sharded_kernel_mask_step): candidates
     generate, hash, and compare(+probe) in VMEM, the host ships one
     digit vector per superstep window.  Multi-target kernel hits come
-    back SENTINEL-tagged (in-kernel blocked-probe survivors), so an
-    oracle engine is required to verify them."""
+    back SENTINEL-tagged (in-kernel blocked-probe survivors: one
+    oracle hash each; a tile with 2+ survivors is rescanned whole), so
+    an oracle engine is required to verify them."""
 
     def __init__(self, engine, gen, targets: Sequence[Target], mesh,
                  batch_per_device: int = 1 << 18, hit_capacity: int = 64,
@@ -157,14 +146,14 @@ class ShardedMaskWorker(_ShardedSuperstepMixin, MaskWorkerBase):
             else:
                 twords = np.asarray(tgt)
             sub = kernel.get("sub") or SUB
+            self._interpret = bool(kernel.get("interpret", False))
             tile = sub * 128
             batch_per_device = max(tile,
                                    (batch_per_device // tile) * tile)
             self.mesh = mesh
             self.step = make_sharded_kernel_mask_step(
                 engine.name, gen, twords, mesh, batch_per_device,
-                hit_capacity, sub=sub,
-                interpret=bool(kernel.get("interpret", False)),
+                hit_capacity, sub=sub, interpret=self._interpret,
                 probe_fp=kernel.get("probe_fp"))
         self.super_batch = self.stride = self.step.super_batch
         #: instance override of MaskWorkerBase.SUPER_CAP: the sharded
@@ -182,16 +171,15 @@ class ShardedMaskWorker(_ShardedSuperstepMixin, MaskWorkerBase):
         queued = []
         flag = None
         pos = unit.start
-        while not getattr(self, "_super_disabled", False):
+        while True:
             inner = self._super_inner((unit.end - pos) // self.stride)
             if inner < 2:
                 break
             window = inner * self.stride
             base = jnp.asarray(self.gen.digits(pos), dtype=jnp.int32)
-            result = self._superstep_dispatch(inner, base,
-                                              jnp.int32(window))
-            if result is None:
-                break                      # degraded to per-batch
+            result = self._call_fused(
+                ("sshard", inner), self.step.superstep(inner), base,
+                jnp.int32(window))
             f = self._batch_flag(result)
             flag = f if flag is None else flag + f
             queued.append(("sshard", (pos, window), result))
@@ -212,6 +200,12 @@ class ShardedMaskWorker(_ShardedSuperstepMixin, MaskWorkerBase):
                           unit=unit.unit_id, kind="batch")
         if flag is not None and hasattr(flag, "copy_to_host_async"):
             flag.copy_to_host_async()
+        if queued and not hasattr(self, "out_devices"):
+            #: ids of the devices that hold the sharded step's output
+            #: buffers (describe_worker): one per mesh device, or the
+            #: mesh is not what ran
+            self.out_devices = sorted(
+                s.device.id for s in queued[0][2][1].addressable_shards)
         return PendingUnit(self, unit, queued, flag)
 
     def process(self, unit: WorkUnit) -> list[Hit]:
@@ -249,9 +243,28 @@ class ShardedMaskWorker(_ShardedSuperstepMixin, MaskWorkerBase):
         lanes_np = np.asarray(lanes)
         tpos_np = np.asarray(tpos)
         hits: list[Hit] = []
+        # kernel multi-target compute: payload n_targets + 1 marks a
+        # COLLIDED tile (2+ probe survivors, one reportable lane) by
+        # its first lane -- rescan exactly that tile on the oracle
+        tile = getattr(self.step, "tile", 0) if self.multi else 0
+        rescan = (tpos_np == len(self.targets) + 1) & (lanes_np >= 0) \
+            if tile else np.zeros_like(lanes_np, bool)
         for d in range(lanes_np.shape[0]):
-            hits.extend(self._decode_lanes(bstart, lanes_np[d], tpos_np[d]))
+            hits.extend(self._decode_lanes(
+                bstart, np.where(rescan[d], -1, lanes_np[d]), tpos_np[d]))
+        for lane in lanes_np[rescan]:
+            hits.extend(self._rescan_tile(bstart + int(lane), unit))
         return hits
+
+    def _rescan_tile(self, start: int, unit: WorkUnit) -> list[Hit]:
+        """Exact host rescan of ONE collided kernel tile (clipped to
+        the unit), noted as deliberate re-coverage."""
+        end = min(start + self.step.tile, unit.end)
+        if end <= start:
+            return []
+        coverage.note("rescan", start, end, unit=unit.unit_id)
+        return CpuWorker(self.oracle, self.gen, self.targets).process(
+            WorkUnit(-1, start, end - start))
 
 
 class ShardedCombinatorWorker(ShardedMaskWorker):
@@ -318,15 +331,14 @@ class ShardedWordlistWorker(_ShardedSuperstepMixin, WordlistWorkerBase):
         queued = []
         flag = None
         ws = w_start
-        while not getattr(self, "_super_disabled", False):
+        while True:
             inner = self._super_inner((w_end - ws) // self.super_words)
             if inner < 2:
                 break
             nw = inner * self.super_words
-            result = self._superstep_dispatch(inner, jnp.int32(ws),
-                                              jnp.int32(nw))
-            if result is None:
-                break                      # degraded to per-window
+            result = self._call_fused(
+                ("wshard", inner), self.step.superstep(inner),
+                jnp.int32(ws), jnp.int32(nw))
             f = self._batch_flag(result)
             flag = f if flag is None else flag + f
             queued.append(("wshard", (ws, nw), result))
@@ -364,8 +376,7 @@ class ShardedWordlistWorker(_ShardedSuperstepMixin, WordlistWorkerBase):
         must stay int32, and a window covers words * n_rules lanes)."""
         from dprf_tpu.ops.superstep import max_inner
         from dprf_tpu.utils import env as envreg
-        if getattr(self, "_super_disabled", False) or \
-                not envreg.get_bool("DPRF_SUPERSTEP"):
+        if not envreg.get_bool("DPRF_SUPERSTEP"):
             return 0
         cap = max_inner(self.stride, self.SUPER_CAP)
         if remaining_chunks < self.SUPER_MIN or cap < self.SUPER_MIN:

@@ -15,7 +15,7 @@ from __future__ import annotations
 import os
 from typing import Optional
 
-from dprf_tpu.telemetry.perf import PHASES, roofline_fraction
+from dprf_tpu.telemetry.perf import PHASES
 from dprf_tpu.telemetry.snapshot import load_snapshots, telemetry_path
 from dprf_tpu.telemetry.trace import load_trace, trace_path
 
@@ -340,8 +340,13 @@ def build_report(session_path: str) -> Optional[dict]:
             "trace_hs": thr["trace_hs"],
             "telemetry_hs": thr["telemetry_hs"],
             "candidates": thr["candidates"],
-            "roofline_frac": (roofline_fraction(engine, rate)
-                              if engine and rate else None),
+            # the gauge the run itself published: only the process
+            # that held the chip knows which chip's band applies
+            "roofline_frac": next(
+                (v.get("value") for v in
+                 _metric_values(last, "dprf_roofline_frac")
+                 if (v.get("labels") or {}).get("engine") == engine
+                 and v.get("value")), None),
         },
         "phases": _phase_stats(spans, sample_scale=sample_scale),
         "busy": _busy_by_worker(spans),

@@ -321,10 +321,13 @@ class Coordinator:
                 self._h_unit.observe(unit_s)
                 self._m_cands.inc(unit.length, engine=self.spec.engine,
                                   device=self.spec.device)
-                if interval > 0:
-                    # live roofline distance from the drain rate
+                if interval > 0 and self.spec.device == "jax":
+                    # live roofline distance from the drain rate (the
+                    # device path runs in THIS process, so its chip
+                    # is the local one)
                     perf_mod.publish_roofline(
                         self.spec.engine, unit.length / interval,
+                        perf_mod.local_device_kind(),
                         registry=self._registry)
                 # submit-to-resolve time feeds the adaptive unit sizer;
                 # it includes up to PIPELINE_DEPTH-1 units of queue

@@ -742,6 +742,7 @@ class CoordinatorState:
                         perf_mod.publish_roofline(
                             job.spec.get("engine", "?"),
                             unit.length / elapsed,
+                            self.health.device_kind(wid),
                             registry=self.registry)
             if self.on_progress:
                 done, total = self.scheduler.progress()
@@ -1895,6 +1896,8 @@ def worker_loop(client: CoordinatorClient, worker, worker_id: str,
                if sender is not None and sender.error is not None
                else None)
         payload = {"engine": eng_name, "device": dev,
+                   "device_kind": (perf_mod.local_device_kind()
+                                   if dev == "jax" else None),
                    "chips": _chip_count(),
                    "depth": pipe.depth,
                    "queue": len(pipe),

@@ -78,9 +78,10 @@ RATE_ALPHA = 0.3
 #: fields are the worker's device-memory totals (telemetry/devstats
 #: summary; ISSUE 13) -- how the coordinator sees fleet HBM headroom
 #: without a second RPC.
-PAYLOAD_KEYS = ("engine", "device", "chips", "depth", "queue",
-                "rate_hs", "error", "hbm_in_use", "hbm_limit",
-                "hbm_peak", "profile_ts", "profile_trigger")
+PAYLOAD_KEYS = ("engine", "device", "device_kind", "chips", "depth",
+                "queue", "rate_hs", "error", "hbm_in_use",
+                "hbm_limit", "hbm_peak", "profile_ts",
+                "profile_trigger")
 MAX_PAYLOAD_STR = 200
 
 #: lock-discipline declaration (`dprf check` locks analyzer): observe
@@ -338,6 +339,13 @@ class HealthRegistry:
                         "ts": ts,
                         "trigger": w.payload.get("profile_trigger")}
             return out
+
+    def device_kind(self, worker: str) -> Optional[str]:
+        """The chip kind this worker's last heartbeat reported (what
+        keys its roofline band), or None before its first beat."""
+        with self._lock:
+            w = self._workers.get(worker)
+            return w.payload.get("device_kind") if w else None
 
     def mem_by_worker(self) -> dict:
         """{worker: hbm bytes in use} from the heartbeat payloads

@@ -70,12 +70,26 @@ SAMPLE_ENV = "DPRF_PERF_SAMPLE"
 #: noisy; the gauge should read like a rate, not a jitter plot)
 ROOFLINE_ALPHA = 0.3
 
-#: chip int32 issue band (ops/s) -- the bracketed VPU model in
-#: BASELINE.md: 1024 lanes x ~1.5 GHz x 2-4 int32 ops/lane/cycle
-CHIP_INT_OPS_BAND = (3.0e12, 6.0e12)
+#: int32 issue band (ops/s) of ONE chip, keyed by jax's device_kind.
+#: A guess, not a measurement: 1024 lanes x ~1.5 GHz x 2-4 int32
+#: ops/lane/cycle (the VPU issue width is unpublished); measuring the
+#: peak is ROADMAP S4.  A kind that is not in this table has no
+#: roofline: nothing is published for it, never another chip's band.
+CHIP_INT_OPS_BANDS = {"TPU v5 lite": (3.0e12, 6.0e12)}
 
-#: HAND roofline models (BASELINE.md tables: decode + pack + rounds +
-#: compare) -- DEMOTED to a cross-check by ISSUE 13: the live model is
+
+def local_device_kind() -> str:
+    """device_kind of this process's first JAX device.  It initialises
+    the backend, so only processes that run device work may call it
+    (the local crack loop, bench, a worker's profile analysis) --
+    never the serve coordinator, which learns each worker's kind from
+    its heartbeat."""
+    import jax
+    return jax.devices()[0].device_kind
+
+
+#: HAND roofline models (decode + pack + rounds + compare op counts)
+#: -- DEMOTED to a cross-check by ISSUE 13: the live model is
 #: the XLA-derived one (telemetry/programs.py: optimized-HLO flops per
 #: candidate, captured at every compile site), which covers EVERY
 #: engine that compiles a step.  These five hand values remain only to
@@ -332,6 +346,11 @@ def probe_pending(worker, unit, sampler: PerfSampler,
         phases, hits, cands, batches = _probe_digit(worker, unit)
     else:
         phases, hits, cands, batches = _probe_coarse(worker, unit)
+    # what ran (runtime.worker.describe_worker): a probed unit's
+    # dispatches are per-batch and synced, not the production shape
+    from dprf_tpu.runtime.worker import count_dispatches
+    count_dispatches(getattr(worker, "_worker", worker), "probe",
+                     batches)
     sweep_span = new_span_id()
     engine = worker_engine(worker)
     job = getattr(unit, "job_id", "j0")
@@ -403,77 +422,76 @@ def record_measured_cost(engine: str, seconds_per_candidate: float,
             seconds_per_candidate, engine=engine)
 
 
-def measured_ops_per_candidate(engine: str) -> Optional[float]:
-    """Measured-cost fallback op model: device-s/candidate scaled by
-    the band CEILING, i.e. "if the chip issued at peak, this is what
-    the kernel's time is worth in ops".  Conservative by construction
-    -- the implied roofline fraction of the measured rate itself is
-    <= 1 -- and only consulted when neither an analyzed program nor a
-    hand entry exists."""
-    spc = _MEASURED_SPC.get(engine)
-    if not spc:
-        return None
-    return spc * CHIP_INT_OPS_BAND[1]
-
-
 def ops_per_candidate(engine: str, registry=None) -> Optional[float]:
     """The engine's roofline op model: the XLA-DERIVED value
     (telemetry/programs.py: optimized flops / candidates per dispatch)
     when a compiled program was analyzed in this process, else the
-    hand table, else the profiler-measured device-s/cand fallback
-    (``record_measured_cost``).  When analyzed AND hand exist the
-    divergence ratio is published so a drifted hand model (or a
-    mis-captured program) surfaces on /metrics instead of silently
-    skewing every roofline fraction.  Returns None only when the
-    engine compiled nothing here, has no hand entry, AND was never
-    covered by a profiler capture window."""
+    hand table.  When analyzed AND hand exist the divergence ratio is
+    published so a drifted hand model (or a mis-captured program)
+    surfaces on /metrics instead of silently skewing every roofline
+    fraction.  None when the engine compiled nothing here and has no
+    hand entry (roofline_band_hs then falls back to a
+    profiler-measured device-s/candidate, ``record_measured_cost``)."""
     from dprf_tpu.telemetry import programs as programs_mod
     analyzed = programs_mod.analyzed_ops_per_candidate(engine)
     hand = OPS_PER_CANDIDATE.get(engine)
     if analyzed and hand:
         ratio = max(analyzed, hand) / min(analyzed, hand)
         _divergence_gauge(registry).set(ratio, engine=engine)
-    return analyzed or hand or measured_ops_per_candidate(engine)
+    return analyzed or hand
 
 
-def roofline_band_hs(engine: str) -> Optional[tuple]:
-    """(lo, hi) H/s ceiling band for an engine, or None when neither
-    an analyzed program nor a hand model exists.  The analyzed model
-    wins (see ops_per_candidate); md5's documented 4-8 GH/s
-    BASELINE.md band applies only on the hand-model fallback, so the
-    committed trajectory stays readable next to the derived one."""
+def roofline_band_hs(engine: str,
+                     device_kind: Optional[str]) -> Optional[tuple]:
+    """(lo, hi) H/s ceiling band for an engine on one chip of
+    ``device_kind``, or None when the kind is not in
+    CHIP_INT_OPS_BANDS or the engine has no cost model.  The analyzed
+    model wins (see ops_per_candidate); md5's 4-8 GH/s hand band
+    applies only on the hand-model fallback, so the committed
+    trajectory stays readable next to the derived one.  An engine
+    with neither gets the measured-cost band: its profiler-measured
+    device time per candidate IS the ceiling's reciprocal (the
+    fraction of the measured rate itself is <= 1 by construction)."""
+    chip = CHIP_INT_OPS_BANDS.get(device_kind)
+    if chip is None:
+        return None
+    lo, hi = chip
     ops = ops_per_candidate(engine)
     if not ops:
-        return None
+        spc = _MEASURED_SPC.get(engine)
+        return (lo / hi / spc, 1.0 / spc) if spc else None
     from dprf_tpu.telemetry import programs as programs_mod
     if engine == "md5" and not \
             programs_mod.analyzed_ops_per_candidate(engine):
         return (4.0e9, 8.0e9)
-    lo, hi = CHIP_INT_OPS_BAND
     return (lo / ops, hi / ops)
 
 
-def roofline_fraction(engine: str, rate_hs: float) -> Optional[float]:
-    """Conservative fraction of the roofline band (vs the HI ceiling,
-    like the driver bench's roofline_frac); None when the engine has
-    no model or the rate is not positive."""
-    band = roofline_band_hs(engine)
+def roofline_fraction(engine: str, rate_hs: float,
+                      device_kind: Optional[str]) -> Optional[float]:
+    """Conservative fraction of the roofline band (vs the HI ceiling);
+    None when the chip kind or the engine has no model or the rate is
+    not positive."""
+    band = roofline_band_hs(engine, device_kind)
     if band is None or not rate_hs or rate_hs <= 0:
         return None
     return rate_hs / band[1]
 
 
-def analyzed_roofline_fraction(engine: str,
-                               rate_hs: float) -> Optional[float]:
+def analyzed_roofline_fraction(engine: str, rate_hs: float,
+                               device_kind: Optional[str]
+                               ) -> Optional[float]:
     """Roofline fraction from the XLA-DERIVED model ALONE (no hand
     fallback): what bench reports as ``analyzed_roofline`` so the
     trajectory can tell a compiler-derived fraction from a hand-table
-    one.  None when no program of this engine was analyzed here."""
+    one.  None when no program of this engine was analyzed here, or
+    the chip kind has no band."""
     from dprf_tpu.telemetry import programs as programs_mod
+    chip = CHIP_INT_OPS_BANDS.get(device_kind)
     ops = programs_mod.analyzed_ops_per_candidate(engine)
-    if not ops or not rate_hs or rate_hs <= 0:
+    if chip is None or not ops or not rate_hs or rate_hs <= 0:
         return None
-    return rate_hs / (CHIP_INT_OPS_BAND[1] / ops)
+    return rate_hs / (chip[1] / ops)
 
 
 def _roofline_gauge(registry=None):
@@ -485,12 +503,14 @@ def _roofline_gauge(registry=None):
 
 
 def publish_roofline(engine: str, rate_hs: float,
+                     device_kind: Optional[str],
                      registry=None) -> Optional[float]:
-    """Fold one throughput observation into the live roofline gauge
-    (EWMA against the gauge's current value, so per-unit jitter reads
-    as a rate).  Returns the smoothed fraction, or None when the
-    engine has no published op model."""
-    frac = roofline_fraction(engine, rate_hs)
+    """Fold one throughput observation of a ``device_kind`` chip into
+    the live roofline gauge (EWMA against the gauge's current value,
+    so per-unit jitter reads as a rate).  Returns the smoothed
+    fraction, or None -- and publishes nothing -- when the kind has
+    no band or the engine no op model."""
+    frac = roofline_fraction(engine, rate_hs, device_kind)
     if frac is None:
         return None
     g = _roofline_gauge(registry)
@@ -511,7 +531,8 @@ def roofline_snapshot(registry=None) -> dict:
 
 
 def publish_scaling(engine: str, per_chip_hs: float, efficiency: float,
-                    n_devices: int, registry=None) -> None:
+                    n_devices: int, device_kind: Optional[str],
+                    registry=None) -> None:
     """Multichip bench publication: per-chip H/s and the 1->N scaling
     efficiency, next to the roofline gauge -- ONE declaration site for
     both gauges."""
@@ -523,4 +544,5 @@ def publish_scaling(engine: str, per_chip_hs: float, efficiency: float,
             "rate_N / (N * rate_1) of the last multichip scaling "
             "bench", labelnames=("engine",)).set(efficiency,
                                                  engine=engine)
-    publish_roofline(engine, per_chip_hs, registry=registry)
+    publish_roofline(engine, per_chip_hs, device_kind,
+                     registry=registry)

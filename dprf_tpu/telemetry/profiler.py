@@ -22,7 +22,7 @@ dispatch.  This module closes that gap in three pieces:
 
   2. **The analyzer** -- ``analyze_trace`` parses the emitted
      ``perfetto_trace.json.gz`` (gzip JSON trace events; verified
-     parseable on jax 0.4.37) with NO dependencies beyond stdlib:
+     parseable on jax 0.9.0) with NO dependencies beyond stdlib:
      lanes come from the process/thread-name metadata events,
      per-event SELF time from the nesting stack, and every device-op
      event is classified by name (fusion / collective / copy-convert
@@ -239,14 +239,17 @@ def _divergence_gauge(registry=None):
 def publish_divergence(engine: str, device_s_per_cand: float,
                        registry=None) -> Optional[float]:
     """Measured-vs-analyzed cost ratio for one capture; None when the
-    engine has no analyzed program in this process (nothing honest to
-    divide by)."""
+    engine has no analyzed program in this process or this process's
+    chip kind has no band (nothing honest to divide by)."""
     from dprf_tpu.telemetry import perf as perf_mod
     from dprf_tpu.telemetry import programs as programs_mod
     ops = programs_mod.analyzed_ops_per_candidate(engine)
     if not ops or not device_s_per_cand or device_s_per_cand <= 0:
         return None
-    predicted = ops / perf_mod.CHIP_INT_OPS_BAND[1]
+    chip = perf_mod.CHIP_INT_OPS_BANDS.get(perf_mod.local_device_kind())
+    if chip is None:
+        return None
+    predicted = ops / chip[1]
     ratio = device_s_per_cand / predicted
     _divergence_gauge(registry).set(ratio, engine=engine)
     return ratio
@@ -279,12 +282,13 @@ def _load_events(trace_file: str) -> list:
 
 #: lane kinds, decided from the process/thread-name metadata: the
 #: device-op lane holds per-HLO events (TPU: the "XLA Ops" threads of
-#: "/device:*" processes; CPU backend: the TfrtCpuClient execution
-#: threads), the compile lanes hold codegen/compile-pass work, the
-#: host lane holds the $file:line python frames.
+#: "/device:*" processes; CPU backend: the "tf_XLAPjRtCpuClient/<id>"
+#: execution threads), the compile lanes hold codegen/compile-pass
+#: work (CPU backend: "tf_xla-cpu-codegen/<id>"), the host lane holds
+#: the $file:line python frames.
 def _lane_kind(proc_name: str, thread_name: str) -> str:
     p, t = proc_name.lower(), thread_name.lower()
-    if "llvm-codegen" in t or "xlacompile" in t or "compile" in t:
+    if "codegen" in t or "compile" in t:
         return "compile"
     if "/device:" in p:
         # xprof device processes: the op lane is "XLA Ops"; module/
@@ -294,7 +298,7 @@ def _lane_kind(proc_name: str, thread_name: str) -> str:
         if "xla modules" in t or t.startswith("step"):
             return "skip"
         return "device" if not t else "skip"
-    if "tfrtcpuclient" in t or "xla:cpu" in t or "stream" in t:
+    if "pjrtcpuclient" in t or "xla:cpu" in t or "stream" in t:
         return "device"
     if t == "python" or "host" in p and t.startswith("py"):
         return "host"

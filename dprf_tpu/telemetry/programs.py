@@ -5,7 +5,7 @@ The roofline story used to end at a hand-maintained
 ``OPS_PER_CANDIDATE`` table in telemetry/perf.py covering five fast
 engines -- every other engine reported no roofline at all, and nothing
 in the stack knew how much HBM a compiled step actually needs.  The
-compiler knows both exactly: jax 0.4.37's AOT surface exposes
+compiler knows both exactly: JAX's AOT surface exposes
 ``compiled.cost_analysis()`` (optimized-HLO flops / bytes accessed)
 and ``compiled.memory_analysis()`` (argument / output / temp / code
 bytes).  This module captures those numbers at every compile site --
@@ -35,7 +35,7 @@ rungs, bench -- into one process-wide registry:
         programs`` shows the fleet's program table, not one process's.
 
 Degradation contract: every jax call here is best-effort.  A backend
-without cost analysis, a step that cannot AOT-lower, or an old jax
+without cost analysis or a step that cannot AOT-lower
 loses the analyzed record -- never the job.  ``DPRF_PROGRAM_ANALYSIS=0``
 is the kill switch (the hand roofline models keep working).
 """

@@ -7,7 +7,7 @@ workers, and the knob-sweep tune surface (sweep_values +
 lookup_tuned_value / record_tuned_value).
 
 Everything runs md5 in interpret mode on the conftest's 8 virtual CPU
-devices (real-TPU numbers live in the TPU_PROBE_LOG records); parity
+devices (chip numbers belong to PERF.md and the ledger); parity
 is always against the CpuWorker oracle, exact hit sets, so the kernel
 path's sentinel/overflow disciplines are exercised end to end.
 """
@@ -100,6 +100,43 @@ def test_sharded_kernel_multi_probe_boundaries(mesh):
     assert got == _cpu_hits(gen, targets,
                             WorkUnit(0, 0, gen.keyspace))
     assert [g[1] for g in got] == plant
+
+
+def test_sharded_kernel_multi_collided_tiles_rescan_one_tile(mesh):
+    """Two (and three) probe survivors INSIDE one tile: the tile can
+    report one lane only, so it comes back tagged as collided and the
+    worker rescans exactly that tile on the oracle -- in a fused
+    window and in the per-batch tail, with a tile cut by the unit's
+    end.  Every plant once, no window redrive, no stride-wide host
+    rescan."""
+    gen = MaskGenerator("?d?d?d?d?d")       # 100000
+    B = 8 * 128
+    stride = 8 * B
+    plant = [5, 6,                          # one tile, fused window
+             3 * B + 10, 3 * B + 500, 3 * B + 1000,    # three in one
+             9 * stride + 7,                # a lone single beside them
+             gen.keyspace - 3, gen.keyspace - 1]       # cut last tile
+    targets = _md5_targets(gen, plant)
+    w = ShardedMaskWorker(get_engine("md5", device="jax"), gen, targets,
+                          mesh, batch_per_device=B, hit_capacity=16,
+                          oracle=get_engine("md5", device="cpu"),
+                          kernel={"interpret": True, "sub": 8})
+    unit = WorkUnit(0, 0, gen.keyspace)
+    notes = []
+    from dprf_tpu.telemetry import coverage
+    coverage.install_collector(
+        lambda name, start, end, attrs: notes.append((name, start, end)))
+    try:
+        got = sorted((h.target_index, h.cand_index, h.plaintext)
+                     for h in w.process(unit))
+    finally:
+        coverage.install_collector(None)
+    assert got == _cpu_hits(gen, targets, unit)
+    rescans = sorted(n[1:] for n in notes if n[0] == "rescan")
+    last_tile = (gen.keyspace - 1) // B * B
+    assert rescans == [(0, B), (3 * B, 4 * B),
+                       (last_tile, gen.keyspace)]
+    assert not [n for n in notes if n[0] == "redrive"]
 
 
 def test_sharded_kernel_overflow_redrives_exactly(mesh):

@@ -193,8 +193,8 @@ def test_chunked_growth_cap():
 def test_pallas_eks_advance_matches_xla():
     """The Pallas EksBlowfish advance kernel (ops/pallas_bcrypt.py) is
     bit-exact vs the XLA form over the ChunkedEks advance contract
-    (interpret mode; the same kernel was proven on TPU v5 lite --
-    TPU_RESULTS_r04 / tpu_cases pallaseks)."""
+    (interpret mode; on the chip the kernel runs in chip_smoke.py's
+    bcrypt phase)."""
     import numpy as np
     import jax.numpy as jnp
 
@@ -249,6 +249,35 @@ def test_bcrypt_route_forced_device(monkeypatch):
     w = dev.make_mask_worker(gen, [t], batch=64, hit_capacity=8,
                              oracle=cpu)
     assert isinstance(w, BcryptMaskWorker)
+    # off the chip the cost loop is the XLA form, and the job's log
+    # says so: the same worker class carries either implementation
+    from dprf_tpu.runtime.worker import describe_worker
+    ran = describe_worker(w)
+    assert ran["advance"] == "xla" and ran["interpret"] == "n/a"
+
+
+def test_bcrypt_worker_says_when_its_cost_loop_is_the_kernel(monkeypatch):
+    """With the kernel as its advance the worker reports advance=pallas,
+    interpret=False and the kernel's compile: chip_smoke.py's bcrypt
+    phase fails on anything else."""
+    import jax
+
+    from dprf_tpu.generators.mask import MaskGenerator
+    from dprf_tpu.ops import blowfish as bf_ops
+    from dprf_tpu.ops import pallas_bcrypt
+    from dprf_tpu.runtime.worker import describe_worker
+
+    monkeypatch.setattr(
+        pallas_bcrypt, "make_best_eks_advance",
+        lambda batch: (jax.jit(bf_ops.eks_rounds), "pallas"))
+    monkeypatch.setenv("DPRF_BCRYPT_ROUTE", "device")
+    dev = get_engine("bcrypt", device="jax")
+    t = dev.parse_target(bcrypt_hash(b"xx", bytes(range(16)), 4))
+    w = dev.make_mask_worker(MaskGenerator("?d?d"), [t], batch=64,
+                             hit_capacity=8, oracle=None)
+    ran = describe_worker(w)
+    assert ran["advance"] == "pallas" and ran["interpret"] is False
+    assert ran["cache"] in ("hit", "miss", "off")
 
 
 def test_measure_eks_rates_runs():

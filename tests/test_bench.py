@@ -32,71 +32,76 @@ def test_run_config_1_worker_path():
     assert res["value"] > 0 and res["targets"] == 1
 
 
-def test_cached_session_fallback_reads_committed_results(tmp_path):
-    """bench.py's fallback chain must consult checked-in
-    TPU_RESULTS_r*.json (VERDICT r3 #1): /tmp session files first, then
-    the latest committed round, ignoring poisoned (>=1e12) values."""
-    import importlib.util
-    import json
-    import os
-    import shutil
+def test_run_config_names_what_ran():
+    """A config record names its path, not only its rate: worker
+    class, interpret flag, dispatch shapes with counts."""
+    res = run_config(1, device="jax", seconds=0.2, batch=4096,
+                     unit_strides=8)
+    assert res["worker"] == "DeviceMaskWorker"   # no kernel off-chip
+    assert res["interpret"] == "n/a"
+    assert "scan:" in res["dispatch"]            # 8 strides: fused
 
+
+def _root_bench():
+    import importlib.util
+    import os
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     spec = importlib.util.spec_from_file_location(
         "bench_root", os.path.join(repo, "bench.py"))
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
+    return mod
 
-    # sandbox copy so the test controls exactly which files exist
-    sandbox = tmp_path / "repo"
-    sandbox.mkdir()
-    shutil.copy(os.path.join(repo, "bench.py"), sandbox / "bench.py")
-    (sandbox / "TPU_RESULTS_r01.json").write_text(json.dumps(
-        {"stages": {"bench": {"md5-pallas": {
-            "device": "tpu", "engine": "md5", "value": 1.0e9}}}}))
-    (sandbox / "TPU_RESULTS_r02.json").write_text(json.dumps(
-        {"sessionA": {"stages": {"bench": {
-            "md5-pallas": {"device": "tpu", "engine": "md5",
-                           "value": 2.0e9},
-            "md5-poisoned": {"device": "tpu", "engine": "md5",
-                             "value": 1.3e15},     # poisoned: ignored
-            "sha1": {"device": "tpu", "engine": "sha1",
-                     "value": 9.9e9}}}}}))         # wrong engine
-    spec2 = importlib.util.spec_from_file_location(
-        "bench_sandbox", str(sandbox / "bench.py"))
-    mod2 = importlib.util.module_from_spec(spec2)
-    spec2.loader.exec_module(mod2)
-    mod2.TMP_SESSION_GLOB = str(tmp_path / "nonexistent" / "*.json")
-    res = mod2._cached_session_result()
-    # newest round wins; nested session shape is scanned; caps applied
-    assert res is not None and res["value"] == 2.0e9
-    assert res["device"] == "tpu" and "cached session" in res["note"]
 
-    # the real repo's committed results must be found too (tmp tier
-    # neutralized so this exercises the committed-file path); only
-    # schema properties are asserted -- the value belongs to whatever
-    # round last measured, not to this test
-    mod.TMP_SESSION_GLOB = str(tmp_path / "nonexistent" / "*.json")
-    real = mod._cached_session_result()
-    assert real is not None and real["device"] == "tpu"
-    assert 0 < real["value"] < mod.CACHED_VALUE_CAP
+def test_root_bench_no_tpu_no_value(capsys, monkeypatch):
+    """No chip, no number: off a TPU the driver's bench.py exits
+    non-zero, prints no value, names the device it found, and starts
+    no child process."""
+    import json
+    import subprocess
 
-    # a stale /tmp leftover (older than the newest committed file)
-    # must NOT shadow the committed record -- it joins the same tier
-    stale_dir = tmp_path / "stale"
-    stale_dir.mkdir()
-    stale = stale_dir / "tpu_session_results.json"
-    stale.write_text(json.dumps({"stages": {"bench": {"md5-xla": {
-        "device": "tpu", "engine": "md5", "value": 5.0e7}}}}))
-    committed = sandbox / "TPU_RESULTS_r02.json"
-    os.utime(stale, (os.path.getmtime(committed) - 100,) * 2)
-    mod2.TMP_SESSION_GLOB = str(stale_dir / "*.json")
-    res = mod2._cached_session_result()
-    assert res["value"] == 2.0e9   # committed round wins the tier
-    # but a FRESH /tmp session (newer than the committed file) wins
-    os.utime(stale, (os.path.getmtime(committed) + 100,) * 2)
-    res = mod2._cached_session_result()
-    assert res["value"] == 5.0e7
+    def no_children(*a, **kw):
+        raise AssertionError("bench.py started a child process")
+
+    monkeypatch.setattr(subprocess, "Popen", no_children)
+    monkeypatch.setattr(subprocess, "run", no_children)
+    rc = _root_bench().main()
+    doc = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+    assert rc != 0 and doc["ok"] is False
+    assert "value" not in doc
+    assert doc["device"] == "cpu" and doc["device_count"] >= 1
+    assert doc["device_kind"] == jax.devices()[0].device_kind
+
+
+def test_root_bench_refuses_a_record_off_the_kernel_worker(
+        capsys, monkeypatch):
+    """On a TPU the record must come from the compiled kernel worker:
+    any other worker class, or an interpreted kernel, is an error
+    without a value -- never a slower path's number."""
+    import json
+
+    import dprf_tpu.bench as dbench
+    mod = _root_bench()
+
+    class FakeTpu:
+        platform = "tpu"
+        device_kind = "TPU v5 lite"
+
+    monkeypatch.setattr(jax, "devices", lambda: [FakeTpu()])
+    for worker, interpret, ok in (
+            ("DeviceMaskWorker", "n/a", False),
+            ("PallasMaskWorker", True, False),
+            ("PallasMaskWorker", False, True)):
+        monkeypatch.setattr(
+            dbench, "run_config",
+            lambda *a, **kw: {"value": 1.0, "worker": worker,
+                              "interpret": interpret, "device": "tpu"})
+        rc = mod.main()
+        doc = json.loads(
+            capsys.readouterr().out.strip().splitlines()[-1])
+        assert (rc == 0) is ok
+        assert ("value" in doc) is ok
+        assert doc["device_kind"] == "TPU v5 lite"
 
 
 def test_run_scaling_plumbing():

@@ -18,25 +18,6 @@ from dprf_tpu.generators.mask import MaskGenerator
 from dprf_tpu.telemetry import MetricsRegistry
 
 
-@pytest.fixture
-def fresh_cache(tmp_path, monkeypatch):
-    """Point the persistent cache at a test-owned EMPTY dir (so the
-    first compile is provably cold) and restore the session-wide dir
-    afterwards -- compilecache state is process-global.  The env var
-    is repointed too: library code calls enable() with no dir, which
-    resolves through $DPRF_COMPILE_CACHE_DIR."""
-    prev = compilecache.cache_dir()
-    want = str(tmp_path / "xla")
-    monkeypatch.setenv(compilecache.CACHE_DIR_ENV, want)
-    d = compilecache.enable(dir=want)
-    assert d is not None
-    yield d
-    if prev is not None:
-        compilecache.enable(dir=prev)
-    else:
-        compilecache.disable()
-
-
 # ---------------------------------------------------------------------------
 # enable(): wiring, idempotency, degradation
 
@@ -44,30 +25,88 @@ def test_enable_idempotent_and_entry_count(fresh_cache):
     import jax
     assert compilecache.enabled()
     assert compilecache.cache_dir() == fresh_cache
-    assert jax.config.jax_compilation_cache_dir == fresh_cache
     # persistence thresholds lowered so step compiles always persist
     assert jax.config.jax_persistent_cache_min_compile_time_secs == 0.0
     assert jax.config.jax_persistent_cache_min_entry_size_bytes == -1
-    assert compilecache.enable(dir=fresh_cache) == fresh_cache  # no-op
+    assert compilecache.enable() == fresh_cache                 # no-op
     assert compilecache.entry_count() == 0                      # empty
 
 
 def test_enable_kill_switch_and_unwritable_dir(tmp_path, monkeypatch):
     monkeypatch.setenv(compilecache.DISABLE_ENV, "0")
-    assert compilecache.enable(dir=str(tmp_path / "x")) is None
+    assert compilecache.enable() is None
     monkeypatch.delenv(compilecache.DISABLE_ENV)
     # an unwritable "dir" (a plain file blocks makedirs) degrades to
     # None -- never an exception, never a half-enabled state
     blocker = tmp_path / "not-a-dir"
     blocker.write_text("x")
     prev = compilecache.cache_dir()
-    assert compilecache.enable(dir=str(blocker)) is None
+    monkeypatch.setenv(compilecache.CACHE_DIR_ENV, str(blocker))
+    assert compilecache.enable() is None
     assert compilecache.cache_dir() == prev     # state untouched
 
 
-def test_default_dir_honors_env(monkeypatch, tmp_path):
-    monkeypatch.setenv(compilecache.CACHE_DIR_ENV, str(tmp_path / "e"))
-    assert compilecache.default_cache_dir() == str(tmp_path / "e")
+# ---------------------------------------------------------------------------
+# placement: the variable decides, else one fixed path in the checkout
+
+def _record_config_updates(monkeypatch):
+    import jax
+    seen = []
+    real = jax.config.update
+
+    def update(name, value):
+        seen.append(name)
+        real(name, value)
+
+    monkeypatch.setattr(jax.config, "update", update)
+    return seen
+
+
+def test_variable_set_places_cache_and_no_code_sets_the_dir(
+        tmp_path, monkeypatch, cache_off):
+    """$JAX_COMPILATION_CACHE_DIR set: the cache is there, and the
+    program makes no jax.config.update of the directory -- only the
+    on switch and the two persistence thresholds."""
+    want = str(tmp_path / "placed")
+    monkeypatch.setenv(compilecache.CACHE_DIR_ENV, want)
+    assert compilecache.default_cache_dir() == want
+    seen = _record_config_updates(monkeypatch)
+    assert compilecache.enable() == want
+    assert "jax_compilation_cache_dir" not in seen
+    assert sorted(seen) == [
+        "jax_enable_compilation_cache",
+        "jax_persistent_cache_min_compile_time_secs",
+        "jax_persistent_cache_min_entry_size_bytes"]
+    assert os.path.isdir(want)
+
+
+def test_variable_unset_uses_the_fixed_checkout_path(monkeypatch,
+                                                     cache_off):
+    """Unset: ONE fixed path inside the checkout -- not under $HOME,
+    and not a name made from a temp name, a pid or the time."""
+    import jax
+    monkeypatch.delenv(compilecache.CACHE_DIR_ENV, raising=False)
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    want = os.path.join(repo, ".cache", "xla")
+    assert compilecache.default_cache_dir() == want
+    assert compilecache.CHECKOUT_CACHE_DIR == want
+    prev = jax.config.jax_compilation_cache_dir
+    try:
+        assert compilecache.enable() == want
+        assert jax.config.jax_compilation_cache_dir == want
+    finally:
+        compilecache.disable()
+        jax.config.update("jax_compilation_cache_dir", prev)
+
+
+def test_disable_switches_off_without_moving_the_dir(fresh_cache):
+    import jax
+    compilecache.disable()
+    assert not compilecache.enabled()
+    assert jax.config.jax_enable_compilation_cache is False
+    assert jax.config.jax_compilation_cache_dir == fresh_cache
+    assert compilecache.enable() == fresh_cache
+    assert jax.config.jax_enable_compilation_cache is True
 
 
 # ---------------------------------------------------------------------------
@@ -85,14 +124,8 @@ def test_classify_compile_rules(fresh_cache, monkeypatch):
     assert compilecache.classify_compile(10.0, 5, 5) == "hit"
 
 
-def test_classify_off_when_disabled(monkeypatch):
-    prev = compilecache.cache_dir()
-    compilecache.disable()
-    try:
-        assert compilecache.classify_compile(9.0, 0, 5) == "off"
-    finally:
-        if prev is not None:
-            compilecache.enable(dir=prev)
+def test_classify_off_when_disabled(cache_off):
+    assert compilecache.classify_compile(9.0, 0, 5) == "off"
 
 
 def test_observe_compile_metrics():
@@ -126,7 +159,7 @@ def _make_worker(engine_name, mask, batch):
 
 @pytest.mark.compileheavy
 def test_repeated_warmup_5x_faster_with_cache(fresh_cache):
-    """Acceptance (ISSUE 3): with $DPRF_COMPILE_CACHE_DIR set, a
+    """Acceptance (ISSUE 3): with the persistent cache on, a
     repeated identically-shaped warmup's XLA compile is >= 5x faster
     than the cold compile -- the cache serves the executable instead
     of re-running XLA (measured ~10x for sha512 on this CPU backend;

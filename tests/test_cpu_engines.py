@@ -121,8 +121,8 @@ def test_registry():
 
 def test_engine_alias_sets_device_symmetric():
     """Every name resolvable on one device resolves on the other
-    (VERDICT r3 weak #6: a job written with a jax-side alias must not
-    fail under --device=cpu, and vice versa)."""
+    (a job written with a jax-side alias must not fail under
+    --device=cpu, and vice versa)."""
     from dprf_tpu.engines import engine_names
 
     cpu = set(engine_names("cpu"))

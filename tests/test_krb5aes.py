@@ -259,8 +259,7 @@ def _short_line(pw: bytes, seed: int = 21) -> str:
 def test_mixed_floor_targets_stay_on_device():
     """One below-floor target must NOT demote the whole job: the
     device worker keeps CTS-safe targets on compiled steps and scans
-    the short one with a host pseudo-step (VERDICT-style per-target
-    routing)."""
+    the short one with a host pseudo-step (per-target routing)."""
     dev = get_engine("krb5tgs-aes", device="jax")
     cpu = get_engine("krb5tgs-aes", device="cpu")
     gen = MaskGenerator("?d?d")
@@ -418,6 +417,11 @@ def test_pbkdf2_lanes_matches_hashlib():
             assert (got[i] == want_w).all(), (n_words, i)
 
 
+#: slow: 385 s in the tier-1 run of PR 21 -- XLA:CPU compiling the
+#: interpret-mode discharge of the PBKDF2 kernel for every kernel
+#: target.  The kernel's Mosaic compile is in tests/test_chip_compile.py
+#: (krb5aes-pbkdf2, ~20 s).
+@pytest.mark.slow
 def test_kernel_route_builds_and_marks(monkeypatch):
     """DPRF_PALLAS=1: the mask worker routes eligible targets onto the
     PBKDF2 kernel step (kernel_targets marker).  The kernel itself is

@@ -219,27 +219,47 @@ def test_make_mask_worker_routes_to_kernel(monkeypatch):
     assert not isinstance(w3, PallasMaskWorker)      # cpu backend
 
 
-def test_bloom_tables_never_false_negative():
-    """Every target's own digest bits must be set in its set's bitmap
-    for all probes -- a real hit can never be filtered out."""
-    from dprf_tpu.ops.pallas_mask import K_PROBES, SET_SIZE, bloom_tables
+@pytest.mark.parametrize("n,width", [(2, 4), (1000, 4), (2500, 5),
+                                     (8192, 8)])
+def test_probe_rows_never_false_negative(n, width):
+    """Every target's own digest survives the in-kernel probe over the
+    rows built from the list -- a real hit can never be filtered out --
+    and a uniform digest that is no target rarely does."""
+    from dprf_tpu.ops.pallas_mask import (kernel_probe_rows,
+                                          probe_block_found)
 
     rng = np.random.default_rng(7)
-    tw = rng.integers(0, 1 << 32, size=(2500, 4), dtype=np.uint64).astype(
-        np.uint32)
-    T = bloom_tables(tw)
-    assert T.shape == (3 * K_PROBES, 128)
-    for i, words in enumerate(tw):
-        s = i // SET_SIZE
-        for p in range(K_PROBES):
-            o = 12 * p
-            j, sh = divmod(o, 32)
-            bits = int(words[j]) >> sh
-            if sh > 20:
-                bits |= int(words[j + 1]) << (32 - sh)
-            bits &= 0xFFF
-            word = T[s * K_PROBES + p, bits >> 5]
-            assert (word >> (bits & 31)) & 1, (i, p)
+    tw = rng.integers(0, 1 << 32, size=(n, width),
+                      dtype=np.uint64).astype(np.uint32)
+    rows, block_bits, k, n_grp, fp_est = kernel_probe_rows(tw)
+    assert rows.shape[1] == 128 and rows.dtype == np.uint32
+    assert 0 < fp_est < 1e-4
+
+    def survivors(words):
+        # the kernel's (sub, 128) tile layout, padded with copies
+        m = -(-len(words) // 128) * 128
+        pad = np.concatenate([words, np.repeat(words[:1], m - len(words),
+                                               axis=0)])
+        shape = (m // 128, 128)
+        digest = [jnp.asarray(pad[:, j].reshape(shape))
+                  for j in range(width)]
+        found = probe_block_found(digest, jnp.asarray(rows),
+                                  jnp.ones(shape, jnp.bool_), block_bits,
+                                  k, n_grp, shape)
+        return np.asarray(found).reshape(-1)[:len(words)]
+
+    assert survivors(tw).all()
+    others = rng.integers(0, 1 << 32, size=(1 << 14, width),
+                          dtype=np.uint64).astype(np.uint32)
+    assert survivors(others).sum() <= 2
+
+
+def test_probe_rows_refuse_more_than_the_kernel_cap():
+    from dprf_tpu.ops.pallas_mask import MAX_TARGETS, kernel_probe_rows
+
+    tw = np.zeros((MAX_TARGETS + 1, 4), np.uint32)
+    with pytest.raises(ValueError, match="targets"):
+        kernel_probe_rows(tw)
 
 
 def _multi_targets(engine_name, eng, plants, n_fill=1000, seed=3):
@@ -258,9 +278,10 @@ def _multi_targets(engine_name, eng, plants, n_fill=1000, seed=3):
 
 @pytest.mark.parametrize("engine", ["md5", "ntlm"])
 def test_pallas_multi_target_matches_xla(engine):
-    """The Bloom multi-target kernel path must match the XLA
-    multi-target path hit-for-hit on a 1k-target list, including a
-    deliberate two-hits-in-one-tile collision (VERDICT r1 item 5)."""
+    """The multi-target kernel path (in-kernel prefilter + oracle
+    verification) must match the XLA multi-target path hit-for-hit on
+    a 1k-target list, including a deliberate two-hits-in-one-tile
+    collision."""
     from dprf_tpu.runtime.worker import DeviceMaskWorker
 
     gen = MaskGenerator("?l?l?l?l")
@@ -288,53 +309,53 @@ def test_pallas_multi_target_matches_xla(engine):
     assert [p for _, _, p in phits] == plants
 
 
-def test_make_mask_worker_falls_back_on_kernel_failure(monkeypatch, capsys):
-    """A kernel that fails to build/compile (Mosaic regression) must
-    degrade to the XLA DeviceMaskWorker with a warning, not abort."""
+@pytest.mark.parametrize("interpret", [False, True],
+                         ids=["real-chip", "interpret"])
+@pytest.mark.parametrize("where", ["build", "warmup"])
+def test_make_mask_worker_kernel_failure_raises(monkeypatch, capsys,
+                                                interpret, where):
+    """A kernel that fails to build (construction) or to compile (the
+    factory's warmup forces it) RAISES with the compiler's message --
+    under a real-chip pallas_mode() above all: the XLA pipeline is far
+    slower, so a quiet switch to it would be a wrong result that still
+    passes.  No fallback warning, no DeviceMaskWorker."""
+    import dprf_tpu.ops.pallas_mask as pm
     import dprf_tpu.runtime.worker as worker_mod
-    from dprf_tpu.runtime.worker import DeviceMaskWorker
 
-    monkeypatch.setenv("DPRF_PALLAS", "1")
+    monkeypatch.setattr(pm, "pallas_mode",
+                        lambda: {"interpret": interpret})
 
     class Boom(worker_mod.PallasMaskWorker):
         def __init__(self, *a, **kw):
+            if where == "build":
+                raise RuntimeError("injected Mosaic lowering failure")
+            self._warmed = False
+
+        def warmup(self):
             raise RuntimeError("injected Mosaic lowering failure")
 
     monkeypatch.setattr(worker_mod, "PallasMaskWorker", Boom)
     gen = MaskGenerator("?l?l?l")
     eng = get_engine("sha1", device="jax")
     t1 = eng.parse_target(hashlib.sha1(b"abc").hexdigest())
-    w = eng.make_mask_worker(gen, [t1], batch=TILE, hit_capacity=8)
-    assert isinstance(w, DeviceMaskWorker)
-    err = capsys.readouterr().err
-    assert "falling back to the XLA pipeline" in err
-    # and the fallback worker actually cracks
-    planted = gen.index_of(b"dog")
-    tdog = eng.parse_target(hashlib.sha1(b"dog").hexdigest())
-    w = eng.make_mask_worker(gen, [tdog], batch=TILE, hit_capacity=8)
-    hits = w.process(WorkUnit(-1, 0, gen.keyspace))
-    assert [h.cand_index for h in hits] == [planted]
+    with pytest.raises(RuntimeError, match="injected Mosaic lowering"):
+        eng.make_mask_worker(gen, [t1], batch=TILE, hit_capacity=8)
+    assert "falling back" not in capsys.readouterr().err
 
 
-def test_make_mask_worker_warmup_failure_falls_back(monkeypatch, capsys):
-    """A compile failure at first call (not construction) is also
-    caught: warmup() forces the compile inside the factory's guard."""
-    import dprf_tpu.runtime.worker as worker_mod
-    from dprf_tpu.runtime.worker import DeviceMaskWorker
+def test_kind_kernel_step_raises_build_and_compile_failures():
+    """The per-target-sweep helper (pdf/7z/krb5aes): same rule."""
+    from dprf_tpu.engines.device._kernel_util import kind_kernel_step
 
-    monkeypatch.setenv("DPRF_PALLAS", "1")
+    def boom():
+        raise RuntimeError("mosaic says no")
 
-    class LateBoom(worker_mod.PallasMaskWorker):
-        def warmup(self):
-            raise RuntimeError("injected compile failure")
-
-    monkeypatch.setattr(worker_mod, "PallasMaskWorker", LateBoom)
-    gen = MaskGenerator("?l?l?l")
-    eng = get_engine("sha1", device="jax")
-    t1 = eng.parse_target(hashlib.sha1(b"abc").hexdigest())
-    w = eng.make_mask_worker(gen, [t1], batch=TILE, hit_capacity=8)
-    assert isinstance(w, DeviceMaskWorker)
-    assert "falling back" in capsys.readouterr().err
+    with pytest.raises(RuntimeError, match="mosaic says no"):
+        kind_kernel_step(boom, lambda step: None)
+    with pytest.raises(RuntimeError, match="mosaic says no"):
+        kind_kernel_step(lambda: object(), lambda step: boom())
+    marker = object()
+    assert kind_kernel_step(lambda: marker, lambda step: None) is marker
 
 
 @pytest.mark.smoke

@@ -7,8 +7,8 @@ rules/cpu.py for EVERY word x EVERY supported opcode -- stronger than
 digest-level checks and fast.  The pallas plumbing (grid, SMEM
 bytecode, varlen pack, digest, lane mapping, bucketing) is covered by
 one small interpret-mode end-to-end test plus the worker tests; the
-full best64 job is proven on real hardware (TPU_RESULTS_r04
-rules_kernel stage).
+full best64 job runs on the chip in chip_smoke.py's wordlist-rules
+phase and compiles for a described v5e in tests/test_chip_compile.py.
 """
 
 import hashlib

@@ -291,16 +291,8 @@ for bstart in range(0, gen.keyspace, 512):
     assert "MULTIHOST_OK" in proc.stdout
 
 
-@pytest.mark.xfail(
-    reason="multi-process CPU collectives (jax.distributed over Gloo "
-    "between two host processes) are unimplemented in jax 0.4.37: "
-    "the cross-process mesh never forms on the CPU backend, so both "
-    "ranks abort at init; single-process multi-device coverage "
-    "(test_multihost_init_and_crack_subprocess above) keeps the SPMD "
-    "crack path tested",
-    run=False)
 def test_multihost_two_process_crack(tmp_path):
-    """The REAL multi-process DCN path (VERDICT r4 missing #4): two
+    """The REAL multi-process DCN path: two
     separate OS processes, each with 4 local virtual CPU devices, form
     one 8-device mesh via `jax.distributed` (Gloo collectives) and run
     the SAME `dprf crack --multihost` command SPMD.  Process 0 owns the

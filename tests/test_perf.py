@@ -281,15 +281,46 @@ def _no_analyzed_model(monkeypatch):
                         lambda engine, programs=None: None)
 
 
+V5E = "TPU v5 lite"
+
+
 def test_roofline_band_and_fraction(monkeypatch):
     _no_analyzed_model(monkeypatch)
-    lo, hi = perf.roofline_band_hs("md5")
+    lo, hi = perf.roofline_band_hs("md5", V5E)
     assert (lo, hi) == (4.0e9, 8.0e9)        # documented band
-    assert perf.roofline_fraction("md5", 4.0e9) == pytest.approx(0.5)
-    assert perf.roofline_band_hs("sha1") == pytest.approx(
+    assert perf.roofline_fraction("md5", 4.0e9,
+                                  V5E) == pytest.approx(0.5)
+    assert perf.roofline_band_hs("sha1", V5E) == pytest.approx(
         (3.0e12 / 1000, 6.0e12 / 1000))
-    assert perf.roofline_band_hs("bcrypt") is None   # no model: None
-    assert perf.roofline_fraction("bcrypt", 1e9) is None
+    assert perf.roofline_band_hs("bcrypt", V5E) is None   # no model
+    assert perf.roofline_fraction("bcrypt", 1e9, V5E) is None
+
+
+@pytest.mark.parametrize("kind", ["cpu", "TPU v4", "TPU v6 lite", None])
+def test_roofline_is_keyed_by_device_kind(monkeypatch, kind):
+    """The band is one chip kind's: a kind that is not in the table
+    gets no band, no fraction and NO gauge -- never v5e's numbers."""
+    _no_analyzed_model(monkeypatch)
+    assert kind not in perf.CHIP_INT_OPS_BANDS
+    reg = MetricsRegistry()
+    assert perf.roofline_band_hs("md5", kind) is None
+    assert perf.roofline_fraction("md5", 4.0e9, kind) is None
+    assert perf.analyzed_roofline_fraction("md5", 4.0e9, kind) is None
+    assert perf.publish_roofline("md5", 4.0e9, kind,
+                                 registry=reg) is None
+    assert perf.roofline_snapshot(reg) == {}
+
+
+def test_measured_cost_band_needs_a_known_kind(monkeypatch):
+    """An engine with only a profiler-measured cost: the measured
+    device time per candidate is the ceiling's reciprocal."""
+    _no_analyzed_model(monkeypatch)
+    monkeypatch.setitem(perf._MEASURED_SPC, "no-model-engine", 1e-9)
+    lo, hi = perf.roofline_band_hs("no-model-engine", V5E)
+    assert hi == pytest.approx(1e9) and lo == pytest.approx(0.5e9)
+    assert perf.roofline_fraction("no-model-engine", 5e8,
+                                  V5E) == pytest.approx(0.5)
+    assert perf.roofline_band_hs("no-model-engine", "cpu") is None
 
 
 def test_roofline_prefers_analyzed_model(monkeypatch):
@@ -299,30 +330,31 @@ def test_roofline_prefers_analyzed_model(monkeypatch):
     monkeypatch.setattr(programs, "analyzed_ops_per_candidate",
                         lambda engine, programs=None: 1500.0)
     assert perf.ops_per_candidate("sha512") == 1500.0
-    assert perf.roofline_band_hs("sha512") == pytest.approx(
+    assert perf.roofline_band_hs("sha512", V5E) == pytest.approx(
         (3.0e12 / 1500, 6.0e12 / 1500))
     # md5's documented hand band yields to the derived one too
-    assert perf.roofline_band_hs("md5") == pytest.approx(
+    assert perf.roofline_band_hs("md5", V5E) == pytest.approx(
         (3.0e12 / 1500, 6.0e12 / 1500))
     assert perf.analyzed_roofline_fraction(
-        "md5", 2.0e9) == pytest.approx(2.0e9 / (6.0e12 / 1500))
+        "md5", 2.0e9, V5E) == pytest.approx(2.0e9 / (6.0e12 / 1500))
 
 
 def test_publish_roofline_smooths_and_snapshots(monkeypatch):
     _no_analyzed_model(monkeypatch)
     reg = MetricsRegistry()
-    f1 = perf.publish_roofline("md5", 4.0e9, registry=reg)
+    f1 = perf.publish_roofline("md5", 4.0e9, V5E, registry=reg)
     assert f1 == pytest.approx(0.5)          # first sample unsmoothed
-    f2 = perf.publish_roofline("md5", 8.0e9, registry=reg)
+    f2 = perf.publish_roofline("md5", 8.0e9, V5E, registry=reg)
     assert 0.5 < f2 < 1.0                    # EWMA toward 1.0
     snap = perf.roofline_snapshot(reg)
     assert snap["md5"] == pytest.approx(f2)
-    assert perf.publish_roofline("bcrypt", 1e9, registry=reg) is None
+    assert perf.publish_roofline("bcrypt", 1e9, V5E,
+                                 registry=reg) is None
 
 
 def test_scaling_gauges_published():
     reg = MetricsRegistry()
-    perf.publish_scaling("md5", 2.0e9, 0.85, 8, registry=reg)
+    perf.publish_scaling("md5", 2.0e9, 0.85, 8, V5E, registry=reg)
     assert reg.get("dprf_per_chip_rate_hs").value(
         engine="md5") == 2.0e9
     assert reg.get("dprf_scaling_efficiency").value(

@@ -148,8 +148,8 @@ def test_jax_engine_registered_with_worker_factory():
 def test_pallas_pmkid_worker_tpu_only_fallback(monkeypatch):
     """Off-TPU (this hermetic suite) the factory must return the XLA
     worker even when the kernel path is forced on -- the PBKDF2 kernel
-    is TPU-only like the sha256 mask kernel (hardware proof:
-    TPU_RESULTS_r04 / TPU_PROBE_LOG_r04)."""
+    is TPU-only like the sha256 mask kernel (on the chip it runs in
+    chip_smoke.py's pmkid phase)."""
     from dprf_tpu.engines.device.pmkid import (PallasPmkidWorker,
                                                PmkidDeviceWorker)
     from dprf_tpu.generators.mask import MaskGenerator
@@ -194,8 +194,8 @@ def test_pmkid_lanes_matches_hashlib():
     """The kernel's shared pure body (pmkid_lanes) reproduces
     hashlib's PBKDF2-HMAC-SHA1 + HMAC PMKID bit-for-bit on an eager
     tiny batch -- key padding, chaining, PMK assembly, truncation.
-    The pallas wrapper itself is hardware-proven (TPU_RESULTS_r04
-    session5: planted crack at 4096 iterations)."""
+    The pallas wrapper itself runs on the chip in chip_smoke.py's
+    pmkid phase (planted crack at 4096 iterations)."""
     import hashlib as _hl
     import hmac as _hmac
 

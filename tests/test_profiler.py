@@ -215,18 +215,19 @@ def test_single_flight_session_blocks_window_and_second_session(
 
 
 @pytest.mark.compileheavy
-def test_live_cpu_capture_attributes_host_and_compile(tmp_path):
-    """Live e2e on the CPU backend: a capture window around a COLD
-    jit compile + dispatches attributes nonzero host-python and
+def test_live_cpu_capture_attributes_host_and_compile(tmp_path,
+                                                      cache_off):
+    """Live e2e on the CPU backend: a capture window around COLD jit
+    compiles + dispatches attributes nonzero host-python and
     compile-pass time (per-HLO device lanes are TPU-only -- the
     committed fixture covers those), counts candidates through the
-    window, and lands in the capture history."""
+    window, and lands in the capture history.  cache_off: every
+    iteration builds a new jit, and only with the persistent cache
+    off does each one compile INSIDE the window."""
     import jax
     import jax.numpy as jnp
     prof = ProfileCapture(registry=MetricsRegistry())
     n = [0]
-    # 7919 lanes: a prime no other test compiles, so the persistent
-    # cache cannot have it and the compile runs INSIDE the window
     x = jnp.arange(7919, dtype=jnp.uint32)
 
     def busy():
@@ -777,41 +778,79 @@ def test_autoprofile_disabled_and_job_stalled_picks_slowest(
 # ---------------------------------------------------------------------------
 # exact compile-cache classifier (ISSUE 15 satellite)
 
-def test_compile_classifier_exact_from_cache_log_lines(tmp_path):
-    """On this jax (explain-capable), the observer classifies from
-    the compiler's own persistent-cache log lines: a cold compile is
+def test_compile_classifier_exact_from_cache_log_lines(fresh_cache):
+    """The observer classifies from the compiler's own
+    persistent-cache log lines: a cold compile is
     an exact miss, a same-key recompile served from disk an exact hit
     -- no wall-clock floor involved."""
     import jax
     import jax.numpy as jnp
 
     from dprf_tpu import compilecache
-    assert compilecache.explain_capable()
-    compilecache.enable(dir=str(tmp_path / "xla"))
     reg = MetricsRegistry()
     x = jnp.arange(4093, dtype=jnp.uint32)   # unique prime shape
-    try:
-        with compilecache.compile_observer("md5", registry=reg) as o1:
-            jax.jit(lambda v: (v ^ jnp.uint32(41)).sum())(
-                x).block_until_ready()
-        assert o1.cache == "miss"
-        # a FRESH jit of the same computation: jax's in-memory cache
-        # cannot serve it, the persistent cache does -> exact hit
-        with compilecache.compile_observer("md5", registry=reg) as o2:
-            jax.jit(lambda v: (v ^ jnp.uint32(41)).sum())(
-                x).block_until_ready()
-        assert o2.cache == "hit"
-        assert reg.get("dprf_compile_cache_hits_total").value(
-            engine="md5") == 1
-        assert reg.get("dprf_compile_cache_misses_total").value(
-            engine="md5") == 1
-    finally:
-        compilecache.disable()
-    # the watch restored the logger exactly (level + propagation)
-    logger = logging.getLogger("jax._src.compiler")
-    assert logger.propagate
+    with compilecache.compile_observer("md5", registry=reg) as o1:
+        jax.jit(lambda v: (v ^ jnp.uint32(41)).sum())(
+            x).block_until_ready()
+    assert o1.cache == "miss"
+    # a FRESH jit of the same computation: jax's in-memory cache
+    # cannot serve it, the persistent cache does -> exact hit
+    with compilecache.compile_observer("md5", registry=reg) as o2:
+        jax.jit(lambda v: (v ^ jnp.uint32(41)).sum())(
+            x).block_until_ready()
+    assert o2.cache == "hit"
+    assert reg.get("dprf_compile_cache_hits_total").value(
+        engine="md5") == 1
+    assert reg.get("dprf_compile_cache_misses_total").value(
+        engine="md5") == 1
+    # the observers' watches are gone; the one left is the process
+    # watch that counts every compile while the cache is on
     from dprf_tpu.compilecache import _watch_state
-    assert _watch_state["count"] == 0
+    assert len(_watch_state["watches"]) == 1
+    counts = compilecache.process_cache_counts()
+    assert counts["cache_hits"] >= 1 and counts["cache_misses"] >= 1
+    # while the process watch is on, the compiler's logger still
+    # propagates: its WARNING records reach the operator's handlers,
+    # its DEBUG records (the lines the watch counts) do not
+    jlog = logging.getLogger("jax._src.compiler")
+    assert jlog.propagate
+    seen = []
+
+    class Seen(logging.Handler):
+        def emit(self, record):
+            seen.append(record.getMessage())
+
+    root_handler = Seen(level=logging.DEBUG)
+    logging.getLogger().addHandler(root_handler)
+    try:
+        jlog.warning("compiler says careful")
+        jlog.debug("compiler chatter")
+    finally:
+        logging.getLogger().removeHandler(root_handler)
+    assert seen == ["compiler says careful"]
+    # switching the cache off restores the logger's level
+    level = _watch_state["saved"]
+    compilecache.disable()
+    assert not _watch_state["watches"] and not jlog.filters
+    assert jlog.propagate and jlog.level == level
+    assert compilecache.process_cache_counts() == {
+        "cache_hits": 0, "cache_misses": 0}
+
+
+def test_observer_window_counts_beside_the_process_watch(fresh_cache,
+                                                         monkeypatch):
+    """A logger stops at the first filter that rejects a record, and
+    the hit line is a DEBUG record: the observer's window must still
+    see it while the process watch is installed.  With the wall floor
+    at zero the fallback heuristic would call this window a miss."""
+    from dprf_tpu import compilecache
+    monkeypatch.setenv("DPRF_COMPILE_COLD_FLOOR_S", "0")
+    jlog = logging.getLogger("jax._src.compiler")
+    before = compilecache.process_cache_counts()["cache_hits"]
+    with compilecache.compile_observer("md5", publish=False) as obs:
+        jlog.debug("Persistent compilation cache hit for 'jit_step'")
+    assert obs.cache == "hit"
+    assert compilecache.process_cache_counts()["cache_hits"] == before + 1
 
 
 def test_compile_classifier_falls_back_when_watch_sees_nothing():

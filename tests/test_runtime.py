@@ -337,7 +337,7 @@ def test_coordinator_rejects_unverifiable_hit_and_rescans(tmp_path):
     """A buggy device worker reporting a wrong plaintext must not poison
     the potfile: the local Coordinator re-hashes hits with the CPU
     oracle, rejects the fake, and exactly rescans the unit -- finding
-    the true crack the buggy worker missed (VERDICT r2 weak #3)."""
+    the true crack the buggy worker missed."""
     from dprf_tpu.engines import get_engine
     from dprf_tpu.generators.mask import MaskGenerator
     from dprf_tpu.runtime.coordinator import Coordinator, JobSpec

@@ -180,23 +180,22 @@ def test_superstep_disabled_env(md5_jax, monkeypatch):
     assert _hits_tuple(pu.resolve()) == [(0, gen.index_of(b"cat"), b"cat")]
 
 
-def test_super_build_failure_degrades_to_per_batch(md5_jax):
-    """A backend that rejects the scan-wrapped program must degrade
-    the worker to per-batch dispatch, not kill the job."""
+def test_super_build_failure_raises(md5_jax):
+    """A fused program the compiler refuses raises with its message:
+    the worker runs the one shape its mode names and does not degrade
+    to another."""
     gen = MaskGenerator("?l?l?l?l")
     batch = 1 << 12
     plant = gen.candidate(9 * batch + 4)
     w = _mask_worker(md5_jax, gen, _md5_targets(md5_jax, [plant]), batch)
 
     def broken_super_step(inner):
-        raise RuntimeError("mosaic says no")
+        raise RuntimeError("compiler says no")
 
     w._super_step = broken_super_step
-    hits = w.process(WorkUnit(0, 0, 16 * batch))
-    assert [h.plaintext for h in hits] == [plant]
-    assert w._super_disabled
-    # and the flag sticks: no further super attempts
-    assert w._super_inner(64) == 0
+    with pytest.raises(RuntimeError, match="compiler says no"):
+        w.process(WorkUnit(0, 0, 16 * batch))
+    assert not hasattr(w, "_super_disabled")
 
 
 def test_submit_or_process_wraps_sync_workers():
