@@ -12,7 +12,8 @@ a runtime ValueError, depending on which import runs first.  Rules:
      ONE call site across the package;
   2. every span-name literal passed to a ``.record("...")`` call is a
      member of ``telemetry/trace.py``'s ``SPAN_NAMES`` tuple, which
-     holds no duplicates;
+     holds no duplicates; likewise every ``.station("...")`` literal
+     and the ``STATIONS`` tuple beside it;
   3. every metric an ALERT RULE references (ISSUE 10) -- the
      ``DEFAULT_RULES`` literal pack in ``telemetry/alerts.py`` and
      any ``DPRF_ALERT_RULES``-style fixture file under
@@ -40,7 +41,8 @@ from dprf_tpu.analysis import Finding
 
 NAME = "metrics"
 DESCRIPTION = ("every dprf_* metric declared at one site; every span "
-               "literal is in SPAN_NAMES; every alert rule "
+               "literal is in SPAN_NAMES and every station literal in "
+               "STATIONS; every alert rule "
                "references a declared metric; jax.profiler calls "
                "only in telemetry/profiler.py")
 
@@ -57,7 +59,7 @@ PROFILER_METHODS = {"start_trace", "stop_trace"}
 #: parse prefilter: a file with no metric/record call text cannot
 #: contribute a declaration or span use
 _RELEVANT_RE = re.compile(
-    r"\.(?:counter|gauge|histogram|record)\s*\(")
+    r"\.(?:counter|gauge|histogram|record|station)\s*\(")
 _PROFILER_RE = re.compile(r"\.(?:start_trace|stop_trace|trace)\s*\(")
 
 
@@ -67,7 +69,15 @@ def _literal(node):
     return None
 
 
+#: literal-taking recorder method -> (what its literal names, the
+#: tuple in telemetry/trace.py that declares those names)
+DECLARED_IN = {"record": ("span", "SPAN_NAMES"),
+               "station": ("station", "STATIONS")}
+
+
 def _scan_file(idx):
+    """(metric declarations, uses of declared names): the second as
+    (name, lineno, the recorder method called with it)."""
     decls, span_uses = [], []
     for node in idx.calls:
         if not isinstance(node.func, ast.Attribute):
@@ -76,8 +86,8 @@ def _scan_file(idx):
         if (node.func.attr in METRIC_METHODS and first
                 and first.startswith("dprf_")):
             decls.append((first, node.lineno))
-        elif node.func.attr == "record" and first is not None:
-            span_uses.append((first, node.lineno))
+        elif node.func.attr in DECLARED_IN and first is not None:
+            span_uses.append((first, node.lineno, node.func.attr))
     return decls, span_uses
 
 
@@ -221,12 +231,13 @@ def _check_profiler_discipline(ctx, pkg_dir: str) -> list:
     return out
 
 
-def _declared_span_names(idx):
-    """The SPAN_NAMES tuple, or None when the assignment is missing."""
+def _declared_names(idx, tuple_name: str):
+    """The SPAN_NAMES / STATIONS tuple, or None when the assignment is
+    missing."""
     if idx is None:
         return None
     for node in idx.assigns:
-        if not any(isinstance(t, ast.Name) and t.id == "SPAN_NAMES"
+        if not any(isinstance(t, ast.Name) and t.id == tuple_name
                    for t in node.targets):
             continue
         if isinstance(node.value, (ast.Tuple, ast.List)):
@@ -240,7 +251,7 @@ def run(ctx) -> list:
     pkg_dir = ctx.package_dir
     out = []
     decl_sites: dict = {}    # metric name -> [(rel, line), ...]
-    span_sites = []          # (name, rel, line)
+    span_sites = []          # (name, rel, line, recorder method)
     for path in ctx.package_files():
         try:
             if not _RELEVANT_RE.search(ctx.source(path)):
@@ -254,8 +265,8 @@ def run(ctx) -> list:
         rel = ctx.rel(path)
         for metric, lineno in decls:
             decl_sites.setdefault(metric, []).append((rel, lineno))
-        for span, lineno in span_uses:
-            span_sites.append((span, rel, lineno))
+        for span, lineno, method in span_uses:
+            span_sites.append((span, rel, lineno, method))
 
     for metric, sites in sorted(decl_sites.items()):
         if len(sites) > 1:
@@ -267,27 +278,28 @@ def run(ctx) -> list:
                 "(telemetry.declare_job_metrics pattern)"))
 
     trace_py = os.path.join(pkg_dir, TRACE_REL)
-    span_names = (_declared_span_names(ctx.index(trace_py))
-                  if os.path.exists(trace_py) else None)
-    if span_names is None:
-        if span_sites:
-            out.append(Finding(
-                NAME, ctx.rel(trace_py), 1,
-                f"SPAN_NAMES tuple not found but {len(span_sites)} "
-                ".record(...) call sites exist"))
-    else:
-        dupes = {n for n in span_names if span_names.count(n) > 1}
+    trace_idx = ctx.index(trace_py) if os.path.exists(trace_py) else None
+    for method, (kind, tuple_name) in DECLARED_IN.items():
+        sites = [s for s in span_sites if s[3] == method]
+        names = _declared_names(trace_idx, tuple_name)
+        if names is None:
+            if sites:
+                out.append(Finding(
+                    NAME, ctx.rel(trace_py), 1,
+                    f"{tuple_name} tuple not found but {len(sites)} "
+                    "call sites use its names"))
+            continue
+        dupes = {n for n in names if names.count(n) > 1}
         if dupes:
             out.append(Finding(
                 NAME, ctx.rel(trace_py), 1,
-                f"duplicate SPAN_NAMES entries: {sorted(dupes)}"))
-        allowed = set(span_names)
-        for span, rel, lineno in span_sites:
-            if span not in allowed:
+                f"duplicate {tuple_name} entries: {sorted(dupes)}"))
+        for span, rel, lineno, _ in sites:
+            if span not in names:
                 out.append(Finding(
                     NAME, rel, lineno,
-                    f"span {span!r} not declared in "
-                    "telemetry/trace.py SPAN_NAMES"))
+                    f"{kind} {span!r} not declared in "
+                    f"telemetry/trace.py {tuple_name}"))
 
     # alert rules (default pack + fixture files) must reference
     # declared metrics only

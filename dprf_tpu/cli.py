@@ -919,14 +919,17 @@ def _log_device(device: str, log: Log) -> None:
              kind=devs[0].device_kind)
 
 
-def _log_ran(worker, log: Log, **kw) -> None:
+def _log_ran(worker, log: Log, host: str = "", **kw) -> None:
     """One line at job end saying what ran: worker class, interpret
-    flag, dispatch shapes, compile cost, and every compile of this
-    process as a persistent-cache hit or miss."""
+    flag, dispatch shapes, compile cost, every compile of this
+    process as a persistent-cache hit or miss, and (``host``, from
+    ``trace.format_stations``) the host's self seconds by station of
+    the sweep loop."""
     from dprf_tpu import compilecache
     from dprf_tpu.runtime.worker import describe_worker
     log.info("ran", **kw, **describe_worker(worker),
-             **compilecache.process_cache_counts())
+             **compilecache.process_cache_counts(),
+             **({"host": host} if host else {}))
 
 
 def _select_worker(engine_name: str, device: str, attack: str, gen,
@@ -1354,7 +1357,7 @@ def _crack_increment(args, device: str, log: Log) -> int:
 def _crack_single(args, device: str, log: Log):
     """One crack job; returns (rc, JobResult | None, n_targets)."""
     from dprf_tpu import compilecache
-    from dprf_tpu.telemetry.trace import get_tracer
+    from dprf_tpu.telemetry.trace import format_stations, get_tracer
     compilecache.enable(log=log)
     job = _setup_job(args, device, log)
     if job is None:
@@ -1363,6 +1366,7 @@ def _crack_single(args, device: str, log: Log):
     session, restored_hits = job.session, job.restored_hits
     dispatcher, spec = job.dispatcher, job.spec
     tracer = get_tracer()
+    stations0 = tracer.station_table()
     if session is not None:
         # flight-recorder stream next to the journal (attached BEFORE
         # the worker builds, so warmup-era spans land in the file too)
@@ -1459,7 +1463,8 @@ def _crack_single(args, device: str, log: Log):
         log.warn("job finished with POISONED units parked; their "
                  "ranges were NOT swept (see "
                  "dprf_units_poisoned_total)", parked=result.parked)
-    _log_ran(worker, log)
+    _log_ran(worker, log, host=format_stations(tracer.station_table(),
+                                               since=stations0))
     log.info("job finished",
              found=f"{len(result.found)}/{len(hl.targets)}",
              tested=result.tested, elapsed=f"{result.elapsed:.2f}s",

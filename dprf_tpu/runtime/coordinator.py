@@ -280,7 +280,8 @@ class Coordinator:
                     continue
                 unit, p, t_submit, _ = pipeline.pop()
                 ctx = self.dispatcher.trace_context(unit.unit_id)
-                hits = p.resolve()
+                with self.tracer.station("resolve", unit=unit.unit_id):
+                    hits = p.resolve()
                 now_resolve = time.monotonic()
                 unit_s = now_resolve - t_submit
                 # inter-completion interval: the loop's true drain
@@ -305,7 +306,9 @@ class Coordinator:
                 if hits:
                     t_verify = time.monotonic()
                     rejected0 = self.rejected
-                    self._finish_unit(unit, hits)
+                    with self.tracer.station("verify",
+                                             unit=unit.unit_id):
+                        self._finish_unit(unit, hits)
                     verify_s = time.monotonic() - t_verify
                     self._perf.observe_verify(verify_s,
                                               engine=self.spec.engine,
@@ -334,12 +337,14 @@ class Coordinator:
                 # wait, so the EWMA under-estimates throughput a little
                 # -- which only biases units SMALLER than the target,
                 # the safe direction
-                self.dispatcher.complete(unit.unit_id, elapsed=unit_s)
-                if self.session is not None:
-                    self.session.record_units(
-                        self.dispatcher.completed_intervals(),
-                        job=self.dispatcher.job_id,
-                        digest=self.dispatcher.coverage_digest())
+                with self.tracer.station("complete", unit=unit.unit_id):
+                    self.dispatcher.complete(unit.unit_id,
+                                             elapsed=unit_s)
+                    if self.session is not None:
+                        self.session.record_units(
+                            self.dispatcher.completed_intervals(),
+                            job=self.dispatcher.job_id,
+                            digest=self.dispatcher.coverage_digest())
                 now = time.perf_counter()
                 if self.progress_cb and now - last_report >= self.progress_interval:
                     last_report = now

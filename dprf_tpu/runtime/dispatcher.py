@@ -228,32 +228,33 @@ class Dispatcher:
     def lease(self, worker_id: str = "local") -> Optional[WorkUnit]:
         """Hand out the next unit, or None if nothing is leasable now
         (either exhausted, or all remaining work is outstanding)."""
-        self.reap_expired()
-        if self._pending:
-            unit = heapq.heappop(self._pending)[2]
-        elif self._next_start < self.keyspace:
-            size = (self.sizer.next_size(worker_id)
-                    if self.sizer is not None else self.unit_size)
-            length = min(size, self.keyspace - self._next_start)
-            unit = self._make_unit(self._next_start, length)
-            self._next_start += length
-        else:
-            return None
-        lease_span = self.tracer.record(
-            "lease", trace=self._trace_ids.get(unit.unit_id),
-            proc="coordinator", worker=worker_id, unit=unit.unit_id,
-            job=self.job_id, start=unit.start, length=unit.length,
-            lease_timeout_s=self.lease_timeout,
-            attempt=self._retries.get(unit.unit_id, 0) + 1)
-        self._outstanding[unit.unit_id] = (
-            unit, worker_id, self._clock() + self.lease_timeout,
-            span_id(lease_span))
-        self.coverage.event("lease", unit.start, unit.end,
-                            unit=unit.unit_id)
-        self._m_leased.inc(job=self.job_id)
-        self._g_outstanding.set(len(self._outstanding),
-                                job=self.job_id)
-        return unit
+        with self.tracer.station("lease"):
+            self.reap_expired()
+            if self._pending:
+                unit = heapq.heappop(self._pending)[2]
+            elif self._next_start < self.keyspace:
+                size = (self.sizer.next_size(worker_id)
+                        if self.sizer is not None else self.unit_size)
+                length = min(size, self.keyspace - self._next_start)
+                unit = self._make_unit(self._next_start, length)
+                self._next_start += length
+            else:
+                return None
+            lease_span = self.tracer.record(
+                "lease", trace=self._trace_ids.get(unit.unit_id),
+                proc="coordinator", worker=worker_id, unit=unit.unit_id,
+                job=self.job_id, start=unit.start, length=unit.length,
+                lease_timeout_s=self.lease_timeout,
+                attempt=self._retries.get(unit.unit_id, 0) + 1)
+            self._outstanding[unit.unit_id] = (
+                unit, worker_id, self._clock() + self.lease_timeout,
+                span_id(lease_span))
+            self.coverage.event("lease", unit.start, unit.end,
+                                unit=unit.unit_id)
+            self._m_leased.inc(job=self.job_id)
+            self._g_outstanding.set(len(self._outstanding),
+                                    job=self.job_id)
+            return unit
 
     def lease_many(self, worker_id: str, n: int) -> list:
         """Up to n units for ONE worker in one call -- the RPC

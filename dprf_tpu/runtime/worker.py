@@ -21,6 +21,7 @@ from dprf_tpu.engines.base import HashEngine, Target
 from dprf_tpu.generators.base import CandidateGenerator
 from dprf_tpu.runtime.workunit import WorkUnit
 from dprf_tpu.telemetry import coverage
+from dprf_tpu.telemetry.trace import get_tracer
 
 
 @dataclasses.dataclass(frozen=True)
@@ -51,12 +52,18 @@ class PendingUnit:
             count_dispatches(worker, kind)
 
     def resolve(self) -> list["Hit"]:
-        if self.flag is None or int(self.flag) == 0:
+        if self.flag is None:
+            return []
+        tracer, uid = get_tracer(), self.unit.unit_id
+        with tracer.station("wait", unit=uid):
+            flag = int(self.flag)
+        if flag == 0:
             return []
         hits: list[Hit] = []
-        for kind, start, result in self.queued:
-            hits.extend(self.worker._decode_queued(kind, start, result,
-                                                   self.unit))
+        with tracer.station("decode", unit=uid):
+            for kind, start, result in self.queued:
+                hits.extend(self.worker._decode_queued(
+                    kind, start, result, self.unit))
         return hits
 
 
@@ -240,17 +247,23 @@ class UnitPipeline:
         import time
         t0 = time.monotonic()
         w = worker or self.worker
+        # the pipeline itself never looks into a unit
+        tracer, uid = get_tracer(), getattr(unit, "unit_id", None)
         if probe is not None:
             from dprf_tpu.telemetry.perf import (drain_backlog,
                                                  probe_pending)
-            # the probe's first sync must measure ITS unit, not the
-            # queued units' device backlog: wait for the stream to
-            # drain first (the probe serializes anyway -- this only
-            # moves the wait out of the attributed phases)
-            drain_backlog(self._q)
-            pending = probe_pending(w, unit, probe[0], trace=probe[1])
+            with tracer.station("probe", unit=uid):
+                # the probe's first sync must measure ITS unit, not
+                # the queued units' device backlog: wait for the
+                # stream to drain first (the probe serializes anyway
+                # -- this only moves the wait out of the attributed
+                # phases)
+                drain_backlog(self._q)
+                pending = probe_pending(w, unit, probe[0],
+                                        trace=probe[1])
         else:
-            pending = submit_or_process(w, unit)
+            with tracer.station("submit", unit=uid):
+                pending = submit_or_process(w, unit)
         self._q.append((unit, pending, t0, meta))
 
     def pop(self):

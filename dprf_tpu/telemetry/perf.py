@@ -57,6 +57,7 @@ import time
 from typing import Optional
 
 from dprf_tpu.telemetry import get_registry
+from dprf_tpu.telemetry.trace import get_tracer, new_span_id
 from dprf_tpu.utils import env as envreg
 
 #: attribution phases, in hot-path order; the ONE declaration site for
@@ -138,7 +139,6 @@ class PerfSampler:
 
     def __init__(self, registry=None, recorder=None,
                  every: Optional[int] = None):
-        from dprf_tpu.telemetry.trace import get_tracer
         self.every = sample_every() if every is None else max(0, every)
         self.hist = phase_histogram(registry)
         self.tracer = get_tracer(recorder)
@@ -245,6 +245,7 @@ def _probe_digit(worker, unit) -> tuple:
     hits: list = []
     batches = 0
     perf = time.perf_counter
+    tracer = get_tracer()
     for bstart in range(unit.start, unit.end, worker.stride):
         n_valid = min(worker.stride, unit.end - bstart)
         t0 = perf()
@@ -261,7 +262,8 @@ def _probe_digit(worker, unit) -> tuple:
         _block(result)
         t3 = perf()
         t["device"] += t3 - t2
-        hits.extend(worker._batch_hits(bstart, result, unit))
+        with tracer.station("decode", unit=unit.unit_id):
+            hits.extend(worker._batch_hits(bstart, result, unit))
         t["d2h"] += perf() - t3
         batches += 1
     return t, hits, unit.length, batches
@@ -279,6 +281,7 @@ def _probe_wordlist(worker, unit) -> tuple:
     hits: list = []
     batches = 0
     perf = time.perf_counter
+    tracer = get_tracer()
     w_start, w_end = word_cover_range(unit, worker.gen.n_rules)
     w_end = min(w_end, worker.gen.n_words)
     ws = w_start
@@ -294,7 +297,8 @@ def _probe_wordlist(worker, unit) -> tuple:
         _block(result)
         t2 = perf()
         t["device"] += t2 - t1
-        hits.extend(worker._window_hits(ws, nw, result, unit))
+        with tracer.station("decode", unit=unit.unit_id):
+            hits.extend(worker._window_hits(ws, nw, result, unit))
         t["d2h"] += perf() - t2
         ws += nw
         batches += 1
@@ -338,7 +342,6 @@ def probe_pending(worker, unit, sampler: PerfSampler,
     pre-allocated sweep span id the caller records the sweep under)
     plus the phase histogram, and returns a resolved PendingUnit
     stand-in carrying the spans for RPC shipping."""
-    from dprf_tpu.telemetry.trace import new_span_id
     strategy = _probe_strategy(worker)
     if strategy == "wordlist":
         phases, hits, cands, batches = _probe_wordlist(worker, unit)

@@ -33,6 +33,15 @@ Span schema (one JSON object per line / ring entry)::
 every ``record("...")`` call site uses a declared name and that every
 metric name is declared at exactly one site.
 
+STATIONS (below ``SPAN_NAMES``) names the places of the sweep loop
+where a unit's host time goes; ``TraceRecorder.station(name, unit=)``
+opens one.  A station is a ``jax.profiler.TraceAnnotation``
+``dprf:<name>`` -- so any profiler trace (``--profile``,
+``DPRF_JAX_PROFILE``, a benchmark's slice) shows the host's stations
+on the device trace's own clock -- and a row of the recorder's
+``station_table()``: count and SELF seconds (its child stations taken
+out), which the job's closing ``ran`` line prints as ``host=``.
+
 Overhead: spans are per-UNIT events (a handful per ~20-second unit),
 ``record`` is a dict build + deque append + one buffered file write --
 asserted <= 2% of the local sweep hot path in tests/test_trace.py.
@@ -43,10 +52,12 @@ asserted <= 2% of the local sweep hot path in tests/test_trace.py.
 
 from __future__ import annotations
 
+import contextlib
 import itertools
 import json
 import os
 import secrets
+import sys
 import threading
 import time
 from collections import deque
@@ -61,6 +72,16 @@ from dprf_tpu.utils import env as envreg
 SPAN_NAMES = ("lease", "rpc", "warmup", "sweep", "hit_verify",
               "complete", "fail", "reissue", "park", "phase",
               "restore")
+
+#: the one declaration site for station names (tools/check_metrics.py
+#: holds every station("...") literal to it).  A unit passes them in
+#: this order; ``wait`` and ``decode`` open inside ``resolve`` (and
+#: ``decode`` inside ``probe``, once a batch of a probed unit).  A
+#: station is a unit or a dispatch, never a lane or a batch of a fused
+#: program: a span each would cost what it measures.
+STATIONS = ("lease", "submit", "probe", "resolve", "wait", "decode",
+            "verify", "complete")
+_STATION_LABELS = {name: "dprf:" + name for name in STATIONS}
 
 #: suffix appended to a session journal path for its span stream
 TRACE_SUFFIX = ".trace.jsonl"
@@ -92,7 +113,7 @@ MAX_ID_LEN = 64
 GUARDED_BY = {
     "TraceRecorder": {
         "_lock": ("_ring", "_fh", "_path", "_max_bytes",
-                  "_file_bytes", "_busy"),
+                  "_file_bytes", "_busy", "_stations"),
     },
 }
 
@@ -227,6 +248,58 @@ class _BusyTracker:
                 for proc, iv in self.procs.items()}
 
 
+#: seconds the open stations' CHILDREN took, innermost last, per
+#: thread (stations nest on the thread that opened them, whichever
+#: recorder holds their table)
+_open_stations = threading.local()
+_NO_STATION = contextlib.nullcontext()
+_perf = time.perf_counter
+
+
+def _annotation_type():
+    """``jax.profiler.TraceAnnotation`` where this process has already
+    imported jax, else None: a coordinator that never touches jax
+    must not import it to time its ledger."""
+    profiler = getattr(sys.modules.get("jax"), "profiler", None)
+    return getattr(profiler, "TraceAnnotation", None)
+
+
+class _Station:
+    """One open station (``TraceRecorder.station``)."""
+
+    __slots__ = ("_recorder", "_name", "_annotation", "_t0")
+
+    def __init__(self, recorder, name, annotation):
+        self._recorder = recorder
+        self._name = name
+        self._annotation = annotation
+
+    def __enter__(self):
+        try:
+            _open_stations.stack.append(0.0)
+        except AttributeError:
+            _open_stations.stack = [0.0]
+        if self._annotation is not None:
+            self._annotation.__enter__()
+        self._t0 = _perf()
+
+    def __exit__(self, *exc):
+        dur = _perf() - self._t0
+        if self._annotation is not None:
+            self._annotation.__exit__(*exc)
+        stack = _open_stations.stack
+        children = stack.pop()
+        if stack:
+            stack[-1] += dur
+        rec = self._recorder
+        with rec._lock:
+            row = rec._stations.get(self._name)
+            if row is None:
+                row = rec._stations[self._name] = [0, 0.0]
+            row[0] += 1
+            row[1] += dur - children
+
+
 class TraceRecorder:
     """Bounded flight-recorder ring + optional JSONL stream.
 
@@ -251,6 +324,8 @@ class TraceRecorder:
         #: live device-utilization state: sweep spans fold into a
         #: sliding-window interval union per worker (ISSUE 9)
         self._busy = _BusyTracker()
+        #: station name -> [times opened, self seconds]
+        self._stations: dict = {}
         from dprf_tpu.telemetry import get_registry
         self._m_spans = get_registry(registry).counter(
             "dprf_trace_spans_total",
@@ -293,6 +368,31 @@ class TraceRecorder:
                 "attrs": attrs}
         self._append(span)
         return span
+
+    def station(self, name: str, unit: Optional[int] = None):
+        """Context manager around one station of a unit's way through
+        the sweep loop (``STATIONS``).  While a profiler trace runs it
+        is an event ``dprf:<name>`` of the host's plane, carrying the
+        unit's id, on the trace's own clock; with none running the
+        annotation is a flag test.  Either way it adds one to the
+        station's count and its self seconds to ``station_table()``.
+        Disabled (``DPRF_TRACE=0``) it does nothing."""
+        if not self.enabled:
+            return _NO_STATION
+        label = _STATION_LABELS[name]
+        annotation = _annotation_type()
+        if annotation is not None:
+            annotation = (annotation(label) if unit is None
+                          else annotation(label, unit=unit))
+        return _Station(self, name, annotation)
+
+    def station_table(self) -> dict:
+        """{station: (times opened, self seconds)} since the recorder
+        was made, in ``STATIONS`` order, stations never opened left
+        out."""
+        with self._lock:
+            return {name: tuple(self._stations[name])
+                    for name in STATIONS if name in self._stations}
 
     def ingest(self, spans, proc: Optional[str] = None,
                sent_at=None, limit: Optional[int] = None) -> int:
@@ -515,6 +615,7 @@ class TraceRecorder:
         with self._lock:
             self._ring.clear()
             self._busy.procs.clear()
+            self._stations.clear()
 
 
 #: process-wide recorder, like telemetry.DEFAULT: library code with no
@@ -524,6 +625,17 @@ DEFAULT_TRACER = TraceRecorder()
 
 def get_tracer(recorder: Optional[TraceRecorder] = None) -> TraceRecorder:
     return recorder if recorder is not None else DEFAULT_TRACER
+
+
+def format_stations(table: dict, since: Optional[dict] = None) -> str:
+    """``lease:0.016,submit:1.920,..``: self seconds by station, for a
+    job's ``ran`` line; ``since`` is the table as the job began.
+    Empty where no station was opened."""
+    since = since or {}
+    return ",".join(
+        f"{name}:{seconds - since.get(name, (0, 0.0))[1]:.3f}"
+        for name, (count, seconds) in table.items()
+        if count > since.get(name, (0, 0.0))[0])
 
 
 def span_id(span: Optional[dict]) -> Optional[str]:
