@@ -168,9 +168,11 @@ def check_ran(smoke, log, workers, fused=None, advance=None):
     none has no kernel: a failure), where the phase names one the
     fused dispatch shape, with no other fused shape beside it (a
     degraded dispatch would show up here as `wide`, `scan` or
-    per-batch only), and for bcrypt the implementation of its cost
+    per-batch only), for bcrypt the implementation of its cost
     loop (`pallas`, never the `xla` form the same worker class can
-    carry)."""
+    carry), and for a target list no collided kernel tile hashed
+    whole on the host (`verify=..,host_tiles:0`: the phases' engines
+    all have the device re-probe)."""
     dev = log["device"]
     if dev is None or dev["platform"] != smoke.platform:
         raise PhaseError(f"device path ran on {dev}, not on a "
@@ -187,6 +189,9 @@ def check_ran(smoke, log, workers, fused=None, advance=None):
     if advance is not None and ran.get("advance") != advance:
         raise PhaseError(f"advance={ran.get('advance')}: the cost loop "
                          f"is not the {advance} kernel")
+    if _shapes(ran.get("verify", "")).get("host_tiles"):
+        raise PhaseError(f"verify={ran['verify']}: collided tiles went "
+                         "to the host oracle, not to the device re-probe")
     shapes = _shapes(ran["dispatch"])
     if fused is not None:
         others = set(shapes) - {fused, "batch", "probe"}

@@ -260,7 +260,24 @@ def md5_compress_lanes(state, m):
     return tuple(x + s for x, s in zip(out, state))
 
 
-def gather256(lo, hi, idx):
+def take_lanes(x, idx):
+    """x[s, idx[s, l]] for (sub, 128) tiles: the hardware's native
+    per-sublane gather, the lookup of every kernel body."""
+    return jnp.take_along_axis(x, idx, axis=1)
+
+
+def select_lanes(x, idx):
+    """take_lanes with no gather, for a kernel body run as plain XLA
+    (make_tile_reprobe): 128 compare-selects a lane.  The TPU compiler
+    wraps every XLA gather's indices in a custom call, and a program's
+    custom calls are what a device trace reads as its kernel."""
+    at = idx[..., None] == jax.lax.broadcasted_iota(
+        jnp.int32, idx.shape + (128,), idx.ndim)
+    return jnp.sum(jnp.where(at, x[:, None, :], jnp.zeros((), x.dtype)),
+                   axis=-1, dtype=x.dtype)
+
+
+def gather256(lo, hi, idx, take=take_lanes):
     """Per-sublane 256-entry lookup: table halves lo/hi uint32[sub, 128]
     with the ENTRY INDEX along lanes, idx uint32[sub, 128] in 0..255 ->
     values uint32[sub, 128].  The hardware's native per-sublane
@@ -268,8 +285,8 @@ def gather256(lo, hi, idx):
     by the bcrypt/krb5 kernels; shared by the RC4 kernels (krb5, pdf)
     and the LUT charset decode."""
     idx7 = (idx & jnp.uint32(127)).astype(jnp.int32)
-    glo = jnp.take_along_axis(lo, idx7, axis=1)
-    ghi = jnp.take_along_axis(hi, idx7, axis=1)
+    glo = take(lo, idx7)
+    ghi = take(hi, idx7)
     return jnp.where(idx < jnp.uint32(128), glo, ghi)
 
 
@@ -282,13 +299,13 @@ def swap256(lo, hi, pos, val, lane):
     return lo, hi
 
 
-def _lut_byte(digit, lo_row, hi_row):
+def _lut_byte(digit, lo_row, hi_row, take=take_lanes):
     """Lane-axis LUT lookup for int32 digit tiles of shape (sub, 128):
     rows are (128,) uint32 halves of the 256-entry table."""
     shape = digit.shape
     return gather256(jnp.broadcast_to(lo_row[None, :], shape),
                      jnp.broadcast_to(hi_row[None, :], shape),
-                     digit.astype(jnp.uint32))
+                     digit.astype(jnp.uint32), take)
 
 
 def kernel_eligible(engine_name: str, gen, n_targets: int) -> bool:
@@ -363,7 +380,7 @@ def kernel_probe_rows(twords: np.ndarray, fp: Optional[float] = None):
 
 
 def probe_block_found(digest, rows, valid, block_bits: int, k: int,
-                      n_grp: int, shape):
+                      n_grp: int, shape, take=take_lanes):
     """In-kernel blocked-Bloom probe over kernel_probe_rows state: a
     lane survives iff all k double-hashed bits of its block are set.
     Real hits always survive (their bits were set from the matching
@@ -391,7 +408,7 @@ def probe_block_found(digest, rows, valid, block_bits: int, k: int,
         for g in range(n_grp):
             row = jnp.broadcast_to(rows[g * BLOCK_WORDS + w][None, :],
                                    shape)
-            got = jnp.take_along_axis(row, lane_idx, axis=1)
+            got = take(row, lane_idx)
             acc = got if acc is None else jnp.where(grp == g, got, acc)
         bw.append(acc)
     found = valid
@@ -414,7 +431,7 @@ _decode_byte = segment_mux
 
 
 def decode_candidate_bytes(radices, seg_tables, length: int, base, carry,
-                           luts=None):
+                           luts=None, take=take_lanes):
     """Mixed-radix add (base digits + per-lane carry) fused with the
     per-position charset lookup, least significant position first --
     the shared decode of every mask kernel body.  seg_tables entries
@@ -430,7 +447,8 @@ def decode_candidate_bytes(radices, seg_tables, length: int, base, carry,
         t = seg_tables[p]
         if isinstance(t, tuple) and t[0] == "lut":
             byts[p] = _lut_byte(d, lut_arr[2 * t[1]],
-                                lut_arr[2 * t[1] + 1]).astype(jnp.uint32)
+                                lut_arr[2 * t[1] + 1],
+                                take).astype(jnp.uint32)
         else:
             byts[p] = _decode_byte(d, t).astype(jnp.uint32)
         carry = s // r
@@ -461,18 +479,24 @@ def _pack_message(byts, length: int, shape, big_endian: bool,
 
 
 def _build_kernel_body(engine_name: str, radices, seg_tables, length: int,
-                       target, sub: int, probe=None):
+                       target, sub: int, probe=None, take=take_lanes):
     """The kernel math as a PURE function of (pid, base digits, n_valid
     [, offset]) -> (count, hit_lane) scalars.  Shared verbatim by the
     pallas_call wrapper (TPU) and by emulate_mask_kernel (eager CPU
     validation -- XLA:CPU cannot compile the statically-unrolled
     SHA-256 graph in reasonable time, so correctness tests drive this
-    body op-by-op).
+    body op-by-op).  ``kernel_body.found_lanes`` is the same math up
+    to the per-lane (found, lane) tiles, before the reduction to one
+    lane a tile: make_tile_reprobe compacts those.
 
     probe: the (block_bits, k, n_grp) geometry from kernel_probe_rows
     for a multi-target job -- the compare runs the blocked probe
     (`tables` holds the probe rows) and every survivor is a maybe the
     caller verifies on the host; None for a single target.
+
+    take: the lane lookup of the probe and the charset LUT decode
+    (take_lanes in a kernel; select_lanes where the body runs as
+    plain XLA).  Same values either way.
 
     An `offset` scalar (the sharded/superstep window start) shifts
     both the decoded keyspace index and the validity bound, so ONE
@@ -495,7 +519,7 @@ def _build_kernel_body(engine_name: str, radices, seg_tables, length: int,
             raise ValueError(f"{engine_name}: expected {n_words} "
                              "target words")
 
-    def kernel_body(pid, base, n_valid, tables=None, luts=None,
+    def found_lanes(pid, base, n_valid, tables=None, luts=None,
                     offset=None):
         shape = (sub, 128)
         lane = (jax.lax.broadcasted_iota(jnp.int32, shape, 0) * 128
@@ -507,7 +531,7 @@ def _build_kernel_body(engine_name: str, radices, seg_tables, length: int,
         if offset is not None:
             gidx = gidx + offset
         byts = decode_candidate_bytes(radices, seg_tables, length,
-                                      base, gidx, luts)
+                                      base, gidx, luts, take)
         m = _pack_message(byts, length, shape, big_endian, widen,
                           32 if engine_name in WIDE_BLOCK else 16)
         digest = core(m, shape)
@@ -518,13 +542,20 @@ def _build_kernel_body(engine_name: str, radices, seg_tables, length: int,
                 found = found & (got == jnp.uint32(want))
         else:
             found = probe_block_found(digest, tables, valid, *probe,
-                                      shape)
+                                      shape, take)
+        return found, lane
+
+    def kernel_body(pid, base, n_valid, tables=None, luts=None,
+                    offset=None):
+        found, lane = found_lanes(pid, base, n_valid, tables, luts,
+                                  offset)
         count = jnp.sum(found.astype(jnp.int32))
         # single-hit extraction: max lane among hits (-1 if none); the
         # caller rescans any tile whose count exceeds 1.
         hit_lane = jnp.max(jnp.where(found, lane, -1))
         return count, hit_lane
 
+    kernel_body.found_lanes = found_lanes
     return kernel_body
 
 
@@ -744,7 +775,10 @@ def make_pallas_multi_crack_step(engine_name: str, gen,
     Contract (see PallasMaskWorker): each maybe lane holds >= 0
     candidates that passed the in-kernel prefilter and must be
     verified by ONE host oracle hash; each collided tile (>= 2 maybes)
-    must be exactly rescanned over its TILE-candidate range.
+    must be resolved to its maybe lanes, each verified the same way:
+    on the device by make_tile_reprobe, which runs this kernel's body
+    over the tile (the worker rescans the tile's TILE candidates on
+    the host oracle only where that re-probe disagrees or overflows).
     n_single > hit_capacity or n_collided > rescan_capacity means the
     whole batch needs the exact rescan.
 
@@ -788,6 +822,65 @@ def make_pallas_multi_crack_step(engine_name: str, gen,
                                   rescan_capacity, tile)
 
     return step
+
+
+def make_tile_reprobe(engine_name: str, gen, target_words: np.ndarray,
+                      sub: Optional[int] = None, capacity: int = 16,
+                      probe_fp: Optional[float] = None):
+    """The collided-tile re-probe: reprobe(base_digits int32[L],
+    n_valid) -> (count int32, lanes int32[capacity]) over ONE tile of
+    sub * 128 lanes that starts at base_digits, lanes tile-relative,
+    unused slots -1.
+
+    The kernel reports one lane a tile, so a tile in which two or more
+    lanes passed the probe bitmap comes back as its index alone.  This
+    runs the kernel's own body (_build_kernel_body: decode, pack, hash
+    core, probe_block_found over the same kernel_probe_rows) over that
+    tile and compacts every surviving lane, so count is the count the
+    kernel saw and the caller verifies count lanes on the oracle, not
+    the tile's width.  count > capacity: the buffer is truncated.
+
+    A plain jax.jit, not a pallas_call, and with no gather in it
+    (select_lanes): 16,384 lanes a collided tile, a few tiles a unit,
+    is no work, and the step's kernel stays the programs' one custom
+    call.  The probe rows (and charset LUT rows) are arguments of the
+    jitted program, not constants of it: one executable serves every
+    target list of the same probe geometry.  ``reprobe.lower`` lowers
+    it for an ahead-of-time compile."""
+    from dprf_tpu.ops import compare as cmp_ops
+
+    if engine_name not in CORES:
+        raise ValueError(f"{engine_name}: the tile re-probe covers the "
+                         "CORES engines only")
+    sub = SUB if sub is None else sub
+    target_words = np.asarray(target_words)
+    rows, block_bits, k, n_grp, _ = kernel_probe_rows(target_words,
+                                                      probe_fp)
+    seg_tables, luts_np = position_tables(gen.charsets)
+    body = _build_kernel_body(engine_name, gen.radices, seg_tables,
+                              gen.length, target_words, sub,
+                              probe=(block_bits, k, n_grp),
+                              take=select_lanes)
+
+    @jax.jit
+    def probe(base_digits, n_valid, tables, luts):
+        found, _ = body.found_lanes(0, base_digits.astype(jnp.int32),
+                                    n_valid.astype(jnp.int32), tables,
+                                    luts)
+        found = found.reshape(-1)         # row-major: the lane index
+        count, lanes, _ = cmp_ops.compact_hits(
+            found, jnp.zeros(found.shape, jnp.int32), capacity)
+        return count, lanes
+
+    tables_dev = jnp.asarray(rows)
+    luts_dev = jnp.asarray(luts_np) if luts_np is not None else None
+
+    def reprobe(base_digits, n_valid):
+        return probe(base_digits, n_valid, tables_dev, luts_dev)
+
+    reprobe.lower = lambda base_digits, n_valid: probe.lower(
+        base_digits, n_valid, tables_dev, luts_dev)
+    return reprobe
 
 
 def reduce_tile_maybes(counts: jnp.ndarray, hit_lanes: jnp.ndarray,
@@ -838,11 +931,13 @@ def make_shard_mask_compute(engine_name: str, gen,
     SENTINEL-tagged (payload == n_targets, out of range) -- the
     workers' lane decode verifies each with one oracle hash.  A tile
     holding 2+ maybes comes back tagged n_targets + 1 with the TILE'S
-    first lane, and the worker rescans that one tile on the host: at
-    the probe's false-positive rate a collided tile turns up every few
-    hundred batches, and redriving down to a stride-wide host rescan
-    (n_dev x batch candidates on the oracle) for each would never
-    end."""
+    first lane, and the worker re-probes that one tile on the device
+    (make_tile_reprobe: its maybe lanes, one oracle hash each; the
+    host rescans the tile only where the re-probe disagrees or
+    overflows): at the probe's false-positive rate a collided tile
+    turns up every few hundred batches, and redriving down to a
+    stride-wide host rescan (n_dev x batch candidates on the oracle)
+    for each would never end."""
     if engine_name not in CORES:
         raise ValueError(f"{engine_name}: sharded kernel computes "
                          "cover the CORES engines only")

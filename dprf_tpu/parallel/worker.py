@@ -24,8 +24,8 @@ from typing import Optional, Sequence
 import numpy as np
 
 from dprf_tpu.engines.base import HashEngine, Target
-from dprf_tpu.runtime.worker import (CpuWorker, Hit, MaskWorkerBase,
-                                     PendingUnit, WordlistWorkerBase,
+from dprf_tpu.runtime.worker import (Hit, MaskWorkerBase, PendingUnit,
+                                     WordlistWorkerBase,
                                      word_cover_range)
 from dprf_tpu.runtime.workunit import WorkUnit
 from dprf_tpu.telemetry import coverage
@@ -106,8 +106,10 @@ class ShardedMaskWorker(_ShardedSuperstepMixin, MaskWorkerBase):
     generate, hash, and compare(+probe) in VMEM, the host ships one
     digit vector per superstep window.  Multi-target kernel hits come
     back SENTINEL-tagged (in-kernel blocked-probe survivors: one
-    oracle hash each; a tile with 2+ survivors is rescanned whole), so
-    an oracle engine is required to verify them."""
+    oracle hash each; a tile with 2+ survivors is re-probed on the
+    device for its survivors, MaskWorkerBase._reprobe_tiles, and
+    rescanned whole on the host only where that disagrees), so an
+    oracle engine is required to verify them."""
 
     def __init__(self, engine, gen, targets: Sequence[Target], mesh,
                  batch_per_device: int = 1 << 18, hit_capacity: int = 64,
@@ -147,7 +149,10 @@ class ShardedMaskWorker(_ShardedSuperstepMixin, MaskWorkerBase):
                 twords = np.asarray(tgt)
             sub = kernel.get("sub") or SUB
             self._interpret = bool(kernel.get("interpret", False))
-            tile = sub * 128
+            tile = self._tile = sub * 128
+            if self.multi:
+                self._setup_tile_reprobe(twords, sub,
+                                         kernel.get("probe_fp"))
             batch_per_device = max(tile,
                                    (batch_per_device // tile) * tile)
             self.mesh = mesh
@@ -245,26 +250,19 @@ class ShardedMaskWorker(_ShardedSuperstepMixin, MaskWorkerBase):
         hits: list[Hit] = []
         # kernel multi-target compute: payload n_targets + 1 marks a
         # COLLIDED tile (2+ probe survivors, one reportable lane) by
-        # its first lane -- rescan exactly that tile on the oracle
+        # its first lane -- re-probe exactly that tile for its
+        # survivors (dispatched first, read back last: the other
+        # lanes are verified while the re-probes wait on the device)
         tile = getattr(self.step, "tile", 0) if self.multi else 0
         rescan = (tpos_np == len(self.targets) + 1) & (lanes_np >= 0) \
             if tile else np.zeros_like(lanes_np, bool)
+        tiles = self._reprobe_tiles(
+            [bstart + int(lane) for lane in lanes_np[rescan]], unit)
         for d in range(lanes_np.shape[0]):
             hits.extend(self._decode_lanes(
                 bstart, np.where(rescan[d], -1, lanes_np[d]), tpos_np[d]))
-        for lane in lanes_np[rescan]:
-            hits.extend(self._rescan_tile(bstart + int(lane), unit))
+        hits.extend(self._tile_hits(tiles, unit))
         return hits
-
-    def _rescan_tile(self, start: int, unit: WorkUnit) -> list[Hit]:
-        """Exact host rescan of ONE collided kernel tile (clipped to
-        the unit), noted as deliberate re-coverage."""
-        end = min(start + self.step.tile, unit.end)
-        if end <= start:
-            return []
-        coverage.note("rescan", start, end, unit=unit.unit_id)
-        return CpuWorker(self.oracle, self.gen, self.targets).process(
-            WorkUnit(-1, start, end - start))
 
 
 class ShardedCombinatorWorker(ShardedMaskWorker):

@@ -103,9 +103,12 @@ def describe_worker(worker) -> dict:
     kernel, its step is plain XLA), the dispatch shapes
     used so far with their counts (`probe`: the per-batch, synced
     dispatches of the sampled units, telemetry/perf.py), and the
-    compile cost with its persistent-cache classification.  A job that
-    ran a slower path than the one expected must be readable from its
-    own log."""
+    compile cost with its persistent-cache classification, and for a
+    multi-target job what the host verified (`verify`: oracle hashes
+    of maybe lanes, collided tiles resolved to their maybe lanes on
+    the device, collided tiles rescanned whole on the host).  A job
+    that ran a slower path than the one expected must be readable
+    from its own log."""
     w = getattr(worker, "_worker", worker)      # OrderedWorker
     counts = getattr(w, "dispatches", None) or {}
     out = {
@@ -116,6 +119,9 @@ def describe_worker(worker) -> dict:
         "compile_s": f"{getattr(w, 'compile_seconds', 0.0):.2f}",
         "cache": getattr(w, "compile_cache", "off"),
     }
+    if getattr(w, "verify_counts", None):
+        out["verify"] = ",".join(f"{k}:{n}" for k, n in
+                                 w.verify_counts.items())
     if hasattr(w, "advance_impl"):    # bcrypt: "pallas" | "xla"
         out["advance"] = w.advance_impl
     if hasattr(w, "out_devices"):     # sharded workers (parallel/)
@@ -405,6 +411,17 @@ class MaskWorkerBase:
     #: override
     ATTACK = "mask"
 
+    #: lanes one collided-tile re-probe can return (beside
+    #: PallasMaskWorker.RESCAN_CAPACITY, the tiles a batch can
+    #: report): a tile expects 0.016 maybes at the kernel probe's
+    #: false-positive rate, so 16 is ample
+    TILE_LANES = 16
+
+    #: the collided-tile re-probe (ops/pallas_mask.make_tile_reprobe);
+    #: None where the step has none (single target, XLA steps,
+    #: pallas_ext steps): collided tiles are then rescanned on the host
+    _reprobe = None
+
     def _setup_targets(self, engine, gen, targets: Sequence[Target],
                        hit_capacity: int, oracle: Optional[HashEngine],
                        probe_ok: bool = False):
@@ -418,6 +435,10 @@ class MaskWorkerBase:
         self.oracle = oracle
         digests = [t.digest for t in self.targets]
         self.multi = len(digests) > 1
+        if self.multi:
+            #: what the host verified (describe_worker's `verify=`)
+            self.verify_counts = {"lanes": 0, "tiles": 0,
+                                  "host_tiles": 0}
         if self.multi and probe_ok:
             ptable = self._setup_probe(digests)
             if ptable is not None:
@@ -497,6 +518,10 @@ class MaskWorkerBase:
         with compile_observer(getattr(self.engine, "name",
                                       "unknown")) as obs:
             hard_sync(self.step(*args))
+            if self._reprobe is not None:
+                # same (base digits, n_valid) arguments: the re-probe
+                # compiles here, never inside a job
+                hard_sync(self._reprobe(*args))
         #: warmup/compile wall time; tune/autotuner.sweep folds it into
         #: a rung's fixed cost (covers workers warmed before the
         #: sweep's own clock started)
@@ -539,10 +564,14 @@ class MaskWorkerBase:
         from dprf_tpu.compilecache import compile_observer
         t0 = time.perf_counter()
         lowered = lower(*args)
+        relowered = (self._reprobe.lower(*args)
+                     if self._reprobe is not None else None)
         trace_s = time.perf_counter() - t0
         with compile_observer(getattr(self.engine, "name",
                                       "unknown")) as obs:
             compiled = lowered.compile()
+            if relowered is not None:
+                relowered.compile()
         #: the XLA compile alone -- what the persistent cache
         #: eliminates (trace/lower cost is irreducible host Python)
         self.xla_compile_seconds = obs.seconds
@@ -861,8 +890,74 @@ class MaskWorkerBase:
                 "unverified probe-table survivor and no oracle engine "
                 "to resolve it with")
         plain = self.gen.candidate(gidx)
+        self.verify_counts["lanes"] += 1
         ti = self._digest_map.get(self.oracle.hash_batch([plain])[0])
         return [Hit(ti, gidx, plain)] if ti is not None else []
+
+    def _setup_tile_reprobe(self, twords, sub: int,
+                            probe_fp: Optional[float] = None) -> None:
+        """Multi-target kernel workers: build the device re-probe of a
+        collided tile from the step's own kernel body, at the step's
+        tile and probe geometry.  Steps from ops/pallas_ext (engines
+        outside CORES) have no such body and keep the host rescan."""
+        from dprf_tpu.ops.pallas_mask import CORES, make_tile_reprobe
+        if self.engine.name in CORES:
+            self._reprobe = make_tile_reprobe(
+                self.engine.name, self.gen, twords, sub,
+                self.TILE_LANES, probe_fp)
+
+    def _reprobe_tiles(self, starts, unit: WorkUnit) -> list:
+        """Dispatch the device re-probe of each collided tile (a tile
+        of self._tile candidates from `start`, clipped to the unit)
+        and return the pending entries for _tile_hits.  Enqueue only:
+        the caller verifies its single maybes while these wait behind
+        the next unit's program."""
+        import jax.numpy as jnp
+        pending = []
+        for start in starts:
+            end = min(start + self._tile, unit.end)
+            if end <= start:
+                continue
+            out = None
+            if self._reprobe is not None:
+                # coverage note (ISSUE 19): the tile is swept a second
+                # time, on the device -- deliberate re-coverage
+                coverage.note("rescan", start, end, unit=unit.unit_id,
+                              kind="device")
+                out = self._reprobe(
+                    jnp.asarray(self.gen.digits(start), dtype=jnp.int32),
+                    jnp.int32(end - start))
+            pending.append((start, end, out))
+        return pending
+
+    def _tile_hits(self, pending: list, unit: WorkUnit) -> list[Hit]:
+        """Read the re-probes back: every maybe lane of a collided
+        tile takes the single-maybe path (one oracle hash each).  A
+        lane that fails the probe bitmap cannot be a target (the
+        bitmap has no false negatives), so that is the tile's exact
+        answer.  A re-probe that disagrees with the kernel (fewer than
+        the two lanes that made the tile collided) or overflowed its
+        buffer, and a step with no re-probe, take the exact host
+        rescan of the tile."""
+        import jax
+        hits: list[Hit] = []
+        # one readback for all of them: the device has nothing queued
+        # behind the last re-probe until this unit is finished
+        outs = jax.device_get([out for _, _, out in pending])
+        for (start, end, _), out in zip(pending, outs):
+            if out is not None and 2 <= out[0] <= out[1].shape[0]:
+                self.verify_counts["tiles"] += 1
+                for lane in out[1]:
+                    if lane >= 0:
+                        hits.extend(
+                            self._verify_probe_lane(start + int(lane)))
+                continue
+            self.verify_counts["host_tiles"] += 1
+            coverage.note("rescan", start, end, unit=unit.unit_id,
+                          kind="host")
+            hits.extend(CpuWorker(self.oracle, self.gen, self.targets)
+                        .process(WorkUnit(-1, start, end - start)))
+        return hits
 
     def _rescan(self, bstart: int, unit: WorkUnit,
                 window: int = 0) -> list[Hit]:
@@ -1176,7 +1271,13 @@ class PallasMaskWorker(MaskWorkerBase):
     by DPRF_PALLAS_PROBE_FP); each single-maybe lane is
     verified here with ONE oracle hash against the target digest map,
     and each collided tile (>= 2 maybes, including any tile with two
-    real hits) is exactly rescanned over its TILE-candidate range.
+    real hits) is re-probed on the device (_reprobe_tiles: the
+    kernel's body over that one tile, returning its maybe lanes), each
+    of which is verified the same way.  The exact host rescan of a
+    tile's whole TILE-candidate range remains for a re-probe that
+    disagrees with the kernel or overflows TILE_LANES, and for the
+    ops/pallas_ext steps (engines outside CORES), which have no
+    re-probe; `verify=` on the job's `ran` line counts each.
     """
 
     RESCAN_CAPACITY = 16
@@ -1212,6 +1313,7 @@ class PallasMaskWorker(MaskWorkerBase):
                                      for t in self.targets])
             self._digest_map = {t.digest: i
                                 for i, t in enumerate(self.targets)}
+            self._setup_tile_reprobe(self._twords, self._sub)
         else:
             self._twords = np.asarray(tgt)
         self.step = self._make_step(batch)
@@ -1288,25 +1390,19 @@ class PallasMaskWorker(MaskWorkerBase):
             if window > self.stride:
                 return self._redrive_wide(bstart, window, unit)
             return self._rescan(bstart, unit, window)  # pathological
+        # the re-probes go out first and are read back last: they
+        # queue behind the next unit's program, and the single maybes
+        # are verified inside that wait
+        tiles = self._reprobe_tiles(
+            [bstart + int(t) * self._tile for t in np.asarray(ctiles)
+             if t >= 0], unit)
         hits: list[Hit] = []
         for lane in np.asarray(lanes):
-            if lane < 0:
-                continue
-            # one oracle hash verifies a probe maybe exactly (and
-            # resolves its target index); false positives drop here
-            gidx = bstart + int(lane)
-            plain = self.gen.candidate(gidx)
-            ti = self._digest_map.get(self.oracle.hash_batch([plain])[0])
-            if ti is not None:
-                hits.append(Hit(ti, gidx, plain))
-        for t in np.asarray(ctiles):
-            if t < 0:
-                continue
-            start = bstart + int(t) * self._tile
-            end = min(start + self._tile, unit.end)
-            sub = WorkUnit(-1, start, end - start)
-            hits.extend(CpuWorker(self.oracle, self.gen,
-                                  self.targets).process(sub))
+            if lane >= 0:
+                # one oracle hash verifies a probe maybe exactly (and
+                # resolves its target index); false positives drop
+                hits.extend(self._verify_probe_lane(bstart + int(lane)))
+        hits.extend(self._tile_hits(tiles, unit))
         return hits
 
 

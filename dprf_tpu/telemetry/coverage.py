@@ -114,8 +114,9 @@ COVERAGE_EVENT_SITES = (
     ("dprf_tpu/runtime/worker.py", "_rescan"),
     ("dprf_tpu/runtime/worker.py", "_redrive_wide_words"),
     ("dprf_tpu/runtime/worker.py", "_rescan_words"),
+    ("dprf_tpu/runtime/worker.py", "_reprobe_tiles"),
+    ("dprf_tpu/runtime/worker.py", "_tile_hits"),
     ("dprf_tpu/parallel/worker.py", "_redrive_sharded_words"),
-    ("dprf_tpu/parallel/worker.py", "_rescan_tile"),
     # every submit() in the sharded module notes its superstep /
     # per-batch dispatch windows ("window" tiling evidence); the
     # sharded word rescan is the inherited WordlistWorkerBase

@@ -105,10 +105,10 @@ def test_sharded_kernel_multi_probe_boundaries(mesh):
 def test_sharded_kernel_multi_collided_tiles_rescan_one_tile(mesh):
     """Two (and three) probe survivors INSIDE one tile: the tile can
     report one lane only, so it comes back tagged as collided and the
-    worker rescans exactly that tile on the oracle -- in a fused
-    window and in the per-batch tail, with a tile cut by the unit's
-    end.  Every plant once, no window redrive, no stride-wide host
-    rescan."""
+    worker re-sweeps exactly that tile (on the device since PR 27:
+    tests/test_tile_reprobe.py) -- in a fused window and in the
+    per-batch tail, with a tile cut by the unit's end.  Every plant
+    once, no window redrive, no stride-wide host rescan."""
     gen = MaskGenerator("?d?d?d?d?d")       # 100000
     B = 8 * 128
     stride = 8 * B

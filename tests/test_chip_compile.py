@@ -205,6 +205,18 @@ def test_ntlm_1k_loop_superstep(one_chip):
     _compile(ls.lower(_sds(one_chip, (7,)), _sds(one_chip)))
 
 
+def test_ntlm_1k_tile_reprobe_has_no_custom_call(one_chip):
+    """Config 2's collided-tile re-probe at the production tile: the
+    kernel's body as plain XLA.  The benchmark's trace reads every
+    custom call of a program as the hash kernel at `--batch` lanes,
+    and the TPU compiler wraps an XLA gather's indices in one, so the
+    re-probe must compile to none."""
+    w = _mask_worker("ntlm", "?a?a?a?a?a?a?a", _ntlm_1k_targets())
+    _, text = _compile(
+        w._reprobe.lower(_sds(one_chip, (7,)), _sds(one_chip)), kernels=0)
+    assert "custom-call" not in text and "gather(" not in text
+
+
 def test_nested_1k_targets_kernel(one_chip, as_tpu):
     """The pallas_ext multi-target step (md5(md5($p)), 1,000 uniform
     targets): the same in-kernel probe bitmap as the CORES kernels."""

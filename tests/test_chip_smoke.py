@@ -117,11 +117,14 @@ def test_read_log_and_check_ran(cs, tmp_path):
     (("dispatch=batch:2,loop:2", "dispatch=batch:2,loop:1,wide:1"),
      "fused shape"),
     (("dispatch=batch:2,loop:2", "dispatch=scan:2"), "fused shape"),
+    (("compile_s=", "verify=lanes:300,tiles:1,host_tiles:2 compile_s="),
+     "host oracle"),
 ])
 def test_check_ran_fails_a_hidden_fallback(cs, tmp_path, edit, why):
-    """The XLA worker, an interpreted kernel, another platform, or a
-    dispatch that fell from `loop` to `wide`, `scan` or per-batch: each
-    is a failed phase, never a slower pass."""
+    """The XLA worker, an interpreted kernel, another platform, a
+    dispatch that fell from `loop` to `wide`, `scan` or per-batch, or
+    collided tiles hashed whole on the host: each is a failed phase,
+    never a slower pass."""
     s = cs.Smoke(str(tmp_path))
     log = cs.read_log(LOG.replace(*edit))
     with pytest.raises(cs.PhaseError, match=why):
