@@ -162,7 +162,8 @@ def _shapes(dispatch):
             (f.split(":") for f in dispatch.split(",") if ":" in f)}
 
 
-def check_ran(smoke, log, workers, fused=None, advance=None):
+def check_ran(smoke, log, workers, fused=None, advance=None,
+              table_mode=None):
     """The device path must be what the phase expects: the platform,
     the kernel worker class, its interpret flag (a worker that reports
     none has no kernel: a failure), where the phase names one the
@@ -172,7 +173,9 @@ def check_ran(smoke, log, workers, fused=None, advance=None):
     loop (`pallas`, never the `xla` form the same worker class can
     carry), and for a target list no collided kernel tile hashed
     whole on the host (`verify=..,host_tiles:0`: the phases' engines
-    all have the device re-probe)."""
+    all have the device re-probe), and for a bulk list its table's
+    mode (`targets=..,mode:device`: the exact verify on the chip too,
+    never `host-verify`, and never no table at all)."""
     dev = log["device"]
     if dev is None or dev["platform"] != smoke.platform:
         raise PhaseError(f"device path ran on {dev}, not on a "
@@ -189,6 +192,12 @@ def check_ran(smoke, log, workers, fused=None, advance=None):
     if advance is not None and ran.get("advance") != advance:
         raise PhaseError(f"advance={ran.get('advance')}: the cost loop "
                          f"is not the {advance} kernel")
+    if table_mode is not None:
+        said = dict(f.split(":", 1) for f in
+                    ran.get("targets", "").split(",") if ":" in f)
+        if said.get("mode") != table_mode:
+            raise PhaseError(f"targets={ran.get('targets')}: the list's "
+                             f"probe table is not in {table_mode} mode")
     if _shapes(ran.get("verify", "")).get("host_tiles"):
         raise PhaseError(f"verify={ran['verify']}: collided tiles went "
                          "to the host oracle, not to the device re-probe")
@@ -326,7 +335,7 @@ def _ntlm_job(smoke, mask, window, n_targets, back):
 def phase_ntlm_1k(smoke, mask=NTLM_MASK, window=NTLM_WINDOW, batch=BATCH,
                   unit=UNIT, n_targets=1000, back=12345, devices=1,
                   name="ntlm-1k", workers=("PallasMaskWorker",),
-                  fused="loop"):
+                  fused="loop", table_mode=None):
     """BASELINE config 2: NTLM, a 1,000-line list, `--limit` window of
     the ?a x 7 mask with the plant in the window's last unit -- the
     multi-target kernel, whose every maybe the oracle verifies."""
@@ -336,7 +345,8 @@ def phase_ntlm_1k(smoke, mask=NTLM_MASK, window=NTLM_WINDOW, batch=BATCH,
                        "--batch", batch, "--unit-size", unit,
                        "--unit-seconds", 0, "--limit", window,
                        "--devices", devices)
-    ran = check_ran(smoke, log, workers, fused=fused)
+    ran = check_ran(smoke, log, workers, fused=fused,
+                    table_mode=table_mode)
     check_plant(smoke, "ntlm", line, plain, proc.stdout,
                 smoke.path(f"{name}.pot"))
     if int(log["finished"]["tested"]) != window:
@@ -349,6 +359,16 @@ def phase_ntlm_1k(smoke, mask=NTLM_MASK, window=NTLM_WINDOW, batch=BATCH,
                    plant=plain.decode("latin-1"), audit="clean",
                    digest=jobs[0]["digest_journal"],
                    found=sorted(proc.stdout.splitlines()), **extra)
+
+
+def phase_ntlm_bulk(smoke, window=1 << 31, n_targets=100_000, **sizes):
+    """A bulk list (past DPRF_TARGETS_PROBE_MIN): the same job with
+    10^5 targets over 2^31 candidates.  It has to hash on the compiled
+    kernel all the same (`interpret=False`, the fused loop) with its
+    probe table on the device in `device` mode: the XLA pipeline, or a
+    host-verify table, in its place is a failed phase."""
+    return phase_ntlm_1k(smoke, window=window, n_targets=n_targets,
+                         name="ntlm-bulk", table_mode="device", **sizes)
 
 
 def _free_port():
@@ -540,7 +560,7 @@ def phase_mesh(smoke, chips=4, **sizes):
             "out_devices": mesh["out_devices"]}
 
 
-PHASES = (phase_md5_mask, phase_ntlm_1k, phase_serve,
+PHASES = (phase_md5_mask, phase_ntlm_1k, phase_ntlm_bulk, phase_serve,
           phase_wordlist_rules, phase_bcrypt, phase_pmkid)
 
 

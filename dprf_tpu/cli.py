@@ -1111,7 +1111,9 @@ def _setup_job(args, device: str, log: Log,
     logged).  Single source of truth for the fingerprint and session
     wiring, so local and distributed jobs can never diverge."""
     engine = get_engine(args.engine, device="cpu")   # parser/oracle always CPU
-    hl = _load_job_targets(args, engine, log)
+    from dprf_tpu.telemetry.trace import get_tracer
+    with get_tracer().station("targets"):
+        hl = _load_job_targets(args, engine, log)
     if hl is None:
         return None
 
@@ -1359,14 +1361,14 @@ def _crack_single(args, device: str, log: Log):
     from dprf_tpu import compilecache
     from dprf_tpu.telemetry.trace import format_stations, get_tracer
     compilecache.enable(log=log)
+    tracer = get_tracer()
+    stations0 = tracer.station_table()    # `targets` opens in set-up
     job = _setup_job(args, device, log)
     if job is None:
         return 2, None, 0
     engine, hl, gen = job.engine, job.hl, job.gen
     session, restored_hits = job.session, job.restored_hits
     dispatcher, spec = job.dispatcher, job.spec
-    tracer = get_tracer()
-    stations0 = tracer.station_table()
     if session is not None:
         # flight-recorder stream next to the journal (attached BEFORE
         # the worker builds, so warmup-era spans land in the file too)

@@ -161,9 +161,11 @@ class JaxEngineBase(GenericWorkerFactories, DeviceHashEngine, HashEngine):
 
         Kernel-capable engines route to the hand-written Pallas kernel
         when eligible (see ops/pallas_mask.pallas_mode): exact
-        single-target compare, or the Bloom-prefilter multi-target path
+        single-target compare, the Bloom-prefilter multi-target path
         (which needs an oracle to verify maybes -- without one the job
-        stays on the generic fused XLA pipeline).
+        stays on the generic fused XLA pipeline), or for a bulk list
+        (targets/probe.probe_eligible) the kernel's hash with the
+        HBM-resident probe table behind it.
 
         A kernel that fails to build or compile (a Mosaic lowering
         regression, an unexpected shape) raises with the compiler's
@@ -171,24 +173,26 @@ class JaxEngineBase(GenericWorkerFactories, DeviceHashEngine, HashEngine):
         so a silent switch to it would be a wrong result that still
         "passes".  The warmup here forces the compile at construction.
         """
-        from dprf_tpu.ops.pallas_mask import kernel_eligible, pallas_mode
+        from dprf_tpu.ops.pallas_mask import (CORES, kernel_eligible,
+                                              pallas_mode)
         from dprf_tpu.targets import probe as probe_mod
         from dprf_tpu.utils.logging import DEFAULT as log
         mode = pallas_mode()
-        if mode is not None and probe_mod.probe_eligible(targets, self):
-            # bulk lists route to the probe-table worker: the Pallas
-            # multi-target kernel replicates a per-set bitmap whose
-            # cost grows with N, exactly what the probe table removes
-            log.info("bulk target list routes to the probe-table XLA "
-                     "pipeline", engine=self.name, targets=len(targets))
-        elif mode is not None and not kernel_eligible(self.name, gen,
-                                                      len(targets)):
+        # a bulk list (probe_eligible) hashes on the kernel too: its
+        # body ends at the digest and the probe table, which lives in
+        # HBM, is a stage of the same program behind it (PallasMask
+        # Worker's bulk mode), so the list's size is no bar
+        bulk = (mode is not None and self.name in CORES
+                and probe_mod.probe_eligible(targets, self))
+        if mode is not None and not kernel_eligible(
+                self.name, gen, 1 if bulk else len(targets)):
             # weak-spot visibility: `--impl auto` users otherwise can't
             # tell which path ran without reading the result JSON
             log.info("pallas kernel not eligible for this job; "
                      "using the XLA pipeline", engine=self.name,
                      targets=len(targets))
-        elif mode is not None and len(targets) > 1 and oracle is None:
+        elif (mode is not None and len(targets) > 1 and oracle is None
+              and not bulk):
             log.info("pallas multi-target kernel needs an oracle to "
                      "verify Bloom maybes; using the XLA pipeline",
                      engine=self.name, targets=len(targets))

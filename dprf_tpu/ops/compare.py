@@ -46,12 +46,11 @@ def make_target_table(digests: list[bytes], word_bytes: int = 4,
     if not digests:
         raise ValueError("empty target list")
     nwords = len(digests[0]) // word_bytes
-    rows = np.zeros((len(digests), nwords), dtype=np.uint32)
-    for i, d in enumerate(digests):
-        if len(d) != nwords * word_bytes:
-            raise ValueError("inconsistent digest sizes in target list")
-        rows[i] = np.frombuffer(
-            d, dtype="<u4" if little_endian else ">u4").astype(np.uint32)
+    if any(len(d) != nwords * word_bytes for d in digests):
+        raise ValueError("inconsistent digest sizes in target list")
+    rows = np.frombuffer(
+        b"".join(digests), dtype="<u4" if little_endian else ">u4"
+    ).astype(np.uint32).reshape(len(digests), nwords)
     order = np.lexsort(rows.T[::-1])   # sort by word0, then word1, ...
     rows = rows[order]
     first = rows[:, 0]

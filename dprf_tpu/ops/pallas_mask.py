@@ -487,7 +487,9 @@ def _build_kernel_body(engine_name: str, radices, seg_tables, length: int,
     SHA-256 graph in reasonable time, so correctness tests drive this
     body op-by-op).  ``kernel_body.found_lanes`` is the same math up
     to the per-lane (found, lane) tiles, before the reduction to one
-    lane a tile: make_tile_reprobe compacts those.
+    lane a tile: make_tile_reprobe compacts those;
+    ``kernel_body.hashed_lanes`` stops at the digest words, which is
+    all a bulk list's kernel runs (target None).
 
     probe: the (block_bits, k, n_grp) geometry from kernel_probe_rows
     for a multi-target job -- the compare runs the blocked probe
@@ -504,23 +506,27 @@ def _build_kernel_body(engine_name: str, radices, seg_tables, length: int,
     tile-relative (the caller adds tile * pid + offset back)."""
     core, n_words, big_endian, widen = CORES[engine_name]
     tile = sub * 128
-    target = np.asarray(target)
-    multi = target.ndim == 2 and target.shape[0] > 1
-    if multi:
-        if probe is None:
+    # target None: the bulk-list kernel, whose body ends at the digest
+    # (hashed_lanes); the probe runs behind it on a table no tile can
+    # hold (make_mask_digest_fn)
+    multi, tw = False, None
+    if target is not None:
+        target = np.asarray(target)
+        multi = target.ndim == 2 and target.shape[0] > 1
+        if multi and probe is None:
             raise ValueError("a multi-target kernel needs its probe "
                              "geometry (kernel_probe_rows)")
-        tw = None
-    else:
-        # plain python ints: jnp scalars here would be captured closure
-        # constants, which pallas_call rejects
-        tw = [int(w) for w in target.reshape(-1)]
-        if len(tw) != n_words:
-            raise ValueError(f"{engine_name}: expected {n_words} "
-                             "target words")
+        if not multi:
+            # plain python ints: jnp scalars here would be captured
+            # closure constants, which pallas_call rejects
+            tw = [int(w) for w in target.reshape(-1)]
+            if len(tw) != n_words:
+                raise ValueError(f"{engine_name}: expected {n_words} "
+                                 "target words")
 
-    def found_lanes(pid, base, n_valid, tables=None, luts=None,
-                    offset=None):
+    def hashed_lanes(pid, base, luts=None, offset=None):
+        """(digest words, lane, window-relative index) of one tile:
+        the decode, pack and hash every variant of the body shares."""
         shape = (sub, 128)
         lane = (jax.lax.broadcasted_iota(jnp.int32, shape, 0) * 128
                 + jax.lax.broadcasted_iota(jnp.int32, shape, 1))
@@ -534,7 +540,12 @@ def _build_kernel_body(engine_name: str, radices, seg_tables, length: int,
                                       base, gidx, luts, take)
         m = _pack_message(byts, length, shape, big_endian, widen,
                           32 if engine_name in WIDE_BLOCK else 16)
-        digest = core(m, shape)
+        return core(m, shape), lane, gidx
+
+    def found_lanes(pid, base, n_valid, tables=None, luts=None,
+                    offset=None):
+        shape = (sub, 128)
+        digest, lane, gidx = hashed_lanes(pid, base, luts, offset)
         valid = gidx < n_valid
         if not multi:
             found = valid
@@ -556,6 +567,7 @@ def _build_kernel_body(engine_name: str, radices, seg_tables, length: int,
         return count, hit_lane
 
     kernel_body.found_lanes = found_lanes
+    kernel_body.hashed_lanes = hashed_lanes
     return kernel_body
 
 
@@ -712,6 +724,120 @@ def make_mask_pallas_fn(engine_name: str, gen, target_words: np.ndarray,
         return p >> 16, (p & 0xFFFF) - 1
 
     return fn
+
+
+#: the bulk list's kernel, by name (make_mask_digest_fn)
+DIGEST_KERNEL_NAME = "mask_digest_kernel"
+
+
+def make_mask_digest_fn(engine_name: str, gen, batch: int,
+                        sub: int = SUB, interpret: bool = False):
+    """The kernel of a bulk target list: fn(base_digits int32[L],
+    offset int32[1]) -> digest words uint32[W, batch / 128, 128],
+    word-major and as the kernel's tiles wrote them (lane i, in
+    row-major order, of plane w is word w of candidate base + offset +
+    i).
+
+    The body is the mask kernels' own (_build_kernel_body: decode,
+    pack, hash core) and ends at the digest: a list past MAX_TARGETS
+    has a probe bitmap of megabytes, which no tile can look up with
+    128-lane gathers, so the probe is a stage of XLA operations behind
+    the kernel (targets/probe.probe_hits_words) and the digest words
+    are what crosses HBM between them: 4 * W bytes a candidate, which
+    at the kernel's rate is a tenth of the chip's bandwidth."""
+    grid = check_batch(batch, sub)
+    if engine_name not in CORES or not kernel_eligible(engine_name, gen, 1):
+        raise ValueError(f"{engine_name} mask job not kernel-eligible; "
+                         "use the XLA path")
+    n_words = CORES[engine_name][1]
+    seg_tables, luts_np = position_tables(gen.charsets)
+    body = _build_kernel_body(engine_name, gen.radices, seg_tables,
+                              gen.length, None, sub)
+
+    def kernel(base_ref, offset_ref, *rest):
+        out_ref = rest[-1]
+        luts_ref = rest[0] if luts_np is not None else None
+        digest, _, _ = body.hashed_lanes(pl.program_id(0), base_ref,
+                                         luts_ref, offset_ref[0])
+        for w in range(n_words):
+            out_ref[w] = digest[w]
+
+    in_specs = [
+        pl.BlockSpec((gen.length,), lambda i: (0,),
+                     memory_space=pltpu.SMEM),
+        pl.BlockSpec((1,), lambda i: (0,), memory_space=pltpu.SMEM),
+    ]
+    if luts_np is not None:
+        in_specs.append(pl.BlockSpec(luts_np.shape, lambda i: (0, 0)))
+    raw = pl.pallas_call(
+        kernel,
+        grid=(grid,),
+        in_specs=in_specs,
+        out_specs=[pl.BlockSpec((n_words, sub, 128),
+                                lambda i: (0, i, 0))],
+        out_shape=[jax.ShapeDtypeStruct((n_words, grid * sub, 128),
+                                        jnp.uint32)],
+        interpret=interpret,
+        # the compiled program's instruction, and its event in a device
+        # trace, carry this name (beside the gathers' own custom calls
+        # of the stage behind it)
+        name=DIGEST_KERNEL_NAME,
+    )
+    luts_dev = jnp.asarray(luts_np) if luts_np is not None else None
+
+    def fn(base_digits, offset):
+        args = [base_digits, offset]
+        if luts_dev is not None:
+            args.append(luts_dev)
+        (words,) = raw(*args)
+        return words
+
+    return fn
+
+
+def make_pallas_bulk_crack_step(engine_name: str, gen, geometry,
+                                batch: int, hit_capacity: int = 64,
+                                survivors: int = 256,
+                                interpret: bool = False,
+                                with_offset: bool = False,
+                                sub: Optional[int] = None):
+    """Bulk-list kernel step: step(base_digits, n_valid[, offset],
+    *table) -> (count, lanes int32[hit_capacity], tpos
+    int32[hit_capacity], n_maybe): the kernel hashes (make_mask_digest_fn),
+    the probe stage behind it (targets/probe.probe_hits_words, under
+    jax.named_scope("dprf_probe")) looks every digest up in the list's
+    bitmap and verifies the survivors exactly against its sorted
+    table, both arguments of the program (ProbeTable.device_args):
+    one executable serves every list of one `geometry`
+    (ProbeTable.geometry).  count is of true hits, tpos their position
+    in the sorted table; a survivor overflow inflates count past the
+    buffer and the worker redrives; n_maybe is what passed the
+    bitmap."""
+    from dprf_tpu.targets import probe as probe_mod
+    sub = SUB if sub is None else sub
+    fn = make_mask_digest_fn(engine_name, gen, batch, sub=sub,
+                             interpret=interpret)
+    lane = jnp.arange(batch, dtype=jnp.int32).reshape(-1, 128)
+
+    def run(base_digits, n_valid, offset, table):
+        words = fn(base_digits.astype(jnp.int32),
+                   jnp.reshape(offset, (1,)).astype(jnp.int32))
+        with jax.named_scope("dprf_probe"):
+            return probe_mod.probe_hits_words(
+                words, table, geometry, offset + lane < n_valid,
+                hit_capacity, survivors)
+
+    if with_offset:
+        @jax.jit
+        def step(base_digits, n_valid, offset, *table):
+            return run(base_digits, n_valid, offset, table)
+        return step
+
+    @jax.jit
+    def step(base_digits, n_valid, *table):
+        return run(base_digits, n_valid, jnp.int32(0), table)
+
+    return step
 
 
 def make_pallas_mask_crack_step(engine_name: str, gen,

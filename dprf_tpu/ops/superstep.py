@@ -117,9 +117,14 @@ def make_loop_super_step(step, inner: int, batch: int, groups):
     - out[payload_idx]: optional same-shape payload riding along;
     - capacity: the WINDOW buffer length for this group.
 
-    Returns super_step(x, n_valid_total) -> the step's output tuple
-    shape with window-relative buffers -- decodable exactly like a
-    wide-mode result.  n_valid_total is the whole window's bound; the
+    A group with buf_idx None is a count alone: out[count_idx] summed
+    over the window (a bulk list's bitmap survivors).
+
+    Returns super_step(x, n_valid_total, *extra) -> the step's output
+    tuple shape with window-relative buffers -- decodable exactly like
+    a wide-mode result.  `extra` goes to every call of the step behind
+    its offset: arguments of the program, the same for every batch (a
+    bulk list's probe table), never constants of it.  n_valid_total is the whole window's bound; the
     offset-aware step masks validity globally, so partial tails are
     exact without per-iteration clips.
     """
@@ -131,22 +136,28 @@ def make_loop_super_step(step, inner: int, batch: int, groups):
             f"arithmetic (max {INT32_BUDGET}); lower inner")
 
     @jax.jit
-    def super_step(x, n_valid):
+    def super_step(x, n_valid, *extra):
         n_valid = jnp.asarray(n_valid, jnp.int32)
         init = []
-        for (_, _, pi, _, cap) in groups:
+        for (_, bi, pi, _, cap) in groups:
             init.append(jnp.int32(0))
+            if bi is None:
+                continue
             init.append(jnp.full((cap,), -1, jnp.int32))
             if pi is not None:
                 init.append(jnp.full((cap,), -1, jnp.int32))
         init = tuple(init)
 
         def body(i, carry):
-            out = step(x, n_valid, (i * batch).astype(jnp.int32))
+            out = step(x, n_valid, (i * batch).astype(jnp.int32), *extra)
             new, at = [], 0
             for (ci, bi, pi, scale, cap) in groups:
-                count, buf = carry[at], carry[at + 1]
                 c_i = out[ci].astype(jnp.int32)
+                if bi is None:
+                    new.append(carry[at] + c_i)
+                    at += 1
+                    continue
+                count, buf = carry[at], carry[at + 1]
                 idx_i = out[bi]
                 ok = idx_i >= 0
                 rel = jnp.where(ok, idx_i + i * jnp.int32(scale), -1)
@@ -166,6 +177,9 @@ def make_loop_super_step(step, inner: int, batch: int, groups):
         out, at = {}, 0
         for (ci, bi, pi, _, _) in groups:
             out[ci] = fin[at]
+            if bi is None:
+                at += 1
+                continue
             out[bi] = fin[at + 1]
             at += 2
             if pi is not None:

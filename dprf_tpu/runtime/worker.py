@@ -106,7 +106,10 @@ def describe_worker(worker) -> dict:
     compile cost with its persistent-cache classification, and for a
     multi-target job what the host verified (`verify`: oracle hashes
     of maybe lanes, collided tiles resolved to their maybe lanes on
-    the device, collided tiles rescanned whole on the host).  A job
+    the device, collided tiles rescanned whole on the host; for a bulk
+    list also the lanes its bitmap passed and the hits the device
+    confirmed exactly) and the bulk list's table (`targets`: digests,
+    device bytes, `device` or `host-verify` mode).  A job
     that ran a slower path than the one expected must be readable
     from its own log."""
     w = getattr(worker, "_worker", worker)      # OrderedWorker
@@ -119,6 +122,10 @@ def describe_worker(worker) -> dict:
         "compile_s": f"{getattr(w, 'compile_seconds', 0.0):.2f}",
         "cache": getattr(w, "compile_cache", "off"),
     }
+    ptable = getattr(w, "probe_table", None)
+    if ptable is not None:
+        out["targets"] = (f"n:{ptable.num_targets},table_bytes:"
+                          f"{ptable.nbytes},mode:{ptable.mode}")
     if getattr(w, "verify_counts", None):
         out["verify"] = ",".join(f"{k}:{n}" for k, n in
                                  w.verify_counts.items())
@@ -417,6 +424,12 @@ class MaskWorkerBase:
     #: false-positive rate, so 16 is ample
     TILE_LANES = 16
 
+    #: a bulk list's ProbeTable (_setup_probe; describe_worker's
+    #: `targets=`), and what a step that takes the table as data is
+    #: handed behind (base digits, n_valid): ProbeTable.device_args
+    probe_table = None
+    _table_args = ()
+
     #: the collided-tile re-probe (ops/pallas_mask.make_tile_reprobe);
     #: None where the step has none (single target, XLA steps,
     #: pallas_ext steps): collided tiles are then rescanned on the host
@@ -454,33 +467,36 @@ class MaskWorkerBase:
     def _setup_probe(self, digests: list):
         """Bulk target lists (>= DPRF_TARGETS_PROBE_MIN digests) get
         the O(1)-per-candidate probe table (dprf_tpu/targets/) instead
-        of the replicated compare table; a build failure falls back to
-        the replicated path loudly.  Only workers whose step builder
-        understands a ProbeTable pass probe_ok=True."""
+        of the replicated compare table; None for any other list.
+        Only workers whose step builder understands a ProbeTable pass
+        probe_ok=True.  A table that cannot be built, or that the
+        byte budget cut to host-verify mode for a job with no oracle
+        to verify with, raises with the reason: the replicated table
+        in its place would be another job than the one asked for."""
+        import jax
+
         from dprf_tpu.targets import probe as probe_mod
         from dprf_tpu.utils.logging import DEFAULT as log
         if not probe_mod.probe_eligible(self.targets, self.engine):
             return None
-        try:
+        with get_tracer().station("targets"):
             ptable = probe_mod.build_probe_table(
                 digests, little_endian=self.engine.little_endian,
                 log=log)
-        except Exception as e:    # noqa: BLE001 -- degrade, not die
-            log.warn("probe-table build failed; falling back to the "
-                     "replicated compare table",
-                     targets=len(digests), error=str(e))
-            return None
+            jax.block_until_ready(ptable.device_args())
         if ptable.mode == probe_mod.MODE_HOST_VERIFY \
                 and self.oracle is None:
             # every survivor needs a host hash in this layout; without
             # an oracle the worker could never confirm a single hit
-            log.warn("host-verify probe table needs an oracle engine; "
-                     "falling back to the replicated compare table",
-                     targets=len(digests))
-            return None
+            raise ValueError(
+                f"the probe table of {len(digests)} targets does not "
+                "fit its device byte budget (DPRF_TARGETS_MAX_BYTES / "
+                "DPRF_TARGETS_HEADROOM_FRAC) beside its exact-verify "
+                "table, and host-verify mode needs an oracle engine")
         self._digest_map = {t.digest: i
                             for i, t in enumerate(self.targets)}
         self._order = ptable.order
+        self.probe_table = ptable
         # distinct program-registry label: the probe step's roofline
         # is a different program from the replicated-compare step's
         self.ATTACK = self.ATTACK + "+probe"
@@ -751,7 +767,7 @@ class MaskWorkerBase:
             ls = cache[inner] = make_loop_super_step(
                 step, inner, self._super_batch(), groups)
         return self._call_fused(("loop", inner), ls, base,
-                                jnp.int32(n_valid))
+                                jnp.int32(n_valid), *self._table_args)
 
     def _wide_step(self, sbatch: int):
         cache = getattr(self, "_wide_cache", None)
@@ -1278,6 +1294,17 @@ class PallasMaskWorker(MaskWorkerBase):
     disagrees with the kernel or overflows TILE_LANES, and for the
     ops/pallas_ext steps (engines outside CORES), which have no
     re-probe; `verify=` on the job's `ran` line counts each.
+
+    Bulk list (>= DPRF_TARGETS_PROBE_MIN digests, an engine in CORES):
+    the probe table of dprf_tpu/targets/ lives in HBM and no tile can
+    hold it, so the kernel's body ends at the digest words and a probe
+    stage of the same program (ops/pallas_mask.
+    make_pallas_bulk_crack_step) looks each up in the bitmap and
+    verifies the survivors exactly against the sorted table: the step
+    returns true hits, as a DeviceMaskWorker's does, and the table is
+    an argument of every dispatch, never a constant of the program.
+    `verify=` counts `survivors` (lanes the bitmap passed) and `exact`
+    (hits the device confirmed).
     """
 
     RESCAN_CAPACITY = 16
@@ -1290,7 +1317,8 @@ class PallasMaskWorker(MaskWorkerBase):
                  sub: Optional[int] = None):
         from dprf_tpu.ops.pallas_mask import CORES, SUB
 
-        tgt = self._setup_targets(engine, gen, targets, hit_capacity, oracle)
+        tgt = self._setup_targets(engine, gen, targets, hit_capacity,
+                                  oracle, probe_ok=engine.name in CORES)
         if engine.name not in CORES:
             # pallas_ext steps (nested double-hash, mysql41) have no
             # offset argument, so no loop program: they fuse wide
@@ -1303,7 +1331,12 @@ class PallasMaskWorker(MaskWorkerBase):
         self.batch = self.stride = batch
         self._tile = tile
         self._interpret = interpret
-        if self.multi:
+        if self.probe_table is not None:
+            from dprf_tpu.targets import probe as probe_mod
+            self._survivors = probe_mod.survivor_cap(self.probe_table, batch)
+            self._table_args = self.probe_table.device_args()
+            self.verify_counts.update(survivors=0, exact=0)
+        elif self.multi:
             if oracle is None:
                 raise ValueError("multi-target pallas worker needs an "
                                  "oracle engine to verify probe maybes")
@@ -1331,6 +1364,8 @@ class PallasMaskWorker(MaskWorkerBase):
         # buffer smaller than one batch's
         cap = max(self.hit_capacity,
                   min(self.hit_capacity * scale, 1024))
+        if self.probe_table is not None:
+            return self._with_table(self._bulk_step(batch, cap))
         if self.multi:
             rcap = max(self.RESCAN_CAPACITY,
                        min(self.RESCAN_CAPACITY * scale, 256))
@@ -1340,6 +1375,24 @@ class PallasMaskWorker(MaskWorkerBase):
         return make_pallas_mask_crack_step(
             self.engine.name, self.gen, self._twords, batch, cap,
             interpret=self._interpret, sub=self._sub)
+
+    def _bulk_step(self, batch: int, cap: int, with_offset: bool = False):
+        from dprf_tpu.ops.pallas_mask import make_pallas_bulk_crack_step
+        return make_pallas_bulk_crack_step(
+            self.engine.name, self.gen, self.probe_table.geometry, batch,
+            cap, self._survivors, interpret=self._interpret,
+            with_offset=with_offset, sub=self._sub)
+
+    def _with_table(self, step):
+        """The bulk step under the workers' (base digits, n_valid)
+        contract: the list's table rides behind as arguments."""
+        table = self._table_args
+
+        def bound(*args):
+            return step(*args, *table)
+
+        bound.lower = lambda *args: step.lower(*args, *table)
+        return bound
 
     def _make_loop_parts(self, inner: int):
         """Offset-aware per-batch kernel step + accumulation groups
@@ -1357,6 +1410,11 @@ class PallasMaskWorker(MaskWorkerBase):
         cap = max(self.hit_capacity,
                   min(self.hit_capacity * inner, 1024))
         grid = self.batch // self._tile
+        if self.probe_table is not None:
+            # true hits and their table positions globalize by the
+            # batch stride; the bitmap's survivors are a count alone
+            return (self._bulk_step(self.batch, cap, with_offset=True),
+                    ((0, 1, 2, self.batch, cap), (3, None, None, 0, 0)))
         if self.multi:
             rcap = max(self.RESCAN_CAPACITY,
                        min(self.RESCAN_CAPACITY * inner, 256))
@@ -1376,12 +1434,25 @@ class PallasMaskWorker(MaskWorkerBase):
     def _batch_flag(self, result):
         if not self.multi:
             return result[0]
+        if self.probe_table is not None:
+            # hits, and the survivors `verify=` counts: every unit of a
+            # bulk list is read back
+            return result[0] + result[3]
         return result[0] + result[2]   # single maybes + collided tiles
 
     def _batch_hits(self, bstart: int, result, unit: WorkUnit,
                     window: int = 0) -> list[Hit]:
         if not self.multi:
             return super()._batch_hits(bstart, result, unit, window)
+        if self.probe_table is not None:
+            import jax
+            count, lanes, tpos, n_maybe = jax.device_get(result)
+            if count <= lanes.shape[0]:   # else redriven, counted there
+                self.verify_counts["survivors"] += int(n_maybe)
+                if self.probe_table.table is not None:
+                    self.verify_counts["exact"] += int(count)
+            return super()._batch_hits(bstart, (count, lanes, tpos),
+                                       unit, window)
         n_single, lanes, n_collided, ctiles = result
         n_single, n_collided = int(n_single), int(n_collided)
         if n_single == 0 and n_collided == 0:

@@ -29,7 +29,7 @@ from __future__ import annotations
 
 import dataclasses
 import math
-from typing import Optional, Sequence
+from typing import NamedTuple, Optional, Sequence
 
 import numpy as np
 import jax.numpy as jnp
@@ -57,7 +57,10 @@ MODE_HOST_VERIFY = "host-verify"
 class ProbeTable:
     """Host-built, device-resident multi-target probe structure."""
 
-    bits: jnp.ndarray        # uint32[n_blocks * BLOCK_WORDS] bitmap
+    #: the bloom_fill bitmap, block-minor: uint32[BLOCK_WORDS,
+    #: n_blocks], row w holding word w of every block, which is how a
+    #: step gathers it (bloom_maybe_words)
+    blocks: jnp.ndarray
     block_bits: int          # log2(n_blocks); static
     k: int                   # bit probes per digest; static
     #: exact-verify buckets (device mode); None in host-verify mode
@@ -67,6 +70,33 @@ class ProbeTable:
     mode: str                # MODE_DEVICE | MODE_HOST_VERIFY
     fp_est: float            # analytic false-positive rate of `bits`
     nbytes: int              # device bytes: bitmap + exact table
+
+    @property
+    def geometry(self) -> "ProbeGeometry":
+        """What a step over this table is compiled for; everything
+        else of the table is data (``device_args``)."""
+        return ProbeGeometry(
+            self.block_bits, self.k,
+            self.table.window if self.table is not None else 0)
+
+    def device_args(self) -> tuple:
+        """The table as arguments of a step: (bitmap, sorted digest
+        words, their first words), the last two left out in
+        host-verify mode.  Lists of one geometry and one padded length
+        have the same shapes, so one executable serves them all."""
+        if self.table is None:
+            return (self.blocks,)
+        return (self.blocks, self.table.words, self.table.first)
+
+
+class ProbeGeometry(NamedTuple):
+    """The static half of a ProbeTable: log2 of the bitmap's blocks,
+    probes a digest, and the exact compare's window (the longest run
+    of sorted digests sharing their first word, rounded up to a power
+    of two; 0: no exact table on the device, host-verify mode)."""
+    block_bits: int
+    k: int
+    window: int
 
 
 def _pow2ceil(x: int) -> int:
@@ -210,45 +240,88 @@ def build_probe_table(digests: Sequence[bytes],
     table = None
     order = np.arange(n, dtype=np.int64)
     if mode == MODE_DEVICE:
-        table = cmp_ops.make_target_table(
-            list(digests), little_endian=little_endian)
+        table = _padded_table(cmp_ops.make_target_table(
+            list(digests), little_endian=little_endian))
         order = table.order
+        exact_bytes = int(table.words.nbytes + table.first.nbytes)
     nbytes = words.nbytes + (exact_bytes if table is not None else 0)
     if log is not None:
         log.info("built probe table", targets=n, mode=mode,
                  bits=m_bits, k=k, fp=round(fp_est, 8),
                  mbytes=round(nbytes / 1e6, 3))
-    return ProbeTable(bits=jnp.asarray(words), block_bits=block_bits,
+    blocks = np.ascontiguousarray(words.reshape(-1, BLOCK_WORDS).T)
+    return ProbeTable(blocks=jnp.asarray(blocks), block_bits=block_bits,
                       k=k, table=table, order=order, num_targets=n,
                       mode=mode, fp_est=fp_est, nbytes=nbytes)
 
 
-def bloom_maybe(digest: jnp.ndarray, pt: ProbeTable) -> jnp.ndarray:
-    """uint32[B, W] candidate digests -> bool[B] "possibly a target".
+def _padded_table(table: cmp_ops.TargetTable) -> cmp_ops.TargetTable:
+    """The sorted table with its length padded to a power of two (by
+    repeating its last digest) and its window to one (at least 2), so
+    that its shapes and its compare depend on the list's size class
+    and not on the list.  A pad equals the last real row and lies
+    behind it, so the leftmost match of a run is always a real row;
+    `order` is padded alike for whoever maps a pad back."""
+    n = table.num_targets
+    pad = _pow2ceil(n) - n
+    words, first = np.asarray(table.words), np.asarray(table.first)
+    if pad:
+        words = np.concatenate([words, np.repeat(words[-1:], pad, 0)])
+        first = np.concatenate([first, np.repeat(first[-1:], pad)])
+    order = np.concatenate([table.order,
+                            np.repeat(table.order[-1:], pad)])
+    return cmp_ops.TargetTable(
+        words=jnp.asarray(words), first=jnp.asarray(first),
+        window=max(2, _pow2ceil(table.window)), order=order)
 
-    Per candidate: one multiplicative block pick from word0, then k
-    double-hashed bit tests inside that single 512-bit block -- the
-    whole prefilter is a constant number of ops in N."""
-    W = digest.shape[1]
-    h1 = digest[:, 0]
-    h2 = digest[:, 1] | jnp.uint32(1)
+
+def bloom_maybe(digest: jnp.ndarray, pt: ProbeTable) -> jnp.ndarray:
+    """uint32[B, W] candidate digests -> bool[B] "possibly a target"
+    (bloom_maybe_words over the table's own bitmap)."""
+    return bloom_maybe_words(digest.T, pt.blocks, pt.block_bits, pt.k)
+
+
+def bloom_maybe_words(words, blocks, block_bits: int, k: int):
+    """Word-major candidate digests (W arrays of one shape, the lanes')
+    against a bloom_fill bitmap given block-minor, uint32[BLOCK_WORDS,
+    n_blocks] (ProbeTable.blocks) -> bool of that shape, "possibly a
+    target".
+
+    Per candidate: one multiplicative block pick from word0, ONE gather
+    of that 512-bit block, then the k double-hashed bit tests against
+    its words with compares and selects: constant work in N, and one
+    lookup a candidate.  The block comes word-major too (row w of the
+    gather is word w of every lane's block), so on a TPU each of the
+    tests is an elementwise pass over full tiles; give the lanes a
+    shape of [.., 128] there.  (On a v5e a gather of 2^22 blocks costs
+    9-12 ms and one of 2^22 single words 31-37 ms, whatever the
+    table's size, so the k probes may not each be a gather of their
+    own: that read 290 ms.  PERF.md has the readings.)"""
+    W = len(words)
+    h1 = words[0]
+    h2 = words[1] | jnp.uint32(1)
     # the alternating probe pairs of bloom_fill (the ONE bit layout)
-    h3 = digest[:, 2] if W > 3 else h1
-    h4 = (digest[:, 3] | jnp.uint32(1)) if W > 3 else h2
-    if pt.block_bits:
-        base = ((h1 * jnp.uint32(_GOLDEN))
-                >> (32 - pt.block_bits)).astype(jnp.int32) * BLOCK_WORDS
+    h3 = words[2] if W > 3 else h1
+    h4 = (words[3] | jnp.uint32(1)) if W > 3 else h2
+    if block_bits:
+        block = ((h1 * jnp.uint32(_GOLDEN))
+                 >> (32 - block_bits)).astype(jnp.int32)
     else:
-        base = jnp.zeros(digest.shape[0], jnp.int32)
-    maybe = jnp.ones(digest.shape[0], dtype=bool)
-    for j in range(pt.k):
+        block = jnp.zeros(h1.shape, jnp.int32)
+    # a block index has block_bits bits: always in bounds
+    rows = blocks.at[:, block].get(mode="promise_in_bounds")
+    maybe = jnp.ones(h1.shape, bool)
+    for j in range(k):
         i = j >> 1
         a, b = (h3, h4) if j & 1 else (h1, h2)
         g = a + jnp.uint32(2 * i + 1) * b
         bit = g & jnp.uint32(BLOCK_BITS - 1)
-        w = base + (bit >> 5).astype(jnp.int32)
-        mask = jnp.left_shift(jnp.uint32(1), bit & jnp.uint32(31))
-        maybe = maybe & ((pt.bits[w] & mask) != 0)
+        widx = bit >> 5
+        word = rows[0]
+        for w in range(1, BLOCK_WORDS):
+            word = jnp.where(widx == w, rows[w], word)
+        maybe = maybe & (((word >> (bit & jnp.uint32(31)))
+                          & jnp.uint32(1)) == 1)
     return maybe
 
 
@@ -267,36 +340,65 @@ def survivor_cap(pt: ProbeTable, batch: int) -> int:
 def probe_hits(digest: jnp.ndarray, pt: ProbeTable,
                valid: jnp.ndarray, hit_capacity: int,
                survivors: int):
-    """Digests -> the workers' (count, lanes, tpos) hit-buffer shape.
+    """uint32[B, W] digests against a ProbeTable held by the caller's
+    closure -> the workers' (count, lanes, tpos) hit-buffer shape
+    (probe_hits_words without its survivor count)."""
+    return probe_hits_words(digest.T, pt.device_args(), pt.geometry,
+                            valid, hit_capacity, survivors)[:3]
+
+
+def compact_lanes(found: jnp.ndarray, capacity: int):
+    """bool[B] -> (count, lanes int32[capacity]): the first `capacity`
+    set lanes in order, unused slots -1.  A binary search for each
+    slot in the running count (capacity x log2 B lookups) where
+    compare.compact_hits scatters all B lanes: on a v5e the scatter of
+    2^22 lanes costs 21 ms, this under 2 (PERF.md)."""
+    run = jnp.cumsum(found.astype(jnp.int32))
+    want = jnp.arange(1, capacity + 1, dtype=jnp.int32)
+    at = jnp.searchsorted(run, want, side="left").astype(jnp.int32)
+    return run[-1], jnp.where(want <= run[-1], at, jnp.int32(-1))
+
+
+def probe_hits_words(words, table: tuple, geometry: ProbeGeometry,
+                     valid: jnp.ndarray, hit_capacity: int,
+                     survivors: int):
+    """Word-major digests (uint32[W, B], or [W, B / 128, 128] with
+    `valid` of the same lanes' shape: lane i in row-major order)
+    against a probe table passed as DATA (ProbeTable.device_args /
+    .geometry) -> (count, lanes int32[cap], tpos int32[cap], n_maybe):
+    the workers' hit-buffer shape, and beside it how many lanes passed
+    the bitmap.
 
     Device mode: Bloom survivors compact into a `survivors`-slot
-    buffer, their digests are re-gathered and verified exactly against
+    buffer, their digests are gathered and verified exactly against
     the sorted table, and true hits compact into the hit_capacity
-    buffer.  A survivor overflow (n_maybe > survivors) could hide a
-    real hit, so the count is inflated past the lane buffer and the
-    callers' existing overflow rescan/redrive path re-covers the
-    window exactly.
+    buffer; tpos is the position in the sorted table.  A survivor
+    overflow (n_maybe > survivors) could hide a real hit, so the count
+    is inflated past the lane buffer and the callers' existing
+    overflow rescan/redrive path re-covers the window exactly.
 
-    Host-verify mode (no exact table on device): the lane buffer IS
-    the survivor buffer (tpos all -1) and count is the survivor count;
-    the worker verifies each lane with one oracle hash.  Overflow
-    falls out of the same count > capacity comparison."""
-    nlanes = digest.shape[0]
-    lane = jnp.arange(nlanes, dtype=jnp.int32)
-    maybe = bloom_maybe(digest, pt) & valid
-    n_maybe = maybe.sum(dtype=jnp.int32)
-    slot = jnp.cumsum(maybe.astype(jnp.int32)) - 1
-    slot = jnp.where(maybe, slot, survivors)
-    surv = jnp.full((survivors,), -1, jnp.int32).at[slot].set(
-        lane, mode="drop")
-    if pt.table is None:
-        return n_maybe, surv, jnp.full((survivors,), -1, jnp.int32)
-    sdig = digest[jnp.maximum(surv, 0)]
-    found, tpos = cmp_ops.compare_multi(sdig, pt.table)
+    Host-verify mode (geometry.window 0: no exact table on device):
+    the lane buffer IS the survivor buffer (tpos all -1) and count is
+    the survivor count; the worker verifies each lane with one oracle
+    hash.  Overflow falls out of the same count > capacity
+    comparison."""
+    maybe = bloom_maybe_words(words, table[0], geometry.block_bits,
+                              geometry.k) & valid
+    maybe = maybe.reshape(-1)
+    words = [w.reshape(-1) for w in words]
+    n_maybe, surv = compact_lanes(maybe, survivors)
+    if not geometry.window:
+        return (n_maybe, surv, jnp.full((survivors,), -1, jnp.int32),
+                n_maybe)
+    at = jnp.maximum(surv, 0)
+    sdig = jnp.stack([w[at] for w in words], axis=-1)    # [S, W]
+    found, tpos = cmp_ops.compare_multi(
+        sdig, cmp_ops.TargetTable(words=table[1], first=table[2],
+                                  window=geometry.window, order=None))
     found = found & (surv >= 0)
     count, slots, tpos = cmp_ops.compact_hits(found, tpos, hit_capacity)
     lanes = jnp.where(slots >= 0, surv[jnp.maximum(slots, 0)],
                       jnp.int32(-1))
     count = jnp.where(n_maybe <= survivors, count,
                       jnp.int32(hit_capacity) + n_maybe)
-    return count, lanes, tpos
+    return count, lanes, tpos, n_maybe

@@ -74,13 +74,15 @@ SPAN_NAMES = ("lease", "rpc", "warmup", "sweep", "hit_verify",
               "restore")
 
 #: the one declaration site for station names (tools/check_metrics.py
-#: holds every station("...") literal to it).  A unit passes them in
-#: this order; ``wait`` and ``decode`` open inside ``resolve`` (and
+#: holds every station("...") literal to it).  ``targets`` is a job's,
+#: before its first unit: hash-file parse (cli._setup_job), a bulk
+#: list's table build and upload (MaskWorkerBase._setup_probe).  A
+#: unit passes the others in this order; ``wait`` and ``decode`` open inside ``resolve`` (and
 #: ``decode`` inside ``probe``, once a batch of a probed unit).  A
 #: station is a unit or a dispatch, never a lane or a batch of a fused
 #: program: a span each would cost what it measures.
-STATIONS = ("lease", "submit", "probe", "resolve", "wait", "decode",
-            "verify", "complete")
+STATIONS = ("targets", "lease", "submit", "probe", "resolve", "wait",
+            "decode", "verify", "complete")
 _STATION_LABELS = {name: "dprf:" + name for name in STATIONS}
 
 #: suffix appended to a session journal path for its span stream

@@ -36,16 +36,23 @@ def parse_lines(engine: HashEngine, lines: Sequence[str]) -> HashlistResult:
     targets: list[Target] = []
     seen: set = set()
     skipped, dups = [], 0
+    # a bare hex digest a line (the engine keeps HashEngine's own
+    # parse_target) is parsed here, with the same result and errors: a
+    # bulk list is a million of them, and the calls were half its load
+    bare = type(engine).parse_target is HashEngine.parse_target
+    size = engine.digest_size
     for no, raw in enumerate(lines, 1):
         text = raw.strip()
         if not text or text.startswith("#"):
             continue
         try:
-            t = engine.parse_target(text)
+            digest = bytes.fromhex(text) if bare else b""
+            t = (Target(raw=text, digest=digest) if len(digest) == size
+                 and bare else engine.parse_target(text))
         except ValueError as e:
             skipped.append((no, text, str(e)))
             continue
-        key = _dedup_key(t)
+        key = _dedup_key(t) if t.params else t.digest
         if key in seen:
             dups += 1
             continue

@@ -217,6 +217,32 @@ def test_ntlm_1k_tile_reprobe_has_no_custom_call(one_chip):
     assert "custom-call" not in text and "gather(" not in text
 
 
+def test_ntlm_bulk_list_loop_superstep(one_chip):
+    """A bulk list's fused dispatch (ISSUE 29): the digest kernel in a
+    loop of 64 with the probe stage behind it, for 100,000 targets.
+    The table is three ARGUMENTS of the program (bitmap, sorted
+    digests, their first words), the kernel is in it once and under
+    its own name, which is what the benchmark's trace matches."""
+    from dprf_tpu.bench import uniform_digest_lines
+    from dprf_tpu.ops.pallas_mask import DIGEST_KERNEL_NAME
+    from dprf_tpu.ops.superstep import make_loop_super_step
+    cpu = get_engine("ntlm", device="cpu")
+    w = _mask_worker("ntlm", "?a?a?a?a?a?a?a",
+                     [cpu.parse_target(line)
+                      for line in uniform_digest_lines(100_000, 16)])
+    assert w.probe_table is not None and w._reprobe is None
+    table = tuple(_sds(one_chip, a.shape, a.dtype)
+                  for a in w._table_args)
+    step, groups = w._make_loop_parts(64)
+    ls = make_loop_super_step(step, 64, w._super_batch(), groups)
+    compiled, text = _compile(
+        ls.lower(_sds(one_chip, (7,)), _sds(one_chip), *table))
+    assert text.count("tpu_custom_call") == 1
+    assert f"%{DIGEST_KERNEL_NAME}." in text
+    args = compiled.memory_analysis().argument_size_in_bytes
+    assert args >= w.probe_table.nbytes
+
+
 def test_nested_1k_targets_kernel(one_chip, as_tpu):
     """The pallas_ext multi-target step (md5(md5($p)), 1,000 uniform
     targets): the same in-kernel probe bitmap as the CORES kernels."""

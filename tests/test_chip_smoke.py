@@ -191,6 +191,37 @@ def test_phase_ntlm_1k_tiny(cs, smoke):
     assert "loop:" in rec["dispatch"]
 
 
+def test_phase_ntlm_bulk_tiny(cs, smoke):
+    """The bulk phase at 4,200 targets: the kernel worker, the fused
+    loop, the table in device mode."""
+    rec = cs.phase_ntlm_bulk(smoke, mask="?l?l?l?l", window=40 * TILE,
+                             batch=TILE, unit=16 * TILE, n_targets=4200,
+                             back=7)
+    assert rec["ok"] and rec["phase"] == "ntlm-bulk"
+    assert rec["worker"] == "PallasMaskWorker" and rec["targets"] == 4200
+    assert rec["swept"] == 40 * TILE and "loop:" in rec["dispatch"]
+    assert len(rec["found"]) == 1 and rec["found"][0].endswith(
+        ":" + rec["plant"])
+
+
+@pytest.mark.parametrize("targets,why", [
+    ("targets=n:100000,table_bytes:2883584,mode:host-verify ",
+     "not in device mode"),
+    ("", "not in device mode"),
+])
+def test_check_ran_wants_the_bulk_table_on_the_device(cs, tmp_path,
+                                                      targets, why):
+    s = cs.Smoke(str(tmp_path))
+    good = LOG.replace("compile_s=", "targets=n:100000,table_bytes:"
+                       "2883584,mode:device compile_s=")
+    cs.check_ran(s, cs.read_log(good), ("PallasMaskWorker",),
+                 fused="loop", table_mode="device")
+    bad = LOG.replace("compile_s=", targets + "compile_s=")
+    with pytest.raises(cs.PhaseError, match=why):
+        cs.check_ran(s, cs.read_log(bad), ("PallasMaskWorker",),
+                     fused="loop", table_mode="device")
+
+
 def test_phase_serve_tiny(cs, smoke):
     rec = cs.phase_serve(smoke, mask="?l?l?l?l", batch=TILE,
                          unit=16 * TILE, small_mask="?l?l?l")

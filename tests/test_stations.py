@@ -230,8 +230,9 @@ def test_the_ran_line_carries_host_by_station(tmp_path, capsys,
     out, ran = _crack(tmp_path, capsys, "--device", "cpu")
     assert "zzy" in out
     host = dict(f.split(":") for f in ran["host"].split(","))
-    assert set(host) == {"lease", "submit", "probe", "resolve", "verify",
-                         "complete"}
+    # `targets` is the job's own, open around the hash file's parse
+    assert set(host) == {"targets", "lease", "submit", "probe", "resolve",
+                         "verify", "complete"}
     assert list(host) == [s for s in STATIONS if s in host]
     assert all(re.fullmatch(r"\d+\.\d{3}", v) for v in host.values())
     assert float(host["submit"]) > 0
@@ -245,7 +246,8 @@ def test_the_ran_line_carries_host_by_station(tmp_path, capsys,
 def test_the_unit_id_rides_every_span_but_lease(tmp_path, capsys):
     """A profiler trace of a small device job (XLA on the CPU): the
     stations are events of the host's plane, `wait` lies inside a
-    `resolve`, and all but `lease` carry their unit's id."""
+    `resolve`, and all but `lease` (and `targets`, which is the job's,
+    before any unit) carry their unit's id."""
     import jax
     from jax.profiler import ProfileData
     opts = jax.profiler.ProfileOptions()
@@ -271,7 +273,8 @@ def test_the_unit_id_rides_every_span_but_lease(tmp_path, capsys):
                                   "wait", "complete")} <= names
     assert names <= {"dprf:" + s for s in STATIONS}
     for name, _, _, unit in events:
-        assert (unit is None) == (name == "dprf:lease"), name
+        assert (unit is None) == (name in ("dprf:lease",
+                                           "dprf:targets")), name
     resolves = [e for e in events if e[0] == "dprf:resolve"]
     for name, s, e, unit in events:
         if name == "dprf:wait":
