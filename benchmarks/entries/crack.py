@@ -14,7 +14,8 @@ put back after it; no file of the program is changed:
 - `_setup_job` returns the real job with `job.dispatcher` inside a
   `WindowDispatcher` (lets the warm units through as set-up, opens the
   window on an empty pipeline, closes it once `--seconds` have passed
-  and its units are drained, times every `lease()` and `complete()`)
+  (in a traced run: `TRACED_WINDOW` of them) and its units are
+  drained, times every `lease()` and `complete()`)
   and `job.engine`, the CPU oracle, inside an `OracleProxy` that times
   its hash calls;
 - `Potfile` is a subclass that notes when each line was written;
@@ -22,7 +23,10 @@ put back after it; no file of the program is changed:
   hold of it.
 
 The window is from its opening to the last `complete()` of the drain:
-it ends in a real sync.  A job with one target ends at its hit, so its
+it ends in a real sync.  A traced run's window is half as long
+(`TRACED_WINDOW`) and the profiler runs over its last seconds
+(`SliceTracer`); everything else of the run, the plan included, is an
+untraced run's.  A job with one target ends at its hit, so its
 plant lies behind the window (`traffic.py`, `tail_plant`): after the
 drain the same job, with the same worker, goes on leasing outside
 every clock (the tail) until it has found the plant, and then the
@@ -43,6 +47,18 @@ import time
 #: always swept by the program's phase sampler, and the first fused
 #: program is compiled lazily at its first call, which is unit 1's
 WARM_UNITS = 2
+#: a traced run closes its window at this share of `--seconds`.  The
+#: plan is an untraced run's (same seed, same `--seconds`, same plant),
+#: so a one-target job's plant lies twice as far behind a traced window
+#: as it must: a program up to twice as fast as the cell's
+#: `tail_plant.units_per_s` still sweeps its whole slice before its hit
+#: ends the job (the slice used to be the last seconds of the full
+#: window, and a program a quarter faster lost it, in silence)
+TRACED_WINDOW = 0.5
+#: the trace is stopped this long after the window's close: stopped at
+#: the close itself, one traced run in three lost the slice's last
+#: program from `XLA Modules` (70 ms of false idle; PERF.md, 3)
+STOP_AFTER_S = 0.25
 #: the text an `XLA Ops` event of the hash kernel holds in the trace:
 #: the Pallas kernel is the programs' one custom call
 KERNEL_EVENT = " custom-call("
@@ -251,7 +267,10 @@ class OracleProxy:
 
 class SliceTracer:
     """Takes the profiler's trace of the window's last `slice_s`
-    seconds; `finish()` stops it."""
+    seconds; `finish()` stops it.  `started` stays False where the
+    window closed (by the clock or at the job's hit) before
+    `start_after_s`: such a run has no slice, which `run.py` reports as
+    an error."""
 
     def __init__(self, directory, start_after_s, spans):
         self.directory, self.start_after = directory, start_after_s
@@ -270,11 +289,13 @@ class SliceTracer:
             self._spans.annotate = True
 
     def finish(self):
-        """Stop the trace: at the window's close (and again, to no
+        """Stop the trace: `STOP_AFTER_S` after the window's close, on
+        the loop's thread and outside every clock (and again, to no
         effect, once the job has returned)."""
         if self.started and self._spans.annotate:
             import jax
             self._spans.annotate = False
+            time.sleep(STOP_AFTER_S)
             jax.profiler.stop_trace()
 
 
@@ -331,10 +352,11 @@ def audit(session):
 
 
 def run(ctx):
-    """One measuring run.  ctx: cfg, cell, plan, seconds, trace (bool),
-    workdir, faults (tests and control.py: {"stall": fn(unit),
-    "patches": fn(cli) -> names of `cli` to replace: the harness's own
-    replacements go around them}).  Returns the observations the
+    """One measuring run.  ctx: cfg, cell, plan, seconds (what the plan
+    was made for: the window's length, and in a traced run twice it,
+    `TRACED_WINDOW`), trace (bool), workdir, faults (tests and
+    control.py: {"stall": fn(unit), "patches": fn(cli) -> names of
+    `cli` to replace: the harness's own replacements go around them}).  Returns the observations the
     comparison and the metric readers take."""
     from dprf_tpu import cli, compilecache
     cfg, cell, plan = ctx["cfg"], ctx["cell"], ctx["plan"]
@@ -346,12 +368,12 @@ def run(ctx):
     session = os.path.join(wd, "window.session")
     potfile = os.path.join(wd, "window.potfile")
     spans = Spans()
-    tracer = None
+    tracer, window_s = None, float(ctx["seconds"])
     if ctx["trace"]:
-        slice_s = min(float(cell.get("trace_slice_s", 4.0)),
-                      0.5 * ctx["seconds"])
+        window_s *= TRACED_WINDOW
+        slice_s = min(float(cell.get("trace_slice_s", 4.0)), 0.5 * window_s)
         tracer = SliceTracer(os.path.join(wd, "trace"),
-                             ctx["seconds"] - slice_s, spans)
+                             window_s - slice_s, spans)
     holder, stamps = {}, []
     under = faults["patches"](cli) if faults.get("patches") else {}
     real_setup = under.get("_setup_job", cli._setup_job)
@@ -363,7 +385,7 @@ def run(ctx):
         if job is None:
             return None
         job.dispatcher = holder["dispatcher"] = WindowDispatcher(
-            job.dispatcher, WARM_UNITS, ctx["seconds"], spans,
+            job.dispatcher, WARM_UNITS, window_s, spans,
             tracer=tracer, stall=faults.get("stall"),
             counter=lambda: sum(
                 compilecache.process_cache_counts().values()),
@@ -402,7 +424,10 @@ def run(ctx):
         "host_counts": dict(spans.counts),
         "potfile": potfile, "session": session,
         "potfile_stamps": stamps, "worker": holder.get("worker"),
+        # None in a traced run too, where the window closed before its
+        # slice was due: the profiler never started
         "trace_dir": tracer.directory if tracer and tracer.started else None,
+        "slice_due_s": tracer.start_after if tracer else None,
         "window_compiles": disp.count_at_close - disp.count_at_open,
     }
     return obs

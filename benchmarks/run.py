@@ -11,8 +11,9 @@ in `BENCHMARK.json`; nothing here names one.
 
 A run: find the chip (none, or too few: exit 3, nothing printed), make
 the inputs from the seed (`traffic.py`), let the entry driver warm up
-and drive the window, read the device's memory peak, run the
-comparison (`compare.py`, against `reference.py`), read the metrics,
+and drive the window (a traced run that comes back without a trace is
+an error: exit 1, nothing printed), read the device's memory peak, run
+the comparison (`compare.py`, against `reference.py`), read the metrics,
 print the numbers compared beside their limits on standard error and
 one JSON object as the last line of standard output.
 """
@@ -76,10 +77,17 @@ def window_shape(obs):
     waits = [(b - a, b - obs["t_open"])
              for a, b in zip([obs["t_open"]] + done, done)]
     worst = max(waits, default=(0.0, 0.0))
-    return {"seconds": obs["t_close"] - obs["t_open"], "units": len(done),
-            "unit_ms_median": 1e3 * times[len(times) // 2] if times else None,
-            "unit_ms_max": 1e3 * times[-1] if times else None,
-            "wait_ms_max": 1e3 * worst[0], "wait_max_at_s": worst[1]}
+    shape = {"seconds": obs["t_close"] - obs["t_open"], "units": len(done),
+             "unit_ms_median": 1e3 * times[len(times) // 2] if times else None,
+             "unit_ms_max": 1e3 * times[-1] if times else None,
+             "wait_ms_max": 1e3 * worst[0], "wait_max_at_s": worst[1]}
+    if obs.get("trace"):
+        # the traced slice, in seconds since the window opened: it ends
+        # at the window's close and is as long as the trace says
+        shape["slice_s"] = [shape["seconds"] - obs["trace"]["window_s"],
+                            shape["seconds"]]
+        shape["close_after_program_s"] = obs["trace"]["close_after_program_s"]
+    return shape
 
 
 def measure(cell_name, seed, seconds, traced, devs, workdir,
@@ -104,6 +112,14 @@ def measure(cell_name, seed, seconds, traced, devs, workdir,
     ctx = {"cfg": cfg, "cell": cell, "plan": plan, "seconds": seconds,
            "trace": traced, "workdir": workdir, "faults": faults}
     obs = entry.run(ctx)
+    if traced and not obs.get("trace_dir"):
+        shutil.rmtree(workdir, ignore_errors=True)
+        raise SystemExit(
+            f"run.py: --trace 1, but the window closed "
+            f"{obs['t_close'] - obs['t_open']:.3f} s after it opened and "
+            f"its slice was due at {obs['slice_due_s']:.3f} s: the profiler "
+            "never started, there is no per-layer metric to read, and "
+            "nothing is printed")
     obs.update(cfg=cfg, cell=cell, plan=plan, t_start=T_START,
                reach_chip_s=reach_chip_s,
                n_devices=cell["chips"], device_kind=devs[0].device_kind

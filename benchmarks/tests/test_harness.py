@@ -148,6 +148,129 @@ def test_a_faster_program_closes_its_window_at_the_hit(tmp_path):
     assert r["metrics"]["cand_per_s"]["value"] > 0
 
 
+# -- where a traced run takes its slice ---------------------------------------
+# `--seconds` 4: a traced window of 2 s with its slice due at 1 s (the
+# tiny cell's `trace_slice_s`), the window's units paced at 50 a second
+# (the tiny job sweeps 140 on this CPU), so `tail_plant.units_per_s`
+# times 4 units behind the window's start is where the hit comes.
+
+def paced(period):
+    """A `stall` that hands the window's k-th unit on no sooner than k
+    periods after its first."""
+    state = {"k": 0, "first": None}
+
+    def stall(unit):
+        if state["first"] is None:
+            state["first"] = time.monotonic()
+        due = state["first"] + state["k"] * period
+        state["k"] += 1
+        time.sleep(max(0.0, due - time.monotonic()))
+
+    return stall
+
+
+def traced_entry(tmp_path, units_per_s, seconds=4.0):
+    """`crack.run` traced and paced; -> (plan, obs, the slice's length
+    in seconds as the trace's own host events give it)."""
+    import entries.crack as crack
+    import trace_reduce
+    cfg, c, _ = plan_of("tiny-md5.crack", 5)
+    c = dict(c, tail_plant={"units_per_s": units_per_s})
+    plan = traffic.make_plan(cfg, c, 5, seconds, crack.WARM_UNITS)
+    wd = tmp_path / "wd"
+    wd.mkdir()
+    obs = crack.run({"cfg": cfg, "cell": c, "plan": plan, "seconds": seconds,
+                     "trace": True, "workdir": str(wd),
+                     "faults": {"stall": paced(0.02)}})
+    slice_s = None
+    if obs["trace_dir"]:
+        loaded = trace_reduce.load(
+            trace_reduce.find_xplane(obs["trace_dir"]), ops=False)
+        t0, t1 = trace_reduce.slice_ends(trace_reduce.loop_events(loaded))
+        slice_s = (t1 - t0) / 1e9
+    return plan, obs, slice_s
+
+
+def test_a_traced_run_keeps_its_whole_slice_when_the_hit_is_in_its_tail(
+        tmp_path):
+    """The hit at 0.6 of `--seconds` (an untraced window would end
+    there): the traced window is closed by the clock at 0.5, its slice
+    is whole, and the job finds the plant in its tail."""
+    plan, obs, slice_s = traced_entry(tmp_path, 30.0)
+    assert obs["trace_dir"] and obs["slice_due_s"] == 1.0
+    window = obs["t_close"] - obs["t_open"]
+    assert 2.0 <= window < 2.3
+    assert 0.85 < slice_s <= window - 1.0 + 0.05
+    assert obs["tail_units"] and obs["rc"] == 0
+    assert all(t is not None for *_, t in obs["units"])
+    (plant,) = plan.plants_in("tail")
+    assert plant.index == plan.window_start + 120 * plan.unit_size \
+        + plant.index % plan.unit_size
+
+
+def test_a_traced_slice_ends_at_a_hit_inside_the_traced_window(tmp_path):
+    """The hit at 0.45 of `--seconds`: the job ends there, the window
+    closes at its last completed unit and the slice ends with it."""
+    _, obs, slice_s = traced_entry(tmp_path, 22.5)
+    assert obs["trace_dir"] and obs["rc"] == 0
+    window = obs["t_close"] - obs["t_open"]
+    assert 1.7 < window < 2.0 and not obs["tail_units"]
+    assert 0.6 < slice_s <= window - 1.0 + 0.05
+
+
+def test_a_traced_run_without_a_slice_is_an_error(tmp_path, capsys):
+    """The hit at 0.2 of `--seconds`, before the slice is due: the
+    profiler never started, and the run says so and prints no result."""
+    import shutil
+    root = tmp_path / "data"
+    shutil.copytree(conftest.DATA, root)
+    f = root / "workloads" / "tiny-md5.crack.json"
+    cell = json.loads(f.read_text())
+    cell["tail_plant"]["units_per_s"] = 10.0
+    f.write_text(json.dumps(cell))
+    import jax
+    bench = {"workloads": [{"name": "tiny-md5.crack"}],
+             "end_to_end": END_TO_END, "per_layer": PER_LAYER}
+    with pytest.raises(SystemExit) as e:
+        run.measure("tiny-md5.crack", 5, 4.0, True, jax.devices(),
+                    str(tmp_path / "wd"), platform="cpu", interpret=True,
+                    faults={"stall": paced(0.02)}, bench=bench,
+                    data_root=str(root), reach_chip_s=0.0)
+    # a message is a non-zero exit code, written to standard error
+    assert isinstance(e.value.code, str)
+    assert "the profiler never started" in e.value.code
+    assert "slice was due at 1.000 s" in e.value.code
+    assert capsys.readouterr().out == ""
+    # the same job untraced is a sound run that ends at its hit
+    r = run.measure("tiny-md5.crack", 5, 4.0, False, jax.devices(),
+                    str(tmp_path / "wd"), platform="cpu", interpret=True,
+                    faults={"stall": paced(0.02)}, bench=bench,
+                    data_root=str(root), reach_chip_s=0.0)
+    assert r["correct"] and 0.7 < r["window"]["seconds"] < 1.0
+
+
+def test_a_traced_and_an_untraced_run_of_one_seed_get_one_plan(
+        tmp_path, monkeypatch):
+    import entries.crack as crack
+    seen = []
+
+    class Stop(Exception):
+        pass
+
+    def no_run(ctx):
+        seen.append(ctx)
+        raise Stop
+
+    monkeypatch.setattr(crack, "run", no_run)
+    for traced in (False, True):
+        with pytest.raises(Stop):
+            measure("tiny-md5.crack", 2**31 + 9, tmp_path, seconds=3.0,
+                    traced=traced)
+    assert seen[0]["plan"] == seen[1]["plan"]
+    assert seen[0]["seconds"] == seen[1]["seconds"] == 3.0
+    assert [c["trace"] for c in seen] == [False, True]
+
+
 def test_cand_per_s_falls_when_a_unit_stalls(tmp_path):
     sound = measure("tiny-md5.crack", 3, tmp_path, seconds=1.0)
     stalled = []

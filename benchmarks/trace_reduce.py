@@ -14,13 +14,19 @@ executed program: the device is busy exactly inside these), `XLA Ops`
 its body) and `Async XLA Ops`; the Pallas kernel is an `XLA Ops` event
 whose text holds ` custom-call(`.  Host threads are lines of the plane
 `/host:CPU`; `jax.profiler.TraceAnnotation`s appear there under their
-own names.  All times are nanoseconds from the trace's start, the
+own names: the harness's `bench:<name>` (`entries/crack.py`, `Spans`)
+and the program's stations `dprf:<station>` (`dprf_tpu/telemetry/
+trace.py`, `STATIONS`), both on the line of the thread that runs the
+job's loop.  All times are nanoseconds from the trace's start, the
 same clock on every plane.
 
 The slice that is reduced runs from the start of the first host
 annotation named `bench:lease` to the end of the last
 `bench:complete`: from the first call the trace saw to the window's
-close.
+close.  What is shared with `span_reduce.py` lives here: the loader,
+the slice's ends, the cut of the loop's thread into segments by the
+spans open on it, and the attribution of the device's idle gaps to
+those segments.
 """
 
 import glob
@@ -29,6 +35,7 @@ import re
 
 DEVICE_PLANE = re.compile(r"^/device:TPU:(\d+)$")
 HOST_PREFIX = "bench:"
+PROGRAM_PREFIX = "dprf:"
 _OP = re.compile(r"^%?(\S+) = .*?\s([a-z][a-z0-9\-]*)\(")
 
 
@@ -40,11 +47,16 @@ def find_xplane(directory):
     return paths[-1]
 
 
-def load(path):
+def load(path, ops=True):
     """{"devices": {"0": {"modules": [[start_ns, end_ns, name]..],
-    "ops": [..]}}, "host": [[start_ns, end_ns, name]..]}"""
+    "ops": [..] (left empty without `ops`)}}, "host": [{"line": the
+    thread's name, "events": [[start_ns, end_ns, name, unit id or
+    None]..]}..] for the lines that hold a `dprf:` or `bench:` event}"""
     from jax.profiler import ProfileData
     data = ProfileData.from_file(path)
+    wanted = {"XLA Modules": "modules"}
+    if ops:
+        wanted["XLA Ops"] = "ops"
     out = {"devices": {}, "host": []}
     for plane in data.planes:
         m = DEVICE_PLANE.match(plane.name)
@@ -52,18 +64,39 @@ def load(path):
             dev = out["devices"].setdefault(
                 m.group(1), {"modules": [], "ops": []})
             for line in plane.lines:
-                key = {"XLA Modules": "modules", "XLA Ops": "ops"}.get(
-                    line.name)
-                if key:
-                    dev[key] = [[e.start_ns, e.start_ns + e.duration_ns,
-                                 e.name] for e in line.events]
+                if line.name in wanted:
+                    dev[wanted[line.name]] = [
+                        [e.start_ns, e.start_ns + e.duration_ns, e.name]
+                        for e in line.events]
         elif plane.name == "/host:CPU":
             for line in plane.lines:
-                out["host"] += [
-                    [e.start_ns, e.start_ns + e.duration_ns, e.name]
-                    for e in line.events if e.name.startswith(HOST_PREFIX)]
-    out["host"].sort()
+                evs = [[e.start_ns, e.start_ns + e.duration_ns, e.name,
+                        dict(e.stats).get("unit")]
+                       for e in line.events
+                       if e.name.startswith((PROGRAM_PREFIX, HOST_PREFIX))]
+                if evs:
+                    out["host"].append({"line": line.name,
+                                        "events": sorted(evs)})
     return out
+
+
+def loop_events(trace):
+    """The events of the thread that ran the job's loop: the line that
+    holds the harness's `bench:lease`."""
+    for line in trace["host"]:
+        if any(e[2] == HOST_PREFIX + "lease" for e in line["events"]):
+            return line["events"]
+    return []
+
+
+def slice_ends(loop):
+    """(t0, t1) of the slice, or None where the loop's thread lacks
+    one of its ends."""
+    leases = [e for e in loop if e[2] == HOST_PREFIX + "lease"]
+    closes = [e for e in loop if e[2] == HOST_PREFIX + "complete"]
+    if not leases or not closes or closes[-1][1] <= leases[0][0]:
+        return None
+    return leases[0][0], closes[-1][1]
 
 
 def _union(intervals):
@@ -106,20 +139,78 @@ def short_name(text):
     return f"{m.group(2)} {m.group(1)}" if m else text[:60]
 
 
+def segments(events, t0, t1):
+    """[t0, t1] cut wherever a span opens or closes: [(start, end,
+    names of the spans open there, outermost first)]."""
+    out, stack, at = [], [], t0     # stack: [end, name]
+
+    def emit(upto):
+        nonlocal at
+        if upto > at:
+            out.append((at, upto, tuple(n for _, n in stack)))
+            at = upto
+
+    def close(upto):
+        while stack and stack[-1][0] <= upto:
+            emit(stack[-1][0])
+            stack.pop()
+
+    for s, e, name in sorted(events, key=lambda ev: (ev[0], -ev[1])):
+        close(s)
+        emit(s)
+        # a child ends with its parent at the latest
+        stack.append([min(e, stack[-1][0]) if stack else e, name])
+    close(t1)
+    emit(t1)
+    return out
+
+
+def loop_segments(loop, t0, t1):
+    """The slice of the loop's thread, cut by the spans open on it."""
+    return segments(_clip([e[:3] for e in loop], t0, t1), t0, t1)
+
+
+def busy_and_gaps(modules, t0, t1):
+    """One chip's slice: the union of the intervals in which a program
+    ran, and the gaps between them."""
+    busy = _union([(s, e) for s, e, _ in _clip(modules, t0, t1)])
+    gaps, at = [], t0
+    for s, e in busy + [[t1, t1]]:
+        if s > at:
+            gaps.append((at, s))
+        at = max(at, e)
+    return busy, gaps
+
+
+def idle_by(gaps, segs, key):
+    """{key(names of the spans open): ns} over the device's gaps: each
+    nanosecond of a gap goes to the segment of the loop's thread that
+    holds it."""
+    idle_ns, i = {}, 0
+    for gs, ge in gaps:
+        while i < len(segs) and segs[i][1] <= gs:
+            i += 1
+        j = i
+        while j < len(segs) and segs[j][0] < ge:
+            s, e, names = segs[j]
+            k = key(names)
+            idle_ns[k] = idle_ns.get(k, 0) + min(e, ge) - max(s, gs)
+            j += 1
+    return idle_ns
+
+
 def reduce(trace, kernel_event):
     """The slice's numbers.  kernel_event: the text an `XLA Ops` event
     of the hash kernel holds (the cell's file names it)."""
-    leases = [e for e in trace["host"] if e[2] == HOST_PREFIX + "lease"]
-    closes = [e for e in trace["host"] if e[2] == HOST_PREFIX + "complete"]
+    loop = loop_events(trace)
+    ends = slice_ends(loop)
     devices = {k: d for k, d in trace["devices"].items() if d["modules"]}
-    if not leases or not closes or not devices:
+    if ends is None or not devices:
         return None
-    t0, t1 = leases[0][0], closes[-1][1]
-    if t1 <= t0:
-        return None
+    t0, t1 = ends
     busy_s, kernel_s, kernel_calls, kernel_whole_s = [], [], [], []
     for dev in devices.values():
-        busy = _union([(s, e) for s, e, _ in _clip(dev["modules"], t0, t1)])
+        busy, _ = busy_and_gaps(dev["modules"], t0, t1)
         busy_s.append(sum(e - s for s, e in busy) / 1e9)
         kern = [ev for ev in _clip(dev["ops"], t0, t1)
                 if kernel_event in ev[2]]
@@ -130,26 +221,16 @@ def reduce(trace, kernel_event):
                  if kernel_event in n and s >= t0 and e <= t1]
         kernel_calls.append(len(whole))
         kernel_whole_s.append(sum(whole) / 1e9)
-    first = devices[sorted(devices, key=int)[0]]
+    first = devices[min(devices, key=int)]
     ops = {}
     for name, sec in _self_times(_clip(first["ops"], t0, t1)).items():
         key = short_name(name)
         ops[key] = ops.get(key, 0.0) + sec
-    busy = _union([(s, e) for s, e, _ in _clip(first["modules"], t0, t1)])
-    gaps, at = [], t0
-    for s, e in busy + [[t1, t1]]:
-        if s > at:
-            gaps.append((at, s))
-        at = max(at, e)
-    host = trace["host"]
-    idle = {}
-    for gs, ge in gaps:
-        named = 0
-        for s, e, name in _clip(host, gs, ge):
-            idle[name] = idle.get(name, 0) + e - s
-            named += e - s
-        idle["host:other"] = idle.get("host:other", 0) + max(
-            0, ge - gs - named)
+    # chip 0's idle, each gap under the innermost span open on the
+    # loop's thread at the time, the program's or the harness's
+    busy, gaps = busy_and_gaps(first["modules"], t0, t1)
+    idle = idle_by(gaps, loop_segments(loop, t0, t1),
+                   lambda names: names[-1] if names else "host:other")
     n = len(devices)
     top = lambda d, scale: [[k, v / scale] for k, v in sorted(
         d.items(), key=lambda kv: -kv[1])[:10] if v > 0]
@@ -160,8 +241,10 @@ def reduce(trace, kernel_event):
         "kernel_calls": sum(kernel_calls) / n,
         "kernel_whole_s": sum(kernel_whole_s) / n,
         "n_devices": n,
+        # on a drained pipeline the close follows chip 0's last program
+        # by the host's few milliseconds; a unit's length here says the
+        # trace lost the slice's last program
+        "close_after_program_s": (t1 - busy[-1][1]) / 1e9 if busy else None,
         "breakdown": {"device_ops": top(ops, 1.0),
                       "idle_gaps": top(idle, 1e9)},
     }
-
-
