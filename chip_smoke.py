@@ -21,8 +21,9 @@ with their counts, compile seconds and persistent-cache hit or miss,
 candidates swept, wall seconds.  A phase FAILS when the worker is not
 the compiled kernel worker it expects, when a fused dispatch shape
 other than the expected one shows up (or the expected one does not;
-``probe`` counts the per-batch dispatches of the units the always-on
-phase sampler sweeps, every 16th),
+``probe`` counts the per-batch dispatches of the units the phase
+sampler sweeps where DPRF_PERF_SAMPLE is set: by default there are
+none),
 when the plant is not found, or when ``dprf audit`` is not clean.  Wall
 seconds are seconds, not a rate: the benchmark is ROADMAP S1.
 

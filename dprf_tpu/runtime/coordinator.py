@@ -115,8 +115,9 @@ class Coordinator:
         #: one by default)
         self.tracer = get_tracer(recorder)
         self._registry = get_registry(registry)
-        #: per-phase sweep attribution (ISSUE 9): every Nth unit runs
-        #: the sampled synced probe; verify timing is unsampled
+        #: per-phase sweep attribution (ISSUE 9), opt-in: with
+        #: DPRF_PERF_SAMPLE=N every Nth unit runs the synced probe,
+        #: unset none does; verify timing is recorded either way
         self._perf = perf_mod.PerfSampler(registry=self._registry,
                                           recorder=self.tracer)
         from dprf_tpu.telemetry import declare_job_metrics
@@ -265,8 +266,9 @@ class Coordinator:
                                 overlapped=True)
                     probe = None
                     if self._perf.take():
-                        # sampled unit: serial synced sweep with
-                        # per-phase attribution (declared PERF_PROBE)
+                        # sampled unit (DPRF_PERF_SAMPLE set): serial
+                        # synced sweep with per-phase attribution
+                        # (declared PERF_PROBE)
                         pctx = self.dispatcher.trace_context(
                             unit.unit_id)
                         probe = (self._perf,

@@ -1791,9 +1791,10 @@ def worker_loop(client: CoordinatorClient, worker, worker_id: str,
         "dprf_worker_idle_seconds",
         "seconds this worker held no submitted unit between sweeps "
         "(pipeline drained: the device idles while RPCs fly)")
-    # sampled per-phase attribution (telemetry/perf.py): every Nth
-    # unit runs the serial synced probe; its phase spans ship back
-    # with the complete report like any other worker span
+    # sampled per-phase attribution (telemetry/perf.py), opt-in: with
+    # DPRF_PERF_SAMPLE=N every Nth unit runs the serial synced probe,
+    # and its phase spans ship back with the complete report like any
+    # other worker span; unset, no unit leaves the pipelined submit
     sampler = perf_mod.PerfSampler(registry=m, recorder=tracer)
     # kernel-profiling plane (ISSUE 15): on-demand bounded capture
     # windows requested over lease/heartbeat responses.  The loop

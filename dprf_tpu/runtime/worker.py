@@ -102,7 +102,8 @@ def describe_worker(worker) -> dict:
     class, whether its kernels are interpreted ("n/a": it has no
     kernel, its step is plain XLA), the dispatch shapes
     used so far with their counts (`probe`: the per-batch, synced
-    dispatches of the sampled units, telemetry/perf.py), and the
+    dispatches of the units DPRF_PERF_SAMPLE asked to sample,
+    telemetry/perf.py; absent unless it is set), and the
     compile cost with its persistent-cache classification, and for a
     multi-target job what the host verified (`verify`: oracle hashes
     of maybe lanes, collided tiles resolved to their maybe lanes on
@@ -144,9 +145,9 @@ HOT_PATHS = ("MaskWorkerBase.submit",)
 #: `dprf check` retrace analyzer: the SAMPLED perf probe is ALLOWED
 #: to sync inside hot loops -- forced block_until_ready boundaries
 #: are how per-phase attribution stays honest, and sampling
-#: (DPRF_PERF_SAMPLE) keeps them off the steady-state path.  An
-#: explicit declaration, not a suppression comment: stale entries
-#: are findings.
+#: (DPRF_PERF_SAMPLE, off unless set) keeps them off the
+#: steady-state path.  An explicit declaration, not a suppression
+#: comment: stale entries are findings.
 PERF_PROBE = ("dprf_tpu.telemetry.perf.probe_pending",)
 
 #: env override for the submit-ahead depth both pipelined loops run at
@@ -251,9 +252,10 @@ class UnitPipeline:
         submit-ahead queue.
 
         ``probe`` = (PerfSampler, trace id) routes THIS unit through
-        the sampled per-phase sweep (telemetry/perf.py): serial and
-        synced, so the phase breakdown is honest; the resolved entry
-        carries its phase spans and the pre-allocated sweep span id.
+        the sampled per-phase sweep (telemetry/perf.py; None unless
+        DPRF_PERF_SAMPLE is set): serial and synced, so the phase
+        breakdown is honest; the resolved entry carries its phase
+        spans and the pre-allocated sweep span id.
         The submit timestamp is taken BEFORE the dispatch so a
         serial/probed unit's submit-to-resolve time covers its real
         work, not just queue wait."""

@@ -20,13 +20,20 @@ and ``dprf report`` show fleet-wide p50/p95 per phase).
 Honest phase timing needs ``block_until_ready`` boundaries between
 the phases -- exactly the host syncs the retrace analyzer forbids on
 the steady-state path, because they drain the device stream.  So
-attribution is SAMPLED: ``DPRF_PERF_SAMPLE=N`` (default every 16th
-unit, 0 disables) routes one unit in N through ``probe_pending`` -- a
-serial, synced sweep of that one unit -- while every other unit runs
-the normal pipelined submit.  ``probe_pending`` is declared in the
-hot-path modules' ``PERF_PROBE`` tables, the retrace analyzer's
-explicit exemption list for deliberately-syncing sampled probes (a
-declaration, not a suppression comment).
+attribution is SAMPLED and OPT-IN: ``DPRF_PERF_SAMPLE=N`` routes one
+unit in N through ``probe_pending`` -- a serial, synced sweep of that
+one unit, behind an emptied pipeline -- while every other unit runs
+the normal pipelined submit.  Unset (the default, 0) no unit leaves
+the fused dispatch: the probe perturbs what it times (on a TPU v5e a
+probed unit of 2^28 md5 candidates takes 290 ms, a fused one 54; at
+N=16 that was a fifth to a third of three benchmark cells, PERF.md
+PR 31), and every unit's ``submit``/``wait``/``decode`` seconds are
+on the job's ``ran host=`` line and in a device trace from the
+stations (telemetry/trace.py), unsampled and unsynced.  Only the
+``verify`` phase is recorded without the knob.  ``probe_pending`` is
+declared in the hot-path modules' ``PERF_PROBE`` tables, the retrace
+analyzer's explicit exemption list for deliberately-syncing sampled
+probes (a declaration, not a suppression comment).
 
 The probed sweep produces exactly the hits the normal path would:
 the phase loop is the per-batch step contract
@@ -64,7 +71,7 @@ from dprf_tpu.utils import env as envreg
 #: the ``dprf_phase_seconds`` phase label values
 PHASES = ("generate", "h2d", "device", "d2h", "verify")
 
-#: sampling cadence knob: probe every Nth unit (0 disables)
+#: sampling cadence knob: probe every Nth unit (0, the default: none)
 SAMPLE_ENV = "DPRF_PERF_SAMPLE"
 
 #: EWMA smoothing for the live roofline gauge (one unit's elapsed is
@@ -107,11 +114,11 @@ OPS_PER_CANDIDATE = {
 }
 
 
-def sample_every(default: int = 16) -> int:
+def sample_every() -> int:
     """The probe cadence: every Nth unit runs the synced phase sweep;
-    0 disables sampling entirely."""
-    n = envreg.get_int(SAMPLE_ENV, default)
-    return max(0, int(n))
+    0 (the knob's declared default, utils/env.py: the one default)
+    means no unit does."""
+    return max(0, envreg.get_int(SAMPLE_ENV))
 
 
 def phase_histogram(registry=None):
@@ -133,7 +140,8 @@ class PerfSampler:
     """Per-loop sampling state + the publication surface the probed
     sweep records into.  One per run loop (local Coordinator /
     remote worker_loop); ``take()`` answers "is THIS unit the sampled
-    one" on the configured cadence (unit 1, N+1, 2N+1, ...)."""
+    one" on the configured cadence (unit 1, N+1, 2N+1, ...; never,
+    at the default cadence of 0)."""
 
     __slots__ = ("every", "hist", "tracer", "_n")
 

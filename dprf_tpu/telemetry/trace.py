@@ -78,7 +78,8 @@ SPAN_NAMES = ("lease", "rpc", "warmup", "sweep", "hit_verify",
 #: before its first unit: hash-file parse (cli._setup_job), a bulk
 #: list's table build and upload (MaskWorkerBase._setup_probe).  A
 #: unit passes the others in this order; ``wait`` and ``decode`` open inside ``resolve`` (and
-#: ``decode`` inside ``probe``, once a batch of a probed unit).  A
+#: ``decode`` inside ``probe``, once a batch of a probed unit: a
+#: unit is probed only where DPRF_PERF_SAMPLE is set).  A
 #: station is a unit or a dispatch, never a lane or a batch of a fused
 #: program: a span each would cost what it measures.
 STATIONS = ("targets", "lease", "submit", "probe", "resolve", "wait",

@@ -21,8 +21,9 @@ from dprf_tpu.runtime.worker import CpuWorker
 from dprf_tpu.runtime.workunit import WorkUnit
 from dprf_tpu.telemetry import perf
 from dprf_tpu.telemetry.registry import MetricsRegistry
-from dprf_tpu.telemetry.trace import (TraceRecorder, load_trace,
-                                      overlap_report, trace_path)
+from dprf_tpu.telemetry.trace import (TraceRecorder, get_tracer,
+                                      load_trace, overlap_report,
+                                      trace_path)
 
 pytestmark = pytest.mark.smoke
 
@@ -177,45 +178,68 @@ def test_sample_zero_disables_probing(monkeypatch):
 
 
 # ---------------------------------------------------------------------------
-# steady-state overhead <= 2% (acceptance criterion; the PR 4
-# noise-free pattern: cost the probes at a measured per-probe price)
+# which units leave the pipelined submit: a count, with no clock in it
+# (what a probed unit costs is a chip's to say: PERF.md, PR 31)
 
-def test_sampling_overhead_within_2_percent(monkeypatch):
-    mask, unit_size = "?l?l?l?l", 1 << 14     # 456,976 -> 28 units
-    monkeypatch.setenv("DPRF_PERF_SAMPLE", "0")
-    offs = [_local_sweep(mask, unit_size)[1] for _ in range(2)]
-    monkeypatch.setenv("DPRF_PERF_SAMPLE", "16")
-    ons = [_local_sweep(mask, unit_size)[1] for _ in range(2)]
-    t_off, t_on = min(offs), min(ons)
-    # primary, noise-free bound: the per-probe EXTRA cost vs the
-    # plain path, measured directly, times the probes a sampled
-    # sweep runs, must be <= 2% of the sweep
-    oracle = get_engine("md5", device="cpu")
-    gen = MaskGenerator(mask)
-    targets = [oracle.parse_target(UNMATCHABLE)]
-    worker = CpuWorker(oracle, gen, targets, chunk=8192)
+@pytest.mark.parametrize("sample, probed_units", [("16", [0, 16]),
+                                                  (None, [])],
+                         ids=["DPRF_PERF_SAMPLE=16", "default"])
+def test_only_the_sampled_units_leave_submit(monkeypatch, sample,
+                                             probed_units):
+    if sample is None:
+        monkeypatch.delenv("DPRF_PERF_SAMPLE", raising=False)
+    else:
+        monkeypatch.setenv("DPRF_PERF_SAMPLE", sample)
+    stations = get_tracer()      # UnitPipeline.submit's: the process's
+    before = stations.station_table()
+    _, _, rec, reg = _local_sweep("?l?l?d", 212)   # 6760 -> 32 units
+    after = stations.station_table()
+    sweeps = [s["attrs"] for s in rec.tail(100000)
+              if s["name"] == "sweep"]
+    assert sorted(a["unit"] for a in sweeps) == list(range(32))
+    assert sorted(a["unit"] for a in sweeps
+                  if a["probed"]) == probed_units
+    passed = {name: after.get(name, (0, 0.0))[0]
+              - before.get(name, (0, 0.0))[0]
+              for name in ("submit", "probe")}
+    assert passed == {"submit": 32 - len(probed_units),
+                      "probe": len(probed_units)}
+    assert reg.get("dprf_phase_seconds").count(
+        phase="device", engine="md5", job="j0") == len(probed_units)
+
+
+def test_the_cadence_has_one_default_and_it_is_off(monkeypatch):
+    """The knob's declared default (utils/env.py) is the only one:
+    unset, no unit is ever the sampler's."""
+    from dprf_tpu.utils import env as envreg
+    monkeypatch.delenv("DPRF_PERF_SAMPLE", raising=False)
+    assert envreg.get_int("DPRF_PERF_SAMPLE") == 0
+    assert perf.sample_every() == 0
     sampler = perf.PerfSampler(registry=MetricsRegistry(),
-                               recorder=_recorder(), every=1)
-    unit = WorkUnit(0, 0, unit_size)
-    t_plain = min(_timed(lambda: worker.process(unit))
-                  for _ in range(3))
-    t_probe = min(_timed(lambda: perf.probe_pending(worker, unit,
-                                                    sampler))
-                  for _ in range(3))
-    per_probe_extra = max(0.0, t_probe - t_plain)
-    n_probes = -(-28 // 16)                   # ceil(units / cadence)
-    assert per_probe_extra * n_probes <= 0.02 * t_on, (
-        f"{n_probes} probes x {per_probe_extra * 1e3:.2f}ms extra "
-        f"> 2% of the {t_on:.3f}s sweep")
-    # generous wall guard against gross regressions (loaded 2-core
-    # box: not a tight bound)
-    assert t_on <= t_off * 1.25 + 0.1, (t_on, t_off)
+                               recorder=_recorder())
+    assert sampler.every == 0
+    assert not any(sampler.take() for _ in range(64))
+    monkeypatch.setenv("DPRF_PERF_SAMPLE", "3")
+    assert perf.sample_every() == 3
 
 
-def _timed(fn) -> float:
-    t0 = time.perf_counter()
-    fn()
-    return time.perf_counter() - t0
+def test_default_job_observes_verify_alone(monkeypatch):
+    """Unset, `dprf_phase_seconds` holds what costs no sync: every
+    hit batch's verify, and none of the sweep's phases."""
+    monkeypatch.delenv("DPRF_PERF_SAMPLE", raising=False)
+    oracle = get_engine("md5", device="cpu")
+    gen = MaskGenerator("?l?l?d")
+    targets = [oracle.parse_target(hashlib.md5(b"zz9").hexdigest())]
+    worker = CpuWorker(oracle, gen, targets, chunk=8192)
+    result, _, rec, reg = _local_sweep("?l?l?d", 600, worker=worker,
+                                       gen=gen)
+    assert result.found == {0: b"zz9"}
+    hist = reg.get("dprf_phase_seconds")
+    counts = {ph: hist.count(phase=ph, engine="md5", job="j0")
+              for ph in perf.PHASES}
+    assert counts == {"generate": 0, "h2d": 0, "device": 0, "d2h": 0,
+                      "verify": 1}
+    assert not [s for s in rec.tail(100000) if s["name"] == "phase"]
 
 
 # ---------------------------------------------------------------------------

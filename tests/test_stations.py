@@ -192,7 +192,23 @@ def _cpu_job(rec, mask="?l?l?l?l", unit_size=1 << 16):
                        registry=reg, recorder=rec)
 
 
-def test_self_seconds_sum_to_the_loops_elapsed():
+#: the phase sampler's cadence (DPRF_PERF_SAMPLE) and whether a job so
+#: run passes the station `probe`: unset, no unit leaves `submit`
+SAMPLED = pytest.mark.parametrize(
+    "sample, probed", [(None, False), ("16", True)],
+    ids=["default", "DPRF_PERF_SAMPLE=16"])
+
+
+@pytest.fixture
+def cadence(monkeypatch, sample):
+    if sample is None:
+        monkeypatch.delenv("DPRF_PERF_SAMPLE", raising=False)
+    else:
+        monkeypatch.setenv("DPRF_PERF_SAMPLE", sample)
+
+
+@SAMPLED
+def test_self_seconds_sum_to_the_loops_elapsed(cadence, probed):
     """What the stations leave out of `Coordinator.run` is the loop's
     own bookkeeping: on a job whose units are worth the while, under a
     twentieth.  (The loop never sleeps here: a unit is always
@@ -206,10 +222,16 @@ def test_self_seconds_sum_to_the_loops_elapsed():
                 for name, (_, s) in table.items())
     assert 0.95 * result.elapsed <= named <= result.elapsed
     units = -(-456976 // (1 << 16))
-    for name in ("submit", "resolve", "complete"):
-        assert table[name][0] - before.get(name, (0, 0.0))[0] >= units - 1
-    # unit 0 is the sampler's: probed, not submitted
-    assert table["probe"][0] - before.get("probe", (0, 0.0))[0] == 1
+
+    def passed(name):
+        return (table.get(name, (0, 0.0))[0]
+                - before.get(name, (0, 0.0))[0])
+
+    for name in ("resolve", "complete"):
+        assert passed(name) == units
+    # sampled, unit 0 is the sampler's: probed, not submitted
+    assert passed("probe") == int(probed)
+    assert passed("submit") == units - int(probed)
 
 
 def _crack(tmp_path, capsys, *extra):
@@ -225,14 +247,17 @@ def _crack(tmp_path, capsys, *extra):
                          if "=" in f)
 
 
+@SAMPLED
 def test_the_ran_line_carries_host_by_station(tmp_path, capsys,
-                                              monkeypatch):
+                                              monkeypatch, cadence,
+                                              probed):
     out, ran = _crack(tmp_path, capsys, "--device", "cpu")
     assert "zzy" in out
     host = dict(f.split(":") for f in ran["host"].split(","))
     # `targets` is the job's own, open around the hash file's parse
-    assert set(host) == {"targets", "lease", "submit", "probe", "resolve",
-                         "verify", "complete"}
+    assert set(host) == {"targets", "lease", "submit", "resolve",
+                         "verify", "complete"} | ({"probe"} if probed
+                                                     else set())
     assert list(host) == [s for s in STATIONS if s in host]
     assert all(re.fullmatch(r"\d+\.\d{3}", v) for v in host.values())
     assert float(host["submit"]) > 0
@@ -243,7 +268,30 @@ def test_the_ran_line_carries_host_by_station(tmp_path, capsys,
     assert out_off == out
 
 
-def test_the_unit_id_rides_every_span_but_lease(tmp_path, capsys):
+def test_the_ran_line_counts_probe_dispatches_only_when_asked(
+        tmp_path, capsys, monkeypatch):
+    """A device job (XLA on the CPU): unset, no dispatch is the
+    sampler's; DPRF_PERF_SAMPLE=2 sweeps every other unit per batch,
+    and the job prints what it printed without."""
+    def shapes(ran):
+        return {k: int(n) for k, n in
+                (f.split(":") for f in ran["dispatch"].split(","))}
+
+    monkeypatch.delenv("DPRF_PERF_SAMPLE", raising=False)
+    out, ran = _crack(tmp_path, capsys, "--batch", "1024")
+    assert "zzy" in out and "probe" not in shapes(ran)
+    assert "probe:" not in ran["host"]
+    monkeypatch.setenv("DPRF_PERF_SAMPLE", "2")
+    out_sampled, ran_sampled = _crack(tmp_path, capsys,
+                                      "--batch", "1024")
+    assert shapes(ran_sampled)["probe"] > 0
+    assert "probe:" in ran_sampled["host"]
+    assert out_sampled == out
+
+
+@SAMPLED
+def test_the_unit_id_rides_every_span_but_lease(tmp_path, capsys,
+                                                cadence, probed):
     """A profiler trace of a small device job (XLA on the CPU): the
     stations are events of the host's plane, `wait` lies inside a
     `resolve`, and all but `lease` (and `targets`, which is the job's,
@@ -269,8 +317,9 @@ def test_the_unit_id_rides_every_span_but_lease(tmp_path, capsys):
                            for e in line.events
                            if e.name.startswith("dprf:")]
     names = {e[0] for e in events}
-    assert {"dprf:" + s for s in ("lease", "submit", "probe", "resolve",
+    assert {"dprf:" + s for s in ("lease", "submit", "resolve",
                                   "wait", "complete")} <= names
+    assert ("dprf:probe" in names) == probed
     assert names <= {"dprf:" + s for s in STATIONS}
     for name, _, _, unit in events:
         assert (unit is None) == (name in ("dprf:lease",
@@ -281,8 +330,13 @@ def test_the_unit_id_rides_every_span_but_lease(tmp_path, capsys):
             assert any(r[1] <= s and e <= r[2] and r[3] == unit
                        for r in resolves)
     # a unit or a dispatch each, never a batch: 17,576 candidates in
-    # units of 4,096 are five units
-    assert len([e for e in events if e[0] == "dprf:submit"]) <= 5
+    # units of 4,096 are five units at most (the plant lies in the
+    # last); sampled, unit 0 passes `probe` and not `submit`
+    submitted = [e[3] for e in events if e[0] == "dprf:submit"]
+    units = sorted({e[3] for e in events if e[0] == "dprf:resolve"})
+    assert submitted == units[int(probed):] and len(units) <= 5
+    assert [e[3] for e in events if e[0] == "dprf:probe"] \
+        == units[:int(probed)]
 
 
 # -- the lint ----------------------------------------------------------------
