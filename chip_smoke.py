@@ -20,10 +20,7 @@ device_kind, worker class, interpret, the dispatch shapes actually used
 with their counts, compile seconds and persistent-cache hit or miss,
 candidates swept, wall seconds.  A phase FAILS when the worker is not
 the compiled kernel worker it expects, when a fused dispatch shape
-other than the expected one shows up (or the expected one does not;
-``probe`` counts the per-batch dispatches of the units the phase
-sampler sweeps where DPRF_PERF_SAMPLE is set: by default there are
-none),
+other than the expected one shows up (or the expected one does not),
 when the plant is not found, or when ``dprf audit`` is not clean.  Wall
 seconds are seconds, not a rate: the benchmark is ROADMAP S1.
 
@@ -204,7 +201,7 @@ def check_ran(smoke, log, workers, fused=None, advance=None,
                          "to the host oracle, not to the device re-probe")
     shapes = _shapes(ran["dispatch"])
     if fused is not None:
-        others = set(shapes) - {fused, "batch", "probe"}
+        others = set(shapes) - {fused, "batch"}
         if not shapes.get(fused) or others:
             raise PhaseError(
                 f"dispatch {ran['dispatch']!r}: expected fused shape "

@@ -59,18 +59,8 @@ declared it.
 ``flag = self.step(...)`` taints ``flag`` -- a later ``int(self._flag)``
 or ``if self._flag:`` in the loop is the same silent sync.
 
-**Sampled perf probes** (telemetry/perf.py) sync BY DESIGN: honest
-per-phase attribution needs block_until_ready boundaries, and
-sampling keeps them off the steady-state path.  A hot-path module
-declares its probe helpers in an explicit ``PERF_PROBE`` table::
-
-    PERF_PROBE = ("dprf_tpu.telemetry.perf.probe_pending",)
-
-Entries are dotted ``package.module.function`` paths (or local
-``func`` / ``Class.method`` names); calls that resolve to a declared
-probe are exempt from the sync rules.  Stale entries (no such
-function) are findings -- the table is a declaration, not a
-suppression.
+There is no list of helpers that may sync inside a hot loop: a sync
+there is a finding, whoever makes it.
 
 Scope: only modules declaring ``HOT_PATHS`` are analyzed, and only
 loops inside the named functions -- warmup, decode-after-flag, and
@@ -93,7 +83,7 @@ DESCRIPTION = ("silent-recompile and host-sync lint over the declared "
                "HOT_PATHS device loops (jit entries resolved through "
                "the call graph)")
 #: declaration tables --explain renders for this check
-DECL_TABLES = ("HOT_PATHS", "PERF_PROBE")
+DECL_TABLES = ("HOT_PATHS",)
 
 #: array-only methods that force a device sync
 SYNC_ATTRS = {"item", "tolist", "block_until_ready"}
@@ -355,53 +345,6 @@ def _resolve_hot(mod, qualname: str):
     return mod.functions.get(qualname)
 
 
-def _parse_probe_table(mod) -> tuple:
-    """([(entry, line)], shape findings) for the module's PERF_PROBE
-    declaration (the sampled-probe sync exemption)."""
-    out: list = []
-    findings: list = []
-    for node in mod.tree.body:
-        if not (isinstance(node, ast.Assign) and len(node.targets) == 1
-                and isinstance(node.targets[0], ast.Name)
-                and node.targets[0].id == "PERF_PROBE"):
-            continue
-        v = node.value
-        if not isinstance(v, (ast.Tuple, ast.List)):
-            findings.append(Finding(
-                NAME, mod.rel, node.lineno,
-                'PERF_PROBE must be a tuple of "pkg.mod.func" / '
-                '"func" / "Class.method" strings'))
-            continue
-        for e in v.elts:
-            s = const_str(e)
-            if s is None:
-                findings.append(Finding(
-                    NAME, mod.rel, node.lineno,
-                    "PERF_PROBE entries must be string literals"))
-                continue
-            out.append((s, node.lineno))
-    return out, findings
-
-
-def _resolve_probe(graph, mod, entry: str):
-    """A PERF_PROBE entry -> FuncInfo: a dotted in-package path
-    ("dprf_tpu.telemetry.perf.probe_pending"), or a local "func" /
-    "Class.method" name in the declaring module.  None = stale."""
-    if entry.startswith(graph.pkg + "."):
-        modpath, _, fname = entry.rpartition(".")
-        target = graph.load_dotted(modpath)
-        if target is None:
-            # "pkg.mod.Class.method" form: one more split
-            modpath2, _, cls = modpath.rpartition(".")
-            target = graph.load_dotted(modpath2)
-            if target is None:
-                return None
-            ci = target.classes.get(cls)
-            return ci.methods.get(fname) if ci is not None else None
-        return target.functions.get(fname)
-    return _resolve_hot(mod, entry)
-
-
 # ---------------------------------------------------------------------------
 # one hot function's walk
 
@@ -479,7 +422,7 @@ class _HotWalker:
     loops."""
 
     def __init__(self, fi, graph, resolver, syncer, local_jits,
-                 attr_jits, loop_vars, rel, find, probe_keys=()):
+                 attr_jits, loop_vars, rel, find):
         self.fi = fi
         self.g = graph
         self.resolver = resolver
@@ -489,9 +432,6 @@ class _HotWalker:
         self.loop_vars = loop_vars
         self.rel = rel
         self.find = find
-        #: FuncInfo keys of the module's declared PERF_PROBE helpers:
-        #: calls resolving to these are exempt from the sync rules
-        self.probe_keys = frozenset(probe_keys)
         self.sc = graph.scope(fi)
         #: tainted device values: plain names AND dotted attribute
         #: chains ("self._flag") -- expr_key normalized
@@ -661,10 +601,6 @@ class _HotWalker:
         callee = self.g.resolve_call(call, self.sc)
         if callee is None or callee.key == self.fi.key:
             return
-        if callee.key in self.probe_keys:
-            # declared sampled perf probe (PERF_PROBE table): its
-            # syncs are the measurement, not a bug
-            return
 
         def _arg_tainted(a) -> bool:
             if isinstance(a, ast.Name):
@@ -727,23 +663,7 @@ def run(ctx) -> list:
         rel = ctx.rel(path)
         hot, shape_findings = _parse_hot_paths(mod)
         findings.extend(shape_findings)
-        probes, probe_findings = _parse_probe_table(mod)
-        findings.extend(probe_findings)
-        probe_keys = set()
-        for entry, pline in probes:
-            pfi = _resolve_probe(graph, mod, entry)
-            if pfi is None:
-                find(rel, pline,
-                     f"PERF_PROBE declares unknown function "
-                     f"{entry!r} -- stale declaration")
-            else:
-                probe_keys.add(pfi.key)
         if not hot:
-            if probes:
-                # a probe table with no hot paths exempts nothing
-                find(rel, probes[0][1],
-                     "PERF_PROBE declared in a module with no "
-                     "HOT_PATHS -- the exemption applies to nothing")
             continue
         attr_jits = _module_attr_jits(mod, graph, resolver)
         for qualname, dline in hot:
@@ -767,6 +687,5 @@ def run(ctx) -> list:
                         local_jits[st.targets[0].id] = stat
             loop_vars = _collect_loop_vars(fi.node)
             _HotWalker(fi, graph, resolver, syncer, local_jits,
-                       attr_jits, loop_vars, rel, find,
-                       probe_keys=probe_keys).walk()
+                       attr_jits, loop_vars, rel, find).walk()
     return findings

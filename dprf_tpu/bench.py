@@ -239,8 +239,7 @@ def _round_phases(phases: dict) -> dict:
 
 def _step_phases(gen, step, batch: int) -> dict:
     """Per-phase breakdown of ONE per-batch step dispatch with forced
-    sync boundaries (the bench-side analogue of the runtime's sampled
-    probe, telemetry/perf.py): generate / h2d / device / d2h.  One
+    sync boundaries: generate / h2d / device / d2h.  One
     dispatch outside the timed window -- the syncs that make the
     attribution honest must never touch the measured loop."""
     import jax
@@ -1045,13 +1044,6 @@ def run_config(config: int, device: str = "jax", seconds: float = 5.0,
         log.info("config compiled", config=config,
                  seconds=f"{compile_s:.1f}", cache=compile_cache)
 
-    # per-phase attribution of one stride through the REAL worker
-    # (telemetry/perf.py probe; outside the timed window, compiled
-    # already) -- bench JSON carries the breakdown
-    from dprf_tpu.telemetry.perf import probe_phases
-    phases = _round_phases(probe_phases(
-        worker, WorkUnit(-1, 0, min(stride, gen.keyspace))))
-
     from dprf_tpu.runtime.worker import submit_or_process
 
     tested = 0
@@ -1106,7 +1098,6 @@ def run_config(config: int, device: str = "jax", seconds: float = 5.0,
         "tested": tested,
         "elapsed_s": round(elapsed, 3),
         "compile_s": round(compile_s, 1),
-        "phases": phases,
         **_compile_fields(compile_cache, compile_s),
         **_introspection_fields(engine_name, tested / elapsed),
     }, mode="config")

@@ -2,10 +2,10 @@
 artifacts alone.
 
 Reads the journal family a run leaves behind -- ``<session>`` (job
-identity + per-job records), ``<session>.trace.jsonl`` (lifecycle +
-phase spans), ``<session>.telemetry.jsonl`` (periodic registry
+identity + per-job records), ``<session>.trace.jsonl`` (lifecycle
+spans), ``<session>.telemetry.jsonl`` (periodic registry
 snapshots) -- and renders what a perf post-mortem needs without a
-live coordinator: throughput, per-phase p50/p95 breakdown, device
+live coordinator: throughput, the host's hit verification, device
 busy fraction per worker, compile-cache behavior, pipeline depth,
 and per-job fair-share actual-vs-weight.
 """
@@ -15,7 +15,6 @@ from __future__ import annotations
 import os
 from typing import Optional
 
-from dprf_tpu.telemetry.perf import PHASES
 from dprf_tpu.telemetry.snapshot import load_snapshots, telemetry_path
 from dprf_tpu.telemetry.trace import load_trace, trace_path
 
@@ -46,63 +45,18 @@ def _counter_total(snapshot, name: str, **labels) -> float:
     return total
 
 
-def _phase_stats(spans: list, sample_scale: float = 1.0) -> dict:
-    """phase -> {count, p50_s, p95_s, total_s, share, per_cand_ns}.
-    The generate/h2d/device/d2h durations come from SAMPLED probes
-    (every Nth unit) while ``verify`` comes from every hit batch's
-    hit_verify span, so the share denominator scales the sampled
-    totals by the observed cadence (units / probed units) -- without
-    it, verify's share would inflate by the sampling factor.
-    ``total_s``/p50/p95/count stay the observed values.
-
-    ``per_cand_ns`` divides each phase's observed time by the
-    candidates its probed units actually hashed (the ``cands`` attr
-    the probe records since ISSUE 19).  A Pallas superstep unit runs
-    many inner batches per probe while the baseline probes one batch,
-    so raw per-unit totals are incomparable across ``--impl``; the
-    per-candidate cost is the number that lines up."""
-    by_phase: dict = {}
-    cands_by_phase: dict = {}
-    for s in spans:
-        if s.get("name") != "phase":
-            continue
-        a = s.get("attrs") or {}
-        ph = a.get("phase")
-        if ph:
-            by_phase.setdefault(str(ph), []).append(
-                float(s.get("dur", 0.0)))
-            try:
-                cands_by_phase[str(ph)] = (
-                    cands_by_phase.get(str(ph), 0)
-                    + int(a.get("cands") or 0))
-            except (TypeError, ValueError):
-                pass
-    # hit_verify spans carry the verify cost for EVERY hit batch
-    for s in spans:
-        if s.get("name") == "hit_verify":
-            by_phase.setdefault("verify", []).append(
-                float(s.get("dur", 0.0)))
-    scale = max(1.0, float(sample_scale))
-
-    def scaled(ph: str) -> float:
-        t = sum(by_phase.get(ph, ()))
-        return t if ph == "verify" else t * scale
-
-    total_all = sum(scaled(ph) for ph in by_phase) or 1.0
-    out = {}
-    for ph in PHASES:
-        durs = sorted(by_phase.get(ph, ()))
-        if not durs:
-            continue
-        cands = cands_by_phase.get(ph, 0)
-        out[ph] = {"count": len(durs),
-                   "p50_s": round(_pct(durs, 0.50), 6),
-                   "p95_s": round(_pct(durs, 0.95), 6),
-                   "total_s": round(sum(durs), 6),
-                   "share": round(scaled(ph) / total_all, 4),
-                   "per_cand_ns": (round(sum(durs) / cands * 1e9, 3)
-                                   if cands else None)}
-    return out
+def _verify_stats(spans: list) -> Optional[dict]:
+    """{count, p50_s, p95_s, total_s} of the host's verification of
+    reported hits, from every hit batch's ``hit_verify`` span; None
+    for a run that verified none."""
+    durs = sorted(float(s.get("dur", 0.0)) for s in spans
+                  if s.get("name") == "hit_verify")
+    if not durs:
+        return None
+    return {"count": len(durs),
+            "p50_s": round(_pct(durs, 0.50), 6),
+            "p95_s": round(_pct(durs, 0.95), 6),
+            "total_s": round(sum(durs), 6)}
 
 
 def _busy_by_worker(spans: list) -> dict:
@@ -326,15 +280,11 @@ def build_report(session_path: str) -> Optional[dict]:
     misses = _counter_total(last, "dprf_compile_cache_misses_total")
     depth_vals = _metric_values(last, "dprf_worker_pipeline_depth")
     sweeps = [s for s in spans if s.get("name") == "sweep"]
-    probed = sum(1 for s in sweeps
-                 if (s.get("attrs") or {}).get("probed"))
-    sample_scale = (len(sweeps) / probed) if probed else 1.0
     return {
         "session": session_path,
         "engine": engine,
         "spans": len(spans),
         "units": len(sweeps),
-        "probed_units": probed,
         "throughput": {
             "hs": rate,
             "trace_hs": thr["trace_hs"],
@@ -348,7 +298,7 @@ def build_report(session_path: str) -> Optional[dict]:
                  if (v.get("labels") or {}).get("engine") == engine
                  and v.get("value")), None),
         },
-        "phases": _phase_stats(spans, sample_scale=sample_scale),
+        "verify": _verify_stats(spans),
         "busy": _busy_by_worker(spans),
         "compile_cache": {
             "hits": int(hits), "misses": int(misses),
@@ -379,7 +329,7 @@ def render_report(doc: dict) -> str:
     report``; CI uploads it as an artifact)."""
     lines = [f"dprf report — {doc['session']}",
              f"engine {doc.get('engine') or '?'} | "
-             f"{doc['units']} units ({doc['probed_units']} probed) | "
+             f"{doc['units']} units | "
              f"{doc['spans']} spans"]
     thr = doc["throughput"]
     roof = thr.get("roofline_frac")
@@ -390,26 +340,14 @@ def render_report(doc: dict) -> str:
                  + (f"  (roofline {roof:.2f})" if roof else ""))
     if thr.get("telemetry_hs") and thr.get("trace_hs"):
         lines.append(f"  telemetry  {_fmt_hs(thr['telemetry_hs'])}")
-    phases = doc.get("phases") or {}
-    if phases:
+    ver = doc.get("verify")
+    if ver:
         lines.append("")
-        lines.append("phase breakdown (sampled probes)")
-        lines.append(f"  {'PHASE':9s} {'COUNT':>6s} {'P50':>10s} "
-                     f"{'P95':>10s} {'TOTAL':>10s} {'SHARE':>6s} "
-                     f"{'PER-CAND':>10s}")
-        for ph in PHASES:
-            st = phases.get(ph)
-            if not st:
-                continue
-            pc = st.get("per_cand_ns")
-            lines.append(
-                f"  {ph:9s} {st['count']:>6d} "
-                f"{st['p50_s'] * 1e3:>8.2f}ms "
-                f"{st['p95_s'] * 1e3:>8.2f}ms "
-                f"{st['total_s']:>9.3f}s "
-                f"{100 * st['share']:>5.1f}% "
-                + (f"{pc:>8.2f}ns" if pc is not None
-                   else f"{'-':>10s}"))
+        lines.append("host verify (every hit batch)")
+        lines.append(f"  {ver['count']} batches  "
+                     f"p50 {ver['p50_s'] * 1e3:.2f}ms  "
+                     f"p95 {ver['p95_s'] * 1e3:.2f}ms  "
+                     f"total {ver['total_s']:.3f}s")
     cov = doc.get("coverage")
     if cov:
         lines.append("")

@@ -115,11 +115,9 @@ class Coordinator:
         #: one by default)
         self.tracer = get_tracer(recorder)
         self._registry = get_registry(registry)
-        #: per-phase sweep attribution (ISSUE 9), opt-in: with
-        #: DPRF_PERF_SAMPLE=N every Nth unit runs the synced probe,
-        #: unset none does; verify timing is recorded either way
-        self._perf = perf_mod.PerfSampler(registry=self._registry,
-                                          recorder=self.tracer)
+        #: verify-phase attribution (telemetry/perf.py): the oracle
+        #: re-hash cost of every hit batch
+        self._h_phase = perf_mod.phase_histogram(self._registry)
         from dprf_tpu.telemetry import declare_job_metrics
         jm = declare_job_metrics(self._registry)
         self._m_hits = jm["hits"]
@@ -264,16 +262,7 @@ class Coordinator:
                                 cache=getattr(self.worker,
                                               "compile_cache", None),
                                 overlapped=True)
-                    probe = None
-                    if self._perf.take():
-                        # sampled unit (DPRF_PERF_SAMPLE set): serial
-                        # synced sweep with per-phase attribution
-                        # (declared PERF_PROBE)
-                        pctx = self.dispatcher.trace_context(
-                            unit.unit_id)
-                        probe = (self._perf,
-                                 pctx[0] if pctx else None)
-                    pipeline.submit(unit, probe=probe)
+                    pipeline.submit(unit)
                 if not len(pipeline):
                     if self.dispatcher.done() or \
                             self.dispatcher.outstanding_count() == 0:
@@ -299,12 +288,8 @@ class Coordinator:
                     "sweep", dur=unit_s,
                     trace=ctx[0] if ctx else None,
                     parent=ctx[1] if ctx else None, proc="local",
-                    # a probed unit's sweep span carries the id its
-                    # phase children were parented on
-                    span=getattr(p, "sweep_span", None),
                     unit=unit.unit_id, length=unit.length,
-                    hits=len(hits),
-                    probed=getattr(p, "sweep_span", None) is not None)
+                    hits=len(hits))
                 if hits:
                     t_verify = time.monotonic()
                     rejected0 = self.rejected
@@ -312,9 +297,10 @@ class Coordinator:
                                              unit=unit.unit_id):
                         self._finish_unit(unit, hits)
                     verify_s = time.monotonic() - t_verify
-                    self._perf.observe_verify(verify_s,
-                                              engine=self.spec.engine,
-                                              job=self.dispatcher.job_id)
+                    self._h_phase.observe(
+                        verify_s, phase="verify",
+                        engine=self.spec.engine,
+                        job=str(self.dispatcher.job_id))
                     self.tracer.record(
                         "hit_verify",
                         dur=verify_s,

@@ -1791,11 +1791,6 @@ def worker_loop(client: CoordinatorClient, worker, worker_id: str,
         "dprf_worker_idle_seconds",
         "seconds this worker held no submitted unit between sweeps "
         "(pipeline drained: the device idles while RPCs fly)")
-    # sampled per-phase attribution (telemetry/perf.py), opt-in: with
-    # DPRF_PERF_SAMPLE=N every Nth unit runs the serial synced probe,
-    # and its phase spans ship back with the complete report like any
-    # other worker span; unset, no unit leaves the pipelined submit
-    sampler = perf_mod.PerfSampler(registry=m, recorder=tracer)
     # kernel-profiling plane (ISSUE 15): on-demand bounded capture
     # windows requested over lease/heartbeat responses.  The loop
     # keeps sweeping while the trace records; poll_profile() is ONE
@@ -2123,11 +2118,9 @@ def worker_loop(client: CoordinatorClient, worker, worker_id: str,
                             # work to hide them behind)
                             c_idle.inc(time.monotonic() - idle_mark)
                             idle_mark = None
-                        probe = ((sampler, tid) if sampler.take()
-                                 else None)
                         pipe.submit(unit,
                                     meta=(tid, lease_sid, ship, job, w),
-                                    worker=w, probe=probe)
+                                    worker=w)
                         cur = None
                 if len(pipe) == 0:
                     if stop_seen:
@@ -2179,20 +2172,12 @@ def worker_loop(client: CoordinatorClient, worker, worker_id: str,
                 m_cands.inc(unit.length, engine=eng_name, device=device)
                 # ts backdates to t_submit, so consecutive sweep spans
                 # OVERLAP when the loop pipelines (the invariant
-                # tools/trace_overlap.py checks).  A probed unit's
-                # sweep span carries the pre-allocated id its phase
-                # children parent onto, and ships them along.
-                psid = getattr(pending, "sweep_span", None)
-                pspans = getattr(pending, "phase_spans", None)
-                if pspans:
-                    ship.extend(pspans)
+                # tools/trace_overlap.py checks).
                 ev = tracer.record("sweep", dur=unit_s, trace=tid,
                                    parent=lease_sid, proc=worker_id,
-                                   span=psid,
                                    unit=unit.unit_id, job=job,
                                    length=unit.length,
-                                   hits=len(hits),
-                                   probed=psid is not None)
+                                   hits=len(hits))
                 if ev:
                     ship.append(ev)
                 payload = [{"target": h.target_index,

@@ -100,12 +100,10 @@ def count_dispatches(worker, kind: str, n: int = 1) -> None:
 def describe_worker(worker) -> dict:
     """What actually runs a job's units, for the job's log: the worker
     class, whether its kernels are interpreted ("n/a": it has no
-    kernel, its step is plain XLA), the dispatch shapes
-    used so far with their counts (`probe`: the per-batch, synced
-    dispatches of the units DPRF_PERF_SAMPLE asked to sample,
-    telemetry/perf.py; absent unless it is set), and the
-    compile cost with its persistent-cache classification, and for a
-    multi-target job what the host verified (`verify`: oracle hashes
+    kernel, its step is plain XLA), the dispatch shapes used so far
+    with their counts, the compile cost with its persistent-cache
+    classification, and for a multi-target job what the host
+    verified (`verify`: oracle hashes
     of maybe lanes, collided tiles resolved to their maybe lanes on
     the device, collided tiles rescanned whole on the host; for a bulk
     list also the lanes its bitmap passed and the hits the device
@@ -141,14 +139,6 @@ def describe_worker(worker) -> dict:
 #: Everything submit() enqueues rides the device stream; a host sync
 #: or a retrace inside it stalls every unit of every job.
 HOT_PATHS = ("MaskWorkerBase.submit",)
-
-#: `dprf check` retrace analyzer: the SAMPLED perf probe is ALLOWED
-#: to sync inside hot loops -- forced block_until_ready boundaries
-#: are how per-phase attribution stays honest, and sampling
-#: (DPRF_PERF_SAMPLE, off unless set) keeps them off the
-#: steady-state path.  An explicit declaration, not a suppression
-#: comment: stale entries are findings.
-PERF_PROBE = ("dprf_tpu.telemetry.perf.probe_pending",)
 
 #: env override for the submit-ahead depth both pipelined loops run at
 PIPELINE_DEPTH_ENV = "DPRF_PIPELINE_DEPTH"
@@ -243,42 +233,23 @@ class UnitPipeline:
     def full(self) -> bool:
         return len(self._q) >= self.depth
 
-    def submit(self, unit, meta=None, worker=None, probe=None) -> None:
+    def submit(self, unit, meta=None, worker=None) -> None:
         """Dispatch the unit's device work now (enqueue-only for
         submit-based workers; a serial worker's process runs here) and
         queue it for a later resolve.  ``worker`` overrides the
         pipeline's default for THIS unit -- a multi-job worker loop
         routes each unit to its job's worker while sharing one
         submit-ahead queue.
-
-        ``probe`` = (PerfSampler, trace id) routes THIS unit through
-        the sampled per-phase sweep (telemetry/perf.py; None unless
-        DPRF_PERF_SAMPLE is set): serial and synced, so the phase
-        breakdown is honest; the resolved entry carries its phase
-        spans and the pre-allocated sweep span id.
         The submit timestamp is taken BEFORE the dispatch so a
-        serial/probed unit's submit-to-resolve time covers its real
+        serial unit's submit-to-resolve time covers its real
         work, not just queue wait."""
         import time
         t0 = time.monotonic()
         w = worker or self.worker
         # the pipeline itself never looks into a unit
         tracer, uid = get_tracer(), getattr(unit, "unit_id", None)
-        if probe is not None:
-            from dprf_tpu.telemetry.perf import (drain_backlog,
-                                                 probe_pending)
-            with tracer.station("probe", unit=uid):
-                # the probe's first sync must measure ITS unit, not
-                # the queued units' device backlog: wait for the
-                # stream to drain first (the probe serializes anyway
-                # -- this only moves the wait out of the attributed
-                # phases)
-                drain_backlog(self._q)
-                pending = probe_pending(w, unit, probe[0],
-                                        trace=probe[1])
-        else:
-            with tracer.station("submit", unit=uid):
-                pending = submit_or_process(w, unit)
+        with tracer.station("submit", unit=uid):
+            pending = submit_or_process(w, unit)
         self._q.append((unit, pending, t0, meta))
 
     def pop(self):

@@ -33,11 +33,8 @@ Metric names (all prefixed ``dprf_``; see README "Observability"):
   dprf_worker_idle_seconds                      seconds a worker held
                                                 no submitted unit
                                                 (device idle)
-  dprf_phase_seconds{phase,engine,job}          per-phase attribution:
-                                                verify always, the
-                                                sweep's phases under
-                                                DPRF_PERF_SAMPLE=N
-                                                (perf.py)
+  dprf_phase_seconds{phase,engine,job}          every hit batch's
+                                                host verify (perf.py)
   dprf_device_busy_fraction{worker}             live sliding-window
                                                 sweep coverage
   dprf_roofline_frac{engine}                    EWMA throughput / the

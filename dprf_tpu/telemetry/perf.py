@@ -1,47 +1,15 @@
-"""Performance attribution (ISSUE 9): per-phase sweep accounting,
+"""Performance attribution (ISSUE 9): the verify phase's histogram,
 roofline distance, and scaling-efficiency metrics.
 
-The metrics layer says how fast the fleet sweeps and the trace layer
-says which unit ran where -- but neither can say WHERE a sweep's time
-goes.  This module splits the worker hot path into PHASES:
-
-  generate   host-side candidate material (mixed-radix digits, word
-             windows) for one dispatch
-  h2d        host->device transfer of the step arguments
-  device     the fused crack step itself (dispatch + device compute)
-  d2h        device->host result fetch + hit decode
-  verify     CPU-oracle re-hash of reported hits (coordinator side)
-
-recorded two ways: ``phase`` child spans under the unit's ``sweep``
-span (so Perfetto shows the breakdown per unit) and a
-``dprf_phase_seconds{phase,engine,job}`` histogram (so ``/metrics``
-and ``dprf report`` show fleet-wide p50/p95 per phase).
-
-Honest phase timing needs ``block_until_ready`` boundaries between
-the phases -- exactly the host syncs the retrace analyzer forbids on
-the steady-state path, because they drain the device stream.  So
-attribution is SAMPLED and OPT-IN: ``DPRF_PERF_SAMPLE=N`` routes one
-unit in N through ``probe_pending`` -- a serial, synced sweep of that
-one unit, behind an emptied pipeline -- while every other unit runs
-the normal pipelined submit.  Unset (the default, 0) no unit leaves
-the fused dispatch: the probe perturbs what it times (on a TPU v5e a
-probed unit of 2^28 md5 candidates takes 290 ms, a fused one 54; at
-N=16 that was a fifth to a third of three benchmark cells, PERF.md
-PR 31), and every unit's ``submit``/``wait``/``decode`` seconds are
-on the job's ``ran host=`` line and in a device trace from the
-stations (telemetry/trace.py), unsampled and unsynced.  Only the
-``verify`` phase is recorded without the knob.  ``probe_pending`` is
-declared in the hot-path modules' ``PERF_PROBE`` tables, the retrace
-analyzer's explicit exemption list for deliberately-syncing sampled
-probes (a declaration, not a suppression comment).
-
-The probed sweep produces exactly the hits the normal path would:
-the phase loop is the per-batch step contract
-(``MaskWorkerBase.submit`` without super/wide fusion), decoded
-through the worker's own ``_batch_hits``/``_window_hits``.  Workers
-with a custom serial ``process`` (per-salt blocks, per-target steps)
-are probed coarsely: their whole ``process`` is one ``device`` phase,
-because re-implementing their sweep here would risk wrong hits.
+Where a unit's time goes is the stations' to say
+(telemetry/trace.py ``STATIONS``): every unit's ``submit`` / ``wait`` /
+``decode`` / ``verify`` / ``complete`` seconds are on the job's ``ran
+host=`` line and, while a device trace runs, events of the host's
+plane beside the device's programs -- unsampled and unsynced.  What
+stays here of the per-phase accounting is
+``dprf_phase_seconds{phase="verify",engine,job}``: the CPU oracle's
+re-hash of a unit's reported hits, observed by both coordinators on
+every hit batch.
 
 Also here, because bench and the live fleet must share one model:
 
@@ -60,19 +28,13 @@ Also here, because bench and the live fleet must share one model:
 
 from __future__ import annotations
 
-import time
 from typing import Optional
 
 from dprf_tpu.telemetry import get_registry
-from dprf_tpu.telemetry.trace import get_tracer, new_span_id
-from dprf_tpu.utils import env as envreg
 
-#: attribution phases, in hot-path order; the ONE declaration site for
-#: the ``dprf_phase_seconds`` phase label values
-PHASES = ("generate", "h2d", "device", "d2h", "verify")
-
-#: sampling cadence knob: probe every Nth unit (0, the default: none)
-SAMPLE_ENV = "DPRF_PERF_SAMPLE"
+#: the ONE declaration site for the ``dprf_phase_seconds`` phase label
+#: values
+PHASES = ("verify",)
 
 #: EWMA smoothing for the live roofline gauge (one unit's elapsed is
 #: noisy; the gauge should read like a rate, not a jitter plot)
@@ -114,278 +76,15 @@ OPS_PER_CANDIDATE = {
 }
 
 
-def sample_every() -> int:
-    """The probe cadence: every Nth unit runs the synced phase sweep;
-    0 (the knob's declared default, utils/env.py: the one default)
-    means no unit does."""
-    return max(0, envreg.get_int(SAMPLE_ENV))
-
-
 def phase_histogram(registry=None):
     """``dprf_phase_seconds`` -- the ONE declaration site (the metrics
     analyzer enforces single-site declarations)."""
     return get_registry(registry).histogram(
         "dprf_phase_seconds",
-        "seconds per attribution phase of a sampled sweep "
-        "(generate/h2d/device/d2h from probed units; verify from "
-        "every hit verification)",
+        "seconds per attribution phase of a unit (verify: the CPU "
+        "oracle's re-hash of a unit's reported hits, from every hit "
+        "verification)",
         labelnames=("phase", "engine", "job"))
-
-
-def worker_engine(worker) -> str:
-    return getattr(getattr(worker, "engine", None), "name", "unknown")
-
-
-class PerfSampler:
-    """Per-loop sampling state + the publication surface the probed
-    sweep records into.  One per run loop (local Coordinator /
-    remote worker_loop); ``take()`` answers "is THIS unit the sampled
-    one" on the configured cadence (unit 1, N+1, 2N+1, ...; never,
-    at the default cadence of 0)."""
-
-    __slots__ = ("every", "hist", "tracer", "_n")
-
-    def __init__(self, registry=None, recorder=None,
-                 every: Optional[int] = None):
-        self.every = sample_every() if every is None else max(0, every)
-        self.hist = phase_histogram(registry)
-        self.tracer = get_tracer(recorder)
-        self._n = 0
-
-    def take(self) -> bool:
-        if self.every <= 0:
-            return False
-        self._n += 1
-        return (self._n - 1) % self.every == 0
-
-    def observe_verify(self, seconds: float, engine: str = "unknown",
-                       job: str = "j0") -> None:
-        """The verify phase is real work on every hit batch (no forced
-        sync needed), so it is recorded unsampled."""
-        self.hist.observe(seconds, phase="verify", engine=engine,
-                          job=str(job))
-
-
-class _ProbedUnit:
-    """Resolved result of a probed sweep: quacks like PendingUnit
-    (``resolve()``), carries the phase breakdown and the spans a
-    remote worker ships with its complete report.  ``sweep_span`` is
-    the pre-allocated span id the caller must record the unit's sweep
-    span under, so the phase spans parent onto it.
-
-    ``cands``/``batches`` (ISSUE 19 satellite): how many candidates
-    the probed sweep covered, over how many dispatches.  A fused
-    (loop-superstep / coarse) probe books its whole window as ONE
-    ``device`` sample while the per-batch probe books one unit of many
-    small dispatches -- so raw phase seconds are not comparable across
-    ``--impl`` variants.  The counts ride the phase spans and let
-    `dprf report` normalize to per-candidate phase cost."""
-
-    __slots__ = ("hits", "phases", "phase_spans", "sweep_span",
-                 "cands", "batches")
-
-    def __init__(self, hits, phases, phase_spans, sweep_span,
-                 cands=0, batches=0):
-        self.hits = hits
-        self.phases = phases
-        self.phase_spans = phase_spans
-        self.sweep_span = sweep_span
-        self.cands = cands
-        self.batches = batches
-
-    def resolve(self):
-        return self.hits
-
-
-def drain_backlog(queue) -> None:
-    """Block until every already-queued pipeline entry's device work
-    is done (its accumulated unit flag is ready), WITHOUT resolving
-    anything -- called right before a sampled probe so the probe's
-    first sync boundary attributes its own unit's work, not the
-    stream backlog the pipeline deliberately keeps full.  Entries
-    without a flag (serial workers' already-resolved units) need no
-    drain."""
-    for entry in queue:
-        flag = getattr(entry[1], "flag", None)
-        if flag is not None:
-            _block(flag)
-
-
-def _block(x) -> None:
-    try:
-        import jax
-        jax.block_until_ready(x)
-    except (ImportError, AttributeError, TypeError):
-        bur = getattr(x, "block_until_ready", None)
-        if bur is not None:
-            bur()
-
-
-def _probe_strategy(worker) -> str:
-    """Which instrumented sweep is SAFE for this worker.  Only the two
-    standard submit loops are re-implemented here; any class with its
-    own ``process`` (per-salt blocks, per-target steps, CPU oracle)
-    keeps its override and is probed coarsely."""
-    from dprf_tpu.parallel import worker as pw
-    from dprf_tpu.runtime import worker as rw
-    proc = getattr(type(worker), "process", None)
-    if proc is rw.DeviceWordlistWorker.process:
-        return "wordlist"
-    if proc is rw.MaskWorkerBase.process:
-        return "digit"
-    if proc is pw.ShardedMaskWorker.process:
-        # same per-batch (base_digits, n_valid) contract + _batch_hits
-        # decode; probing it per stride makes the sharded path's ~zero
-        # h2d visible in the phase report
-        return "digit"
-    return "coarse"
-
-
-def _probe_digit(worker, unit) -> tuple:
-    """Per-batch (base_digits, n_valid) contract with forced sync
-    boundaries between phases -- MaskWorkerBase.submit minus the
-    super/wide fusion, decoded through the worker's own _batch_hits
-    so a probed unit yields exactly the production hits."""
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
-    t = {"generate": 0.0, "h2d": 0.0, "device": 0.0, "d2h": 0.0}
-    hits: list = []
-    batches = 0
-    perf = time.perf_counter
-    tracer = get_tracer()
-    for bstart in range(unit.start, unit.end, worker.stride):
-        n_valid = min(worker.stride, unit.end - bstart)
-        t0 = perf()
-        digits = np.asarray(worker.gen.digits(bstart), dtype=np.int32)
-        t1 = perf()
-        t["generate"] += t1 - t0
-        base = jax.device_put(digits)
-        _block(base)
-        nv = jnp.int32(n_valid)
-        _block(nv)
-        t2 = perf()
-        t["h2d"] += t2 - t1
-        result = worker.step(base, nv)
-        _block(result)
-        t3 = perf()
-        t["device"] += t3 - t2
-        with tracer.station("decode", unit=unit.unit_id):
-            hits.extend(worker._batch_hits(bstart, result, unit))
-        t["d2h"] += perf() - t3
-        batches += 1
-    return t, hits, unit.length, batches
-
-
-def _probe_wordlist(worker, unit) -> tuple:
-    """Word-window contract ((w0, n_valid_words) scalars): candidate
-    generation happens ON DEVICE via the rule interpreter, so the
-    generate phase is folded into ``device`` and h2d is the scalar
-    argument transfer."""
-    import jax.numpy as jnp
-
-    from dprf_tpu.runtime.worker import word_cover_range
-    t = {"generate": 0.0, "h2d": 0.0, "device": 0.0, "d2h": 0.0}
-    hits: list = []
-    batches = 0
-    perf = time.perf_counter
-    tracer = get_tracer()
-    w_start, w_end = word_cover_range(unit, worker.gen.n_rules)
-    w_end = min(w_end, worker.gen.n_words)
-    ws = w_start
-    while ws < w_end:
-        nw = min(worker.word_batch, w_end - ws)
-        t0 = perf()
-        w0 = jnp.int32(ws)
-        nv = jnp.int32(nw)
-        _block((w0, nv))
-        t1 = perf()
-        t["h2d"] += t1 - t0
-        result = worker.step(w0, nv)
-        _block(result)
-        t2 = perf()
-        t["device"] += t2 - t1
-        with tracer.station("decode", unit=unit.unit_id):
-            hits.extend(worker._window_hits(ws, nw, result, unit))
-        t["d2h"] += perf() - t2
-        ws += nw
-        batches += 1
-    # the sweep covers whole word windows; out-of-unit hits are
-    # filtered, but the device DID hash the covering lanes
-    return t, hits, (w_end - w_start) * worker.gen.n_rules, batches
-
-
-def _probe_coarse(worker, unit) -> tuple:
-    """Fallback for workers with their own serial ``process``: one
-    honest total under ``device`` beats a wrong re-implementation of
-    a per-salt sweep.  A fused (loop-superstep) process books the
-    WHOLE unit as one device sample, so the candidate count riding
-    the probe is what keeps its phase cost comparable to the
-    per-batch probes (per-candidate normalization in `dprf
-    report`)."""
-    t0 = time.perf_counter()
-    hits = worker.process(unit)
-    return {"device": time.perf_counter() - t0}, hits, unit.length, 1
-
-
-def probe_phases(worker, unit) -> dict:
-    """Phase breakdown of one synced sweep, no publication -- the
-    bench-side entry (``dprf bench`` reports it as ``phases``)."""
-    strategy = _probe_strategy(worker)
-    if strategy == "wordlist":
-        phases, _, _, _ = _probe_wordlist(worker, unit)
-    elif strategy == "digit":
-        phases, _, _, _ = _probe_digit(worker, unit)
-    else:
-        phases, _, _, _ = _probe_coarse(worker, unit)
-    return phases
-
-
-def probe_pending(worker, unit, sampler: PerfSampler,
-                  trace: Optional[str] = None) -> _ProbedUnit:
-    """The SAMPLED unit's sweep: serial, with block_until_ready
-    boundaries between phases (this is the helper the hot-path
-    modules declare in ``PERF_PROBE`` -- the syncs are the point).
-    Records one ``phase`` span per phase (parented on the
-    pre-allocated sweep span id the caller records the sweep under)
-    plus the phase histogram, and returns a resolved PendingUnit
-    stand-in carrying the spans for RPC shipping."""
-    strategy = _probe_strategy(worker)
-    if strategy == "wordlist":
-        phases, hits, cands, batches = _probe_wordlist(worker, unit)
-    elif strategy == "digit":
-        phases, hits, cands, batches = _probe_digit(worker, unit)
-    else:
-        phases, hits, cands, batches = _probe_coarse(worker, unit)
-    # what ran (runtime.worker.describe_worker): a probed unit's
-    # dispatches are per-batch and synced, not the production shape
-    from dprf_tpu.runtime.worker import count_dispatches
-    count_dispatches(getattr(worker, "_worker", worker), "probe",
-                     batches)
-    sweep_span = new_span_id()
-    engine = worker_engine(worker)
-    job = getattr(unit, "job_id", "j0")
-    spans = []
-    ts = time.time() - sum(phases.values())
-    for phase in PHASES:
-        dur = phases.get(phase)
-        if dur is None:
-            continue
-        sampler.hist.observe(dur, phase=phase, engine=engine,
-                             job=str(job))
-        # cands/batches ride every phase span (ISSUE 19 satellite):
-        # `dprf report` divides phase seconds by candidates probed, so
-        # a coarse fused probe (whole window = ONE device sample) and
-        # the per-batch probes stay comparable across --impl variants
-        ev = sampler.tracer.record(
-            "phase", dur=dur, ts=ts, trace=trace, parent=sweep_span,
-            phase=phase, unit=unit.unit_id, job=job, engine=engine,
-            cands=cands, batches=batches)
-        ts += dur
-        if ev is not None:
-            spans.append(ev)
-    return _ProbedUnit(hits, phases, spans, sweep_span,
-                       cands=cands, batches=batches)
 
 
 # ---------------------------------------------------------------------------

@@ -66,23 +66,19 @@ from typing import Optional
 from dprf_tpu.utils import env as envreg
 
 #: the one declaration site for span names (tools/check_metrics.py
-#: enforces that every record() literal is a member).  ``phase`` is a
-#: child of a sampled unit's ``sweep`` span: one per attribution
-#: phase (telemetry/perf.py), attrs carry which phase.
+#: enforces that every record() literal is a member).
 SPAN_NAMES = ("lease", "rpc", "warmup", "sweep", "hit_verify",
-              "complete", "fail", "reissue", "park", "phase",
-              "restore")
+              "complete", "fail", "reissue", "park", "restore")
 
 #: the one declaration site for station names (tools/check_metrics.py
 #: holds every station("...") literal to it).  ``targets`` is a job's,
 #: before its first unit: hash-file parse (cli._setup_job), a bulk
 #: list's table build and upload (MaskWorkerBase._setup_probe).  A
-#: unit passes the others in this order; ``wait`` and ``decode`` open inside ``resolve`` (and
-#: ``decode`` inside ``probe``, once a batch of a probed unit: a
-#: unit is probed only where DPRF_PERF_SAMPLE is set).  A
-#: station is a unit or a dispatch, never a lane or a batch of a fused
-#: program: a span each would cost what it measures.
-STATIONS = ("targets", "lease", "submit", "probe", "resolve", "wait",
+#: unit passes the others in this order; ``wait`` and ``decode`` open
+#: inside ``resolve``.  A station is a unit or a dispatch, never a lane
+#: or a batch of a fused program: a span each would cost what it
+#: measures.
+STATIONS = ("targets", "lease", "submit", "resolve", "wait",
             "decode", "verify", "complete")
 _STATION_LABELS = {name: "dprf:" + name for name in STATIONS}
 
@@ -352,21 +348,18 @@ class TraceRecorder:
 
     def record(self, name: str, dur: float = 0.0, ts: Optional[float] = None,
                trace: Optional[str] = None, parent: Optional[str] = None,
-               proc: Optional[str] = None, span: Optional[str] = None,
+               proc: Optional[str] = None,
                **attrs) -> Optional[dict]:
         """Record one span; ``ts`` defaults to now - dur (i.e. the
-        caller measured ``dur`` ending now).  ``span`` overrides the
-        generated span id -- how a sampled sweep's pre-allocated id
-        (telemetry/perf.py) lets its phase children parent onto a
-        span recorded later.  Returns the span dict (shippable over
-        RPC) or None when disabled."""
+        caller measured ``dur`` ending now).  Returns the span dict
+        (shippable over RPC) or None when disabled."""
         if not self.enabled:
             return None
         if ts is None:
             ts = self._clock() - dur
         span = {"name": name, "ts": round(float(ts), 6),
                 "dur": round(float(dur), 6), "trace": trace,
-                "parent": parent, "span": span or new_span_id(),
+                "parent": parent, "span": new_span_id(),
                 "proc": proc if proc is not None else self.proc,
                 "attrs": attrs}
         self._append(span)

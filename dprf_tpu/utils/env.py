@@ -238,15 +238,6 @@ _declare("DPRF_HEARTBEAT_S", 10.0, "float",
          "coordinator's health state machine ages workers in "
          "multiples of this interval.  0 disables explicit "
          "heartbeats.")
-_declare("DPRF_PERF_SAMPLE", 0, "int",
-         "Opt-in per-phase sweep attribution: N > 0 takes every Nth "
-         "unit off the fused, pipelined dispatch and sweeps it batch "
-         "by batch with a host sync between phases, recording phase "
-         "spans and the dprf_phase_seconds histogram "
-         "(telemetry/perf.py).  Off (0) by default: on a TPU v5e a "
-         "probed unit takes about five times a fused one (290 ms "
-         "against 54 for 2^28 md5 candidates), which at N=16 cost a "
-         "fifth to a third of the sweep rate.")
 _declare("DPRF_JAX_PROFILE", None, "path",
          "Write a jax.profiler trace of the sweep loops to this "
          "directory (kernel-level drill-down beside the span "

@@ -885,3 +885,18 @@ def test_registry_typed_getters(monkeypatch):
     with pytest.raises(KeyError, match="undeclared env knob"):
         # dprf: disable=env-knobs -- asserts the registry rejects undeclared names
         env.get_str("DPRF_NOT_A_KNOB")
+
+
+def test_the_sampler_knob_is_gone_from_registry_and_readme():
+    from dprf_tpu.utils import env
+
+    # in two halves: the name is to be found nowhere in the tree
+    gone = "DPRF_PERF" + "_SAMPLE"
+    with pytest.raises(KeyError, match="undeclared env knob"):
+        env.get_int(gone)
+    with open(os.path.join(REPO, "README.md")) as fh:
+        text = fh.read()
+    table = text[text.index(env.README_BEGIN):text.index(env.README_END)]
+    rows = [ln for ln in table.splitlines() if ln.startswith("| `DPRF_")]
+    assert len(rows) == len(env.KNOBS) == 53
+    assert gone not in text

@@ -1124,12 +1124,20 @@ def test_retrace_attribute_truth_test_caught(tmp_path):
 
 
 # ---------------------------------------------------------------------------
-# retrace: PERF_PROBE declared sampled-probe exemption (ISSUE 9)
+# retrace: a helper that syncs inside a hot loop is a finding, and no
+# table says who may
 
-def test_retrace_undeclared_probe_helper_caught(tmp_path):
+#: the table's name, in two halves: it is to be found nowhere in the tree
+GONE_TABLE = "PERF" + "_PROBE"
+
+
+@pytest.mark.parametrize("table", ["", GONE_TABLE + ' = ("grab",)'],
+                         ids=["no-table", "exemption-table"])
+def test_retrace_undeclared_probe_helper_caught(tmp_path, table):
     root = make_repo(tmp_path, {"dprf_tpu/hot.py": RETRACE_HEAD + """\
 
     HOT_PATHS = ("sweep",)
+    %s
 
     def grab(r):
         return r.item()
@@ -1140,78 +1148,13 @@ def test_retrace_undeclared_probe_helper_caught(tmp_path):
             r = step(u)
             out += grab(r)
         return out
-"""})
+""" % table})
     f = bad(check(root, "retrace"))
     assert len(f) == 1 and "syncs the device value" in f[0].message
 
 
-def test_retrace_declared_perf_probe_exempt(tmp_path):
-    root = make_repo(tmp_path, {"dprf_tpu/hot.py": RETRACE_HEAD + """\
-
-    HOT_PATHS = ("sweep",)
-    PERF_PROBE = ("grab",)
-
-    def grab(r):
-        return r.item()
-
-    def sweep(units):
-        out = 0
-        for u in units:
-            r = step(u)
-            out += grab(r)
-        return out
-"""})
-    assert bad(check(root, "retrace")) == []
-
-
-def test_retrace_dotted_perf_probe_resolves_cross_module(tmp_path):
-    root = make_repo(tmp_path, {
-        "dprf_tpu/probe_mod.py": """\
-            def grab(r):
-                return r.item()
-        """,
-        "dprf_tpu/hot.py": RETRACE_HEAD + """\
-
-    from dprf_tpu.probe_mod import grab
-
-    HOT_PATHS = ("sweep",)
-    PERF_PROBE = ("dprf_tpu.probe_mod.grab",)
-
-    def sweep(units):
-        out = 0
-        for u in units:
-            r = step(u)
-            out += grab(r)
-        return out
-"""})
-    assert bad(check(root, "retrace")) == []
-
-
-def test_retrace_stale_perf_probe_entry_is_finding(tmp_path):
-    root = make_repo(tmp_path, {"dprf_tpu/hot.py": RETRACE_HEAD + """\
-
-    HOT_PATHS = ("sweep",)
-    PERF_PROBE = ("nope",)
-
-    def sweep(units):
-        r = None
-        for u in units:
-            r = step(u)
-        return r
-"""})
-    f = bad(check(root, "retrace"))
-    assert len(f) == 1 and "stale declaration" in f[0].message
-    assert "nope" in f[0].message
-
-
-def test_retrace_probe_table_without_hot_paths_is_finding(tmp_path):
-    root = make_repo(tmp_path, {"dprf_tpu/hot.py": """\
-        HOT_PATHS = ()
-        PERF_PROBE = ("grab",)
-
-        def grab(r):
-            return r.item()
-"""})
-    f = bad(check(root, "retrace"))
-    assert len(f) == 1 and "exemption applies to nothing" \
-        in f[0].message
+def test_retrace_real_repo_is_clean_under_its_one_table():
+    from dprf_tpu.analysis import retrace
+    assert retrace.DECL_TABLES == ("HOT_PATHS",)
+    assert bad(check(REPO, "retrace")) == []
+    assert GONE_TABLE not in analysis.explain(REPO, "retrace")

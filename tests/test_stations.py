@@ -11,6 +11,7 @@ import subprocess
 import sys
 import threading
 import timeit
+from typing import NamedTuple
 
 import pytest
 
@@ -72,11 +73,11 @@ def test_a_child_on_another_recorder_is_still_taken_out(clock):
     """The loop's recorder and the process's default one need not be
     the same object: stations nest on the thread, not the recorder."""
     outer, inner = recorder(enabled=True), recorder(enabled=True)
-    with outer.station("probe", unit=1):
+    with outer.station("submit", unit=1):
         clock.now += 1.0
         with inner.station("decode", unit=1):
             clock.now += 3.0
-    assert outer.station_table() == {"probe": (1, 1.0)}
+    assert outer.station_table() == {"submit": (1, 1.0)}
     assert inner.station_table() == {"decode": (1, 3.0)}
 
 
@@ -91,9 +92,13 @@ def test_a_station_left_by_an_exception_is_counted_and_closed(clock):
     assert rec.station_table() == {"submit": (2, 3.0)}
 
 
-def test_an_undeclared_station_name_is_refused():
+@pytest.mark.parametrize("name", ["per_lane", "probe"])
+def test_an_undeclared_station_name_is_refused(name):
+    """`probe`: a unit is swept one way, so no station stands for a
+    second."""
+    assert len(STATIONS) == 8
     with pytest.raises(KeyError):
-        recorder(enabled=True).station("per_lane")
+        recorder(enabled=True).station(name)
 
 
 def test_format_stations_reports_the_job_not_the_process():
@@ -177,69 +182,145 @@ def test_it_does_not_import_jax():
     assert proc.returncode == 0, proc.stdout + proc.stderr
 
 
-# -- the job -----------------------------------------------------------------
+def test_telemetry_imports_neither_runtime_nor_parallel():
+    """The arrows point one way: the loops import telemetry, which
+    therefore holds no sweep of a unit."""
+    code = ("import importlib, pkgutil, sys\n"
+            "import dprf_tpu.telemetry as t\n"
+            "for m in pkgutil.iter_modules(t.__path__):\n"
+            "    importlib.import_module('dprf_tpu.telemetry.' + m.name)\n"
+            "bad = sorted(m for m in sys.modules if m.startswith(\n"
+            "    ('dprf_tpu.runtime', 'dprf_tpu.parallel')))\n"
+            "sys.exit('imported: %s' % bad if bad else 0)\n")
+    proc = subprocess.run([sys.executable, "-c", code], cwd=REPO,
+                          capture_output=True, text=True, timeout=120)
+    assert proc.returncode == 0, proc.stdout + proc.stderr
 
-def _cpu_job(rec, mask="?l?l?l?l", unit_size=1 << 16):
-    reg = MetricsRegistry()
-    eng = get_engine("md5")
-    gen = MaskGenerator(mask)
-    targets = [eng.parse_target("ff" * 16)]      # unmatchable: a sweep
-    disp = Dispatcher(gen.keyspace, unit_size, registry=reg, recorder=rec)
-    spec = JobSpec(engine="md5", device="cpu", attack="mask",
-                   attack_arg=mask, keyspace=gen.keyspace,
-                   fingerprint="stations")
-    return Coordinator(spec, targets, disp, CpuWorker(eng, gen, targets),
-                       registry=reg, recorder=rec)
+
+# -- the job, by worker family -----------------------------------------------
+
+class Family(NamedTuple):
+    """One of the ways a unit's sweep is implemented (whose `process`
+    a worker runs), as `dprf crack` selects it."""
+    device: str         # --device
+    attack: str         # -a
+    devices: int        # --devices
+    pallas: str         # DPRF_PALLAS
+    worker: str         # the class the `ran` line names
+    fused: str          # the fused dispatch shape a large unit takes
+    shapes: frozenset   # every shape its submit may queue
 
 
-#: the phase sampler's cadence (DPRF_PERF_SAMPLE) and whether a job so
-#: run passes the station `probe`: unset, no unit leaves `submit`
-SAMPLED = pytest.mark.parametrize(
-    "sample, probed", [(None, False), ("16", True)],
-    ids=["default", "DPRF_PERF_SAMPLE=16"])
+FAMILIES = {
+    # the compiled hash kernel, interpreted on the CPU
+    "kernel": Family("jax", "mask", 1, "1", "PallasMaskWorker", "loop",
+                     frozenset({"batch", "scan", "loop", "wide"})),
+    # one program across the CPU's forced devices (tests/conftest.py)
+    "sharded": Family("jax", "mask", 2, "auto", "ShardedMaskWorker",
+                      "sshard", frozenset({"batch", "sshard"})),
+    # the word-window loop, candidates made on the device
+    "wordlist": Family("jax", "wordlist", 1, "auto",
+                       "DeviceWordlistWorker", "wsuper",
+                       frozenset({"wbatch", "wsuper", "wwide"})),
+    # a `process` of the worker's own, run inside `submit`: no
+    # PendingUnit, so no `wait` or `decode`, and no dispatch to count
+    "serial": Family("cpu", "mask", 1, "auto", "CpuWorker", "",
+                     frozenset()),
+}
+
+BY_FAMILY = pytest.mark.parametrize("family", list(FAMILIES))
+
+MASK, PLANT = "?l?l?l?d", b"zzy9"        # 175,760 candidates
+WORDS = [b"w%04d" % i for i in range(3000)] + [PLANT]
+#: units of 16 strides (8 of the kernel's 4,096-lane tile): large
+#: enough for the fused shape, a remainder left for the per-batch one
+UNIT, WORD_UNIT, BATCH, WORD_BATCH = 32768, 1024, 1024, 128
 
 
 @pytest.fixture
-def cadence(monkeypatch, sample):
-    if sample is None:
-        monkeypatch.delenv("DPRF_PERF_SAMPLE", raising=False)
-    else:
-        monkeypatch.setenv("DPRF_PERF_SAMPLE", sample)
+def fam(monkeypatch, family):
+    monkeypatch.setenv("DPRF_PALLAS", FAMILIES[family].pallas)
+    return FAMILIES[family]
 
 
-@SAMPLED
-def test_self_seconds_sum_to_the_loops_elapsed(cadence, probed):
+def _passed(rec, before):
+    """{station: (times passed, self seconds)} since `before`."""
+    return {name: (n - before.get(name, (0, 0.0))[0],
+                   s - before.get(name, (0, 0.0))[1])
+            for name, (n, s) in rec.station_table().items()}
+
+
+@BY_FAMILY
+def test_self_seconds_sum_to_the_loops_elapsed(fam):
     """What the stations leave out of `Coordinator.run` is the loop's
     own bookkeeping: on a job whose units are worth the while, under a
     twentieth.  (The loop never sleeps here: a unit is always
-    leasable.)"""
-    rec = get_tracer()
+    leasable.)  The worker is the one `dprf crack` would build, and
+    the job finds what the oracle finds."""
+    from dprf_tpu.cli import _select_worker
+    from dprf_tpu.generators.wordlist import WordlistRulesGenerator
+    from dprf_tpu.runtime.workunit import WorkUnit
+    from dprf_tpu.utils.logging import Log
+    oracle = get_engine("md5")
+    # the plant and a digest nothing hashes to: the job sweeps it all
+    targets = [oracle.parse_target(hashlib.md5(PLANT).hexdigest()),
+               oracle.parse_target("ff" * 16)]
+    if fam.attack == "wordlist":
+        gen = WordlistRulesGenerator(WORDS, None, max_len=16)
+        unit_size, batch = WORD_UNIT, WORD_BATCH
+    else:
+        gen = MaskGenerator(MASK)
+        unit_size, batch = UNIT, BATCH
+    worker = _select_worker("md5", fam.device, fam.attack, gen, targets,
+                            batch, 16, oracle, fam.devices,
+                            Log(quiet=True))
+    assert type(worker).__name__ == fam.worker
+    if hasattr(worker, "warmup"):
+        worker.warmup()      # the loop joins a compile outside a station
+    rec, reg = get_tracer(), MetricsRegistry()
+    disp = Dispatcher(gen.keyspace, unit_size, registry=reg, recorder=rec)
+    spec = JobSpec(engine="md5", device=fam.device, attack=fam.attack,
+                   attack_arg=MASK, keyspace=gen.keyspace,
+                   fingerprint="stations")
     before = rec.station_table()
-    result = _cpu_job(rec).run()
+    result = Coordinator(spec, targets, disp, worker, registry=reg,
+                         recorder=rec, oracle=oracle).run()
     assert result.exhausted
-    table = rec.station_table()
-    named = sum(s - before.get(name, (0, 0.0))[1]
-                for name, (_, s) in table.items())
+    want = CpuWorker(oracle, gen, targets).process(
+        WorkUnit(-1, 0, gen.keyspace))
+    assert result.found == {h.target_index: h.plaintext for h in want} \
+        == {0: PLANT}
+    table = _passed(rec, before)
+    named = sum(s for _, s in table.values())
     assert 0.95 * result.elapsed <= named <= result.elapsed
-    units = -(-456976 // (1 << 16))
+    units = -(-gen.keyspace // unit_size)
+    # once a unit; `decode` and `verify` where a unit reported: one
+    # did.  (`lease` also when it finds nothing left to hand out.)
+    want_passed = {"submit": units, "resolve": units, "verify": 1,
+                   "complete": units}
+    if fam.shapes:
+        want_passed.update(wait=units, decode=1)
+    assert {name: n for name, (n, _) in table.items()
+            if n and name != "lease"} == want_passed
+    assert table["lease"][0] >= units
+    shapes = getattr(worker, "dispatches", {})
+    assert set(shapes) <= fam.shapes and (not fam.fused
+                                          or shapes[fam.fused] > 0)
 
-    def passed(name):
-        return (table.get(name, (0, 0.0))[0]
-                - before.get(name, (0, 0.0))[0])
 
-    for name in ("resolve", "complete"):
-        assert passed(name) == units
-    # sampled, unit 0 is the sampler's: probed, not submitted
-    assert passed("probe") == int(probed)
-    assert passed("submit") == units - int(probed)
-
-
-def _crack(tmp_path, capsys, *extra):
+def _crack(tmp_path, capsys, fam):
     hashes = tmp_path / "h.txt"
-    hashes.write_text(hashlib.md5(b"zzy").hexdigest() + "\n")
-    rc = cli_main(["crack", "--engine", "md5", "-a", "mask", "?l?l?l",
-                   str(hashes), "--unit-size", "4096", "--no-potfile",
-                   *extra])
+    hashes.write_text(hashlib.md5(PLANT).hexdigest() + "\n")
+    if fam.attack == "wordlist":
+        words = tmp_path / "words.txt"
+        words.write_bytes(b"\n".join(WORDS) + b"\n")
+        attack = [str(words), "--unit-size", str(WORD_UNIT),
+                  "--batch", str(WORD_BATCH)]
+    else:
+        attack = [MASK, "--unit-size", str(UNIT), "--batch", str(BATCH)]
+    rc = cli_main(["crack", "--engine", "md5", "-a", fam.attack, *attack,
+                   str(hashes), "--no-potfile", "--device", fam.device,
+                   "--devices", str(fam.devices)])
     cap = capsys.readouterr()
     ran = [ln for ln in cap.err.splitlines() if " ran " in ln]
     assert rc == 0 and len(ran) == 1, cap.err
@@ -247,55 +328,41 @@ def _crack(tmp_path, capsys, *extra):
                          if "=" in f)
 
 
-@SAMPLED
+@BY_FAMILY
 def test_the_ran_line_carries_host_by_station(tmp_path, capsys,
-                                              monkeypatch, cadence,
-                                              probed):
-    out, ran = _crack(tmp_path, capsys, "--device", "cpu")
-    assert "zzy" in out
+                                              monkeypatch, fam):
+    out, ran = _crack(tmp_path, capsys, fam)
+    assert PLANT.decode() in out
+    assert ran["worker"] == fam.worker
     host = dict(f.split(":") for f in ran["host"].split(","))
     # `targets` is the job's own, open around the hash file's parse
     assert set(host) == {"targets", "lease", "submit", "resolve",
-                         "verify", "complete"} | ({"probe"} if probed
-                                                     else set())
+                         "verify", "complete"} | (
+                             {"wait", "decode"} if fam.shapes else set())
     assert list(host) == [s for s in STATIONS if s in host]
     assert all(re.fullmatch(r"\d+\.\d{3}", v) for v in host.values())
     assert float(host["submit"]) > 0
+    # the shapes the family's own submit queues, the fused one among
+    # them, and no other
+    if fam.shapes:
+        shapes = {k: int(n) for k, n in
+                  (f.split(":") for f in ran["dispatch"].split(","))}
+        assert set(shapes) <= fam.shapes and shapes[fam.fused] > 0
+    else:
+        assert ran["dispatch"] == "none"
     # DPRF_TRACE=0 is read when the recorder is made: the same switch
     monkeypatch.setattr(get_tracer(), "enabled", False)
-    out_off, ran_off = _crack(tmp_path, capsys, "--device", "cpu")
+    out_off, ran_off = _crack(tmp_path, capsys, fam)
     assert "host" not in ran_off
     assert out_off == out
 
 
-def test_the_ran_line_counts_probe_dispatches_only_when_asked(
-        tmp_path, capsys, monkeypatch):
-    """A device job (XLA on the CPU): unset, no dispatch is the
-    sampler's; DPRF_PERF_SAMPLE=2 sweeps every other unit per batch,
-    and the job prints what it printed without."""
-    def shapes(ran):
-        return {k: int(n) for k, n in
-                (f.split(":") for f in ran["dispatch"].split(","))}
-
-    monkeypatch.delenv("DPRF_PERF_SAMPLE", raising=False)
-    out, ran = _crack(tmp_path, capsys, "--batch", "1024")
-    assert "zzy" in out and "probe" not in shapes(ran)
-    assert "probe:" not in ran["host"]
-    monkeypatch.setenv("DPRF_PERF_SAMPLE", "2")
-    out_sampled, ran_sampled = _crack(tmp_path, capsys,
-                                      "--batch", "1024")
-    assert shapes(ran_sampled)["probe"] > 0
-    assert "probe:" in ran_sampled["host"]
-    assert out_sampled == out
-
-
-@SAMPLED
-def test_the_unit_id_rides_every_span_but_lease(tmp_path, capsys,
-                                                cadence, probed):
-    """A profiler trace of a small device job (XLA on the CPU): the
-    stations are events of the host's plane, `wait` lies inside a
-    `resolve`, and all but `lease` (and `targets`, which is the job's,
-    before any unit) carry their unit's id."""
+@BY_FAMILY
+def test_the_unit_id_rides_every_span_but_lease(tmp_path, capsys, fam):
+    """A profiler trace of a small job (on the CPU): the stations are
+    events of the host's plane, `wait` lies inside a `resolve`, and
+    all but `lease` (and `targets`, which is the job's, before any
+    unit) carry their unit's id."""
     import jax
     from jax.profiler import ProfileData
     opts = jax.profiler.ProfileOptions()
@@ -303,7 +370,7 @@ def test_the_unit_id_rides_every_span_but_lease(tmp_path, capsys,
     jax.profiler.start_trace(str(tmp_path / "trace"),
                              profiler_options=opts)
     try:
-        _crack(tmp_path, capsys, "--batch", "1024")
+        _crack(tmp_path, capsys, fam)
     finally:
         jax.profiler.stop_trace()
     path, = glob.glob(str(tmp_path / "trace" / "plugins" / "profile" /
@@ -317,26 +384,29 @@ def test_the_unit_id_rides_every_span_but_lease(tmp_path, capsys,
                            for e in line.events
                            if e.name.startswith("dprf:")]
     names = {e[0] for e in events}
-    assert {"dprf:" + s for s in ("lease", "submit", "resolve",
-                                  "wait", "complete")} <= names
-    assert ("dprf:probe" in names) == probed
-    assert names <= {"dprf:" + s for s in STATIONS}
+    assert names == {"dprf:" + s for s in STATIONS
+                     if fam.shapes or s not in ("wait", "decode")}
     for name, _, _, unit in events:
         assert (unit is None) == (name in ("dprf:lease",
                                            "dprf:targets")), name
     resolves = [e for e in events if e[0] == "dprf:resolve"]
     for name, s, e, unit in events:
-        if name == "dprf:wait":
+        if name in ("dprf:wait", "dprf:decode"):
             assert any(r[1] <= s and e <= r[2] and r[3] == unit
                        for r in resolves)
-    # a unit or a dispatch each, never a batch: 17,576 candidates in
-    # units of 4,096 are five units at most (the plant lies in the
-    # last); sampled, unit 0 passes `probe` and not `submit`
-    submitted = [e[3] for e in events if e[0] == "dprf:submit"]
-    units = sorted({e[3] for e in events if e[0] == "dprf:resolve"})
-    assert submitted == units[int(probed):] and len(units) <= 5
-    assert [e[3] for e in events if e[0] == "dprf:probe"] \
-        == units[:int(probed)]
+    # a unit or a dispatch each, never a batch: every unit passes
+    # `submit`, `resolve`, `complete` (and `wait`, where it is pending)
+    # once, under its own id; the plant lies in the last, which alone
+    # is decoded and verified
+    units = sorted(e[3] for e in resolves)
+    assert units == list(range(len(units))) and 2 <= len(units) <= 6
+    for once in ("submit", "complete") + (("wait",) if fam.shapes
+                                          else ()):
+        assert sorted(e[3] for e in events
+                      if e[0] == "dprf:" + once) == units, once
+    for last in ("verify",) + (("decode",) if fam.shapes else ()):
+        assert [e[3] for e in events
+                if e[0] == "dprf:" + last] == units[-1:], last
 
 
 # -- the lint ----------------------------------------------------------------

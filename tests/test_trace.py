@@ -272,12 +272,6 @@ def test_distributed_reissue_stitches_both_workers_onto_one_trace(tmp_path):
     assert len(leases) == 2
     first_lease, second_lease = leases
     for s in spans:
-        if s["name"] == "phase":
-            # sampled-probe phase children parent onto their unit's
-            # SWEEP span (same proc), not the lease span directly
-            assert by_id[s["parent"]]["name"] == "sweep"
-            assert by_id[s["parent"]]["proc"] == s["proc"]
-            continue
         if s["proc"] == "wA":
             assert s["parent"] == first_lease["span"]
         if s["proc"] == "wB":
@@ -591,13 +585,20 @@ def test_check_metrics_flags_duplicate_declaration(tmp_path):
     assert "dprf_dup_total" in proc.stdout
 
 
-def test_check_metrics_flags_undeclared_span_name(tmp_path):
+@pytest.mark.parametrize("name", ["made_up_span", "phase"])
+def test_check_metrics_flags_undeclared_span_name(tmp_path, name):
+    """Held to the package's own declaration, which has no `phase`:
+    a `sweep` span has no children, a unit is swept in one piece."""
+    from dprf_tpu.telemetry.trace import SPAN_NAMES
+    assert name not in SPAN_NAMES
     pkg = tmp_path / "pkg"
     (pkg / "telemetry").mkdir(parents=True)
     (pkg / "telemetry" / "trace.py").write_text(
-        'SPAN_NAMES = ("lease",)\n')
+        "SPAN_NAMES = %r\n" % (SPAN_NAMES,))
     (pkg / "a.py").write_text(
-        'def f(tracer):\n    tracer.record("made_up_span")\n')
+        'def f(tracer):\n    tracer.record("sweep")\n'
+        '    tracer.record("%s")\n' % name)
     proc = _run_lint(str(pkg))
     assert proc.returncode == 1
-    assert "made_up_span" in proc.stdout
+    assert "'%s'" % name in proc.stdout
+    assert "'sweep'" not in proc.stdout
