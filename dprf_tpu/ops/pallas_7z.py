@@ -123,9 +123,8 @@ def make_7z_kdf_pallas_fn(gen, batch: int, salt: bytes, cycles: int,
         pid = pl.program_id(0)
         lane = (lax.broadcasted_iota(jnp.int32, shape, 0) * 128
                 + lax.broadcasted_iota(jnp.int32, shape, 1))
-        carry = lane + pid * tile
         byts = decode_candidate_bytes(radices, seg_tables, length,
-                                      base_ref, carry)
+                                      base_ref, pid * tile, lane, tile)
         state = _kdf_lanes(byts, length, salt, cycles, shape)
         out_ref[...] = jnp.concatenate(list(state), axis=0)
 

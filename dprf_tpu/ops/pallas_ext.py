@@ -217,9 +217,8 @@ def _build_ext_body(name: str, radices, seg_tables, length: int,
         shape = (sub, 128)
         lane = (jax.lax.broadcasted_iota(jnp.int32, shape, 0) * 128
                 + jax.lax.broadcasted_iota(jnp.int32, shape, 1))
-        carry = lane + pid * tile
         cand = decode_candidate_bytes(radices, seg_tables, length,
-                                      base, carry, luts)
+                                      base, pid * tile, lane, tile, luts)
         if salted:
             salt_ref, tgt_ref = rest
             salt_b = [salt_ref[j].astype(jnp.uint32)

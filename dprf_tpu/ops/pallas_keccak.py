@@ -73,9 +73,8 @@ def _build_keccak_body(radices, seg_tables, length: int, tw,
         shape = (sub, 128)
         lane = (jax.lax.broadcasted_iota(jnp.int32, shape, 0) * 128
                 + jax.lax.broadcasted_iota(jnp.int32, shape, 1))
-        carry = lane + pid * tile
         byts = decode_candidate_bytes(radices, seg_tables, length,
-                                      base, carry)
+                                      base, pid * tile, lane, tile)
 
         def const_byte(q: int) -> int:
             # the padding is STATIC: mask candidates all have length

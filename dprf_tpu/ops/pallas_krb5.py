@@ -184,9 +184,10 @@ def _build_body(radices, seg_tables, length: int, sub: int,
 
         def chunk(c, acc):
             count, hit = acc
-            gidx = pid * tile + c * sub + row
+            start = pid * tile + c * sub
+            gidx = start + row
             byts = decode_candidate_bytes(radices, seg_tables, length,
-                                          base, gidx)
+                                          base, start, row, sub)
             m = _pack_message(byts, length, shape, False, True)
             init = tuple(jnp.full(shape, jnp.uint32(int(w)))
                          for w in md4_ops.INIT)

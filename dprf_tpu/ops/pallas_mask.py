@@ -5,8 +5,8 @@ Why a kernel at all: the XLA path (ops/pipeline.py) materializes the
 candidate block uint8[B, L] and the digest uint32[B, W] in HBM between
 fusions.  At the throughputs these engines target, those intermediate
 writes are the bandwidth floor.  This kernel keeps the whole chain --
-mixed-radix decode, charset lookup, message packing (with UTF-16LE
-widening for NTLM), the full compression rounds, compare, hit
+index -> candidate decode, charset lookup, message packing (with
+UTF-16LE widening for NTLM), the full compression rounds, compare, hit
 reduction -- in VMEM/registers, and writes one packed int32 per grid
 cell -- (count << 16) | (hit_lane + 1), splatted over the minimum
 (8, 128) Mosaic output block -- back to HBM: ~4096/TILE bytes per
@@ -23,6 +23,14 @@ pipeline and the kernel bodies are validated eagerly via
 emulate_mask_kernel.
 
 Design choices forced by the VPU:
+- The decode is an odometer, not a division (decode_candidate_bytes):
+  the vector unit has no integer divide, and a mixed-radix decode of
+  each lane's whole index was 27 emulated divisions a candidate at
+  ?l x9.  A tile's first index is a scalar, added to the base digits
+  once a tile on the scalar unit; a lane's index inside its tile has
+  digits only in the last few positions, where tile digit + lane digit
+  + carry is one compare and one conditional subtract; every position
+  above them takes one of two scalar bytes, chosen by one carry bit.
 - Charset lookup is arithmetic where possible: a charset in digit
   order is piecewise byte = digit + delta, so the lookup is a few
   vectorized `where` adds (7 segments for ?a, 1 for ?l/?u/?d).
@@ -91,10 +99,10 @@ def check_batch(batch: int, sub: int) -> int:
     """Shared guard for every packed-output mask kernel factory
     (this module's, pallas_ext's, pallas_keccak's): sub bound for the
     16-bit packed count/lane fields, tile alignment, and the int32
-    lane-arithmetic headroom (the first mixed-radix addition computes
-    base_digit + lane with base_digit <= 255, so the lane index needs
-    256 of headroom below 2^31 or the last lanes wrap and decode
-    wrong candidates).  Returns the grid size."""
+    index-arithmetic headroom (the decode's first mixed-radix addition
+    computes base_digit + tile start with base_digit <= 255, so the
+    index needs 256 of headroom below 2^31 or the last tiles wrap and
+    decode wrong candidates).  Returns the grid size."""
     if sub > 128:
         raise ValueError("sub > 128 overflows the packed 16-bit "
                          "count/lane output fields")
@@ -430,29 +438,117 @@ def probe_block_found(digest, rows, valid, block_bits: int, k: int,
 _decode_byte = segment_mux
 
 
-def decode_candidate_bytes(radices, seg_tables, length: int, base, carry,
-                           luts=None, take=take_lanes):
-    """Mixed-radix add (base digits + per-lane carry) fused with the
-    per-position charset lookup, least significant position first --
-    the shared decode of every mask kernel body.  seg_tables entries
-    are segment lists (arithmetic mux, any length) or ("lut", k)
-    markers resolving into `luts` rows [2k, 2k+2) (position_tables;
-    carry must then be a (sub, 128) tile -- every kernel body's is)."""
+def lane_digit_count(radices, lane_bound: int) -> int:
+    """K: the low mask positions a lane index below lane_bound has
+    digits in -- the least K with prod(radices[-K:]) >= lane_bound
+    (all of them where the whole keyspace is smaller)."""
+    k, span = 0, 1
+    while k < len(radices) and span < lane_bound:
+        k += 1
+        span *= radices[-k]
+    return k
+
+
+def _quotient(n, r: int):
+    """n // r for int32 n in [0, 2^14) and a radix r in [2, 256], with
+    no division: (n * m) >> sh, m = ceil(2^sh / r).  Exact because
+    n * (m * r - 2^sh) < 2^14 * r <= 2^sh (Granlund and Montgomery's
+    condition), and n * m < 2^31."""
+    sh = 14 + (r - 1).bit_length()
+    return (n * (-(-(1 << sh) // r))) >> sh
+
+
+def decode_candidate_bytes(radices, seg_tables, length: int, base, start,
+                           lane, lane_bound: int, luts=None,
+                           take=take_lanes):
+    """Candidate bytes of keyspace index digits(base) + start + lane,
+    per mask position -- the shared decode of every mask kernel body,
+    an odometer with no division on the vector unit.
+
+    start is the SCALAR part of the index (a tile's first candidate:
+    pid * tile, plus the window offset), lane the int32 tile of
+    per-lane parts in [0, lane_bound), lane_bound static.  Three steps:
+
+    - scalar, once a tile: base digits + start by the mixed-radix add
+      (one scalar div/rem a position) give the digits of the tile's
+      first candidate, and a second scalar odometer their successor in
+      the upper positions;
+    - a lane index below lane_bound has digits only in the low
+      K = lane_digit_count positions.  They are the same for every
+      tile: quotients by an exact reciprocal multiply and shift
+      (_quotient), no division;
+    - in the low positions tile digit + lane digit + carry < 2 r: one
+      compare, one conditional subtract.  The carry out of them, c0,
+      is all an upper position sees of the lane: its byte is a select
+      between the bytes of the tile's digit and of the successor's,
+      both scalars.
+
+    seg_tables entries are segment lists (arithmetic mux, any length)
+    or ("lut", k) markers resolving into `luts` rows [2k, 2k+2)
+    (position_tables; lane must then be a (sub, 128) tile -- every
+    kernel body's is).  An index past the keyspace wraps (the carry
+    out of position 0 is dropped); callers mask such lanes."""
+    if not 2 <= lane_bound <= 1 << 14:
+        raise ValueError("lane_bound outside [2, 2^14]: the range "
+                         "_quotient is exact in")
     lut_arr = luts[...] if luts is not None else None
-    byts: list = [None] * length
+    k = lane_digit_count(radices, lane_bound)
+    low = range(length - 1, length - 1 - k, -1)
+    upper = range(length - 1 - k, -1, -1)
+
+    def is_lut(p):
+        return isinstance(seg_tables[p], tuple) and seg_tables[p][0] == "lut"
+
+    def byte_of(p, digit):
+        if is_lut(p):
+            row = 2 * seg_tables[p][1]
+            return _lut_byte(digit, lut_arr[row], lut_arr[row + 1], take)
+        return _decode_byte(digit, seg_tables[p])
+
+    # scalar side: the digits of the tile's first candidate
+    tdig: list = [None] * length
+    carry = start
     for p in range(length - 1, -1, -1):
-        r = radices[p]
         s = base[p] + carry
-        d = s % r
-        t = seg_tables[p]
-        if isinstance(t, tuple) and t[0] == "lut":
-            byts[p] = _lut_byte(d, lut_arr[2 * t[1]],
-                                lut_arr[2 * t[1] + 1],
-                                take).astype(jnp.uint32)
+        tdig[p] = jax.lax.rem(s, jnp.int32(radices[p]))
+        carry = jax.lax.div(s, jnp.int32(radices[p]))
+
+    # lane side: the low digits of the lane index
+    ldig = {}
+    q, span = lane, 1
+    for p in low:
+        r = radices[p]
+        span *= r
+        if r == 1:
+            ldig[p] = 0
+        elif p == length - k and span >= lane_bound:
+            ldig[p] = q                 # what is left is below r
         else:
-            byts[p] = _decode_byte(d, t).astype(jnp.uint32)
-        carry = s // r
-    return byts
+            nq = _quotient(q, r)
+            ldig[p] = q - nq * r
+            q = nq
+
+    byts: list = [None] * length
+    c0 = None                  # carry out of the positions below
+    for p in low:
+        s = tdig[p] + ldig[p]
+        if c0 is not None:
+            s = s + c0.astype(jnp.int32)
+        c0 = s >= radices[p]
+        byts[p] = byte_of(p, jnp.where(c0, s - radices[p], s))
+    # the upper positions' digits where c0 is set: the tile's, plus one
+    carry = 1
+    for p in upper:
+        s = tdig[p] + carry
+        wrap = s == radices[p]
+        succ = jnp.where(wrap, 0, s)
+        carry = wrap.astype(jnp.int32)
+        if is_lut(p):           # a lane lookup: on the digit's tile
+            byts[p] = byte_of(p, jnp.where(c0, succ, tdig[p]))
+        else:
+            byts[p] = jnp.where(c0, byte_of(p, succ), byte_of(p, tdig[p]))
+    return [jnp.broadcast_to(b, lane.shape).astype(jnp.uint32)
+            for b in byts]
 
 
 def _pack_message(byts, length: int, shape, big_endian: bool,
@@ -530,14 +626,16 @@ def _build_kernel_body(engine_name: str, radices, seg_tables, length: int,
         shape = (sub, 128)
         lane = (jax.lax.broadcasted_iota(jnp.int32, shape, 0) * 128
                 + jax.lax.broadcasted_iota(jnp.int32, shape, 1))
-        # The base index of this *tile* is folded into the scalar side
-        # (pid * tile, plus the window offset) before vector carry
-        # propagation.
-        gidx = lane + pid * tile
+        # the tile's first index (pid * tile, plus the window offset)
+        # stays a scalar: the decode adds it to the base digits once a
+        # tile, on the scalar unit
+        start = pid * tile
         if offset is not None:
-            gidx = gidx + offset
+            start = start + offset
+        gidx = lane + start
         byts = decode_candidate_bytes(radices, seg_tables, length,
-                                      base, gidx, luts, take)
+                                      base, start, lane, tile, luts,
+                                      take)
         m = _pack_message(byts, length, shape, big_endian, widen,
                           32 if engine_name in WIDE_BLOCK else 16)
         return core(m, shape), lane, gidx

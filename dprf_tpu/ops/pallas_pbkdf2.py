@@ -157,9 +157,8 @@ def make_pbkdf2_kdf_pallas_fn(gen, batch: int, salt_len: int,
         pid = pl.program_id(0)
         lane = (lax.broadcasted_iota(jnp.int32, shape, 0) * 128
                 + lax.broadcasted_iota(jnp.int32, shape, 1))
-        carry = lane + pid * tile
         byts = decode_candidate_bytes(radices, seg_tables, length,
-                                      base_ref, carry)
+                                      base_ref, pid * tile, lane, tile)
         t = pbkdf2_lanes(byts, [salt_ref[p] for p in range(salt_len)],
                          salt_len, iters_ref[0], n_words, shape)
         out_ref[...] = jnp.concatenate(list(t), axis=0)
@@ -241,9 +240,8 @@ def make_pmkid_pallas_fn(gen, batch: int, essid_len: int,
         pid = pl.program_id(0)
         lane = (lax.broadcasted_iota(jnp.int32, shape, 0) * 128
                 + lax.broadcasted_iota(jnp.int32, shape, 1))
-        carry = lane + pid * tile
         byts = decode_candidate_bytes(radices, seg_tables, length,
-                                      base_ref, carry)
+                                      base_ref, pid * tile, lane, tile)
         pmkid = pmkid_lanes(byts, [essid_ref[p] for p in range(essid_len)],
                             essid_len, [msg_ref[i] for i in range(5)],
                             iters_ref[0], shape)

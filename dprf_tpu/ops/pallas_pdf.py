@@ -196,9 +196,10 @@ def _build_body(radices, seg_tables, length: int, rev: int,
 
         def chunk(c, acc):
             count, hit = acc
-            gidx = pid * tile + c * sub + row
+            start = pid * tile + c * sub
+            gidx = start + row
             byts = decode_candidate_bytes(radices, seg_tables, length,
-                                          base, gidx)
+                                          base, start, row, sub)
             b1 = _block1_words(byts, length, o_ref, shape)
             state = _compress(_md5_init(shape), b1)
             b2 = [jnp.full(shape, b2_ref[w].astype(jnp.uint32))

@@ -467,9 +467,9 @@ def test_unbounded_segment_decode_matches_oracle():
     tabs = segment_tables(gen.charsets)
     assert any(len(t) > 16 for t in tabs)     # really past the budget
     base = jnp.asarray(gen.digits(100), jnp.int32)
-    carry = jnp.arange(16, dtype=jnp.int32).reshape(2, 8)
+    lane = jnp.arange(16, dtype=jnp.int32).reshape(2, 8)
     byts = decode_candidate_bytes(gen.radices, tabs, gen.length,
-                                  base, carry)
+                                  base, jnp.int32(0), lane, 16)
     got = np.stack([np.asarray(b) for b in byts], axis=-1).reshape(16, 3)
     want = np.stack([np.frombuffer(gen.candidate(100 + i), np.uint8)
                      for i in range(16)])
@@ -477,3 +477,170 @@ def test_unbounded_segment_decode_matches_oracle():
     assert krb5_kernel_eligible(gen)
     assert pdf_kernel_eligible(gen, 3, 16)
     assert sevenzip_kernel_eligible(gen, 19, 2)
+
+
+# -- the odometer decode (PR 33) ---------------------------------------------
+
+def _top(mask: str, back: int = 0) -> int:
+    """The keyspace's last index (every digit r - 1), less `back`."""
+    return MaskGenerator(mask).keyspace - 1 - back
+
+
+#: id -> (mask, Markov seed or None, unbounded segment mux?, sub, index
+#: of the base digits, scalar start).  K is the count of low positions
+#: a lane index below sub * 128 has digits in (lane_digit_count): 3 for
+#: ?l x9 and ?a x7 at sub 128.
+DECODE_CASES = {
+    # all base digits r - 1: lane 0 is the last candidate and every
+    # lane past it wraps as the kernel's index does
+    "l9-all-top": ("?l" * 9, None, False, 128, _top("?l" * 9), 0),
+    # the carry runs through all six upper positions at lane 5001, with
+    # a start that is no multiple of the tile
+    "l9-carry-mid-tile": ("?l" * 9, None, False, 128,
+                          _top("?l" * 9, 5000 + 3 * 16384 + 777),
+                          3 * 16384 + 777),
+    "a7-all-top": ("?a" * 7, None, False, 128, _top("?a" * 7), 0),
+    "a7-carry-mid-tile": ("?a" * 7, None, False, 128,
+                          _top("?a" * 7, 9000 + 16384 + 5), 16384 + 5),
+    # a unit's last tile: pid * tile + offset just under 2^28
+    "l9-start-near-2^28": ("?l" * 9, None, False, 128, 26 ** 8 + 12345,
+                           (1 << 28) - 16384 - 77),
+    "a7-start-near-2^28": ("?a" * 7, None, False, 128,
+                           95 ** 6 * 94 + 4321, (1 << 28) - 16384),
+    "mixed-radices": ("?d?l?a?u?d?l", None, False, 128,
+                      10 * 26 * 95 * 26 * 9 + 17, 16384 * 5 + 1),
+    # radix 1 among the low positions (c, b) and the upper ones (a)
+    "fixed-characters": ("?la?l?l?lb?lc?d", None, False, 128,
+                         26 ** 4 * 10 * 3 + 25 * 26 * 10 + 99,
+                         16384 * 7 + 333),
+    # K would be 3: the mask has two positions and 100 candidates
+    "shorter-than-K": ("?d?d", None, False, 128, 37, 0),
+    "keyspace-under-a-tile": ("?l?l", None, False, 8, 600, 50),
+    "markov-segment-mux": ("?l?d?l?l?l", 11, True, 128,
+                           26 * 10 * 26 * 26 * 25 + 26 * 26 * 9, 16384),
+    "markov-lut-rows": ("?l?d?l?l?l", 12, False, 128,
+                        26 * 10 * 26 * 26 * 25 + 26 * 26 * 9, 16384 + 9),
+}
+
+
+@pytest.mark.parametrize("case", sorted(DECODE_CASES))
+def test_odometer_decode_matches_generator(case):
+    """decode_candidate_bytes (scalar tile digits, multiply-and-shift lane
+    digits, compare-and-subtract) against MaskGenerator.candidate,
+    byte for byte over a whole tile; an index past the keyspace wraps,
+    as the kernel's always has (`valid` masks the lane)."""
+    import jax
+    from dprf_tpu.ops.pallas_mask import (decode_candidate_bytes,
+                                          position_tables, segment_tables)
+    mask, seed, unbounded, sub, index, start = DECODE_CASES[case]
+    counts = None
+    if seed is not None:
+        counts = np.random.RandomState(seed).randint(
+            1, 10**6, (len(mask) // 2, 256)).astype(np.uint64)
+    gen = MaskGenerator(mask, markov_counts=counts)
+    if unbounded:
+        tabs, luts = segment_tables(gen.charsets), None
+        assert max(len(t) for t in tabs) > MAX_SEGMENTS
+    else:
+        tabs, luts = position_tables(gen.charsets)
+        assert (luts is not None) == (seed is not None)
+    shape = (sub, 128)
+    lane = (jax.lax.broadcasted_iota(jnp.int32, shape, 0) * 128
+            + jax.lax.broadcasted_iota(jnp.int32, shape, 1))
+    byts = decode_candidate_bytes(
+        gen.radices, tabs, gen.length,
+        jnp.asarray(gen.digits(index), jnp.int32), jnp.int32(start),
+        lane, sub * 128, None if luts is None else jnp.asarray(luts))
+    assert all(b.shape == shape and b.dtype == jnp.uint32 for b in byts)
+    got = np.stack([np.asarray(b).reshape(-1) for b in byts], axis=-1)
+    want = np.stack([
+        np.frombuffer(gen.candidate((index + start + i) % gen.keyspace),
+                      np.uint8) for i in range(sub * 128)])
+    assert (got == want).all()
+
+
+def test_lane_digit_quotients_are_exact_for_every_radix():
+    """The lane digits come from _quotient, (n * m) >> sh in int32:
+    exact, and free of overflow, for every radix a byte charset can
+    have and every lane index a tile can hold (decode_candidate_bytes'
+    own limit, 2^14)."""
+    from dprf_tpu.ops.pallas_mask import _quotient
+    n = np.arange(1 << 14, dtype=np.int32)
+    for r in range(2, 257):
+        assert (_quotient(n, r) == n // r).all(), r
+        # and the product never left int32
+        assert (_quotient(n.astype(np.int64), r) == n // r).all(), r
+
+
+def _tile_equations(jaxpr, shape, counts=None):
+    """primitive -> equations of `jaxpr` (nested ones included) whose
+    result is a `shape` tile."""
+    counts = {} if counts is None else counts
+    for eqn in jaxpr.eqns:
+        nested = [getattr(v, "jaxpr", v) for v in eqn.params.values()
+                  if hasattr(v, "eqns") or hasattr(v, "jaxpr")]
+        for sub_jaxpr in nested:
+            _tile_equations(sub_jaxpr, shape, counts)
+        if not nested and any(getattr(v.aval, "shape", None) == shape
+                              for v in eqn.outvars):
+            name = eqn.primitive.name
+            counts[name] = counts.get(name, 0) + 1
+    return counts
+
+
+#: the cells' kernel bodies at sub 128: id -> (engine, mask, targets
+#: (None: the bulk list's body, which ends at the digest), the most
+#: tile equations the decode may be, the most the body may be).  With
+#: the division form the counts were 163 of 879 (md5-mask) and 253 of
+#: 746 (ntlm-1m), 27 and 21 of them `div` / `rem` (CPU count, PR 33).
+BODY_COUNTS = {
+    "md5-l9-one-target": ("md5", "?l" * 9, 1, 64, 790),
+    "ntlm-a7-digest": ("ntlm", "?a" * 7, None, 110, 610),
+    "ntlm-a7-1000-targets": ("ntlm", "?a" * 7, 1000, 110, 1020),
+}
+
+
+@pytest.mark.parametrize("case", sorted(BODY_COUNTS))
+def test_kernel_body_has_no_vector_division(case):
+    """A count that keeps the odometer in place: the cells' kernel
+    bodies hold no `div` and no `rem` whose result is a tile, and the
+    decode stays a small share of the body's tile equations."""
+    import jax
+    from dprf_tpu.ops import pallas_mask as pm
+    engine, mask, n_targets, decode_max, body_max = BODY_COUNTS[case]
+    sub = 128
+    gen = MaskGenerator(mask)
+    tabs, _ = pm.position_tables(gen.charsets)
+    rng = np.random.RandomState(1)
+    words = rng.randint(0, 1 << 32, (n_targets or 1, 4),
+                        dtype=np.uint64).astype(np.uint32)
+    i32 = jax.ShapeDtypeStruct((), jnp.int32)
+    base = jax.ShapeDtypeStruct((gen.length,), jnp.int32)
+    if n_targets is None:
+        body = pm._build_kernel_body(engine, gen.radices, tabs,
+                                     gen.length, None, sub)
+        jaxpr = jax.make_jaxpr(
+            lambda pid, b, off: body.hashed_lanes(pid, b, None, off)[0])(
+                i32, base, i32)
+    elif n_targets == 1:
+        body = pm._build_kernel_body(engine, gen.radices, tabs,
+                                     gen.length, words[0], sub)
+        jaxpr = jax.make_jaxpr(body)(i32, base, i32)
+    else:
+        rows, block_bits, k, n_grp, _ = pm.kernel_probe_rows(words)
+        body = pm._build_kernel_body(engine, gen.radices, tabs,
+                                     gen.length, words, sub,
+                                     probe=(block_bits, k, n_grp))
+        jaxpr = jax.make_jaxpr(body)(i32, base, i32, jnp.asarray(rows))
+    shape = (sub, 128)
+    whole = _tile_equations(jaxpr.jaxpr, shape)
+    assert not {"div", "rem"} & set(whole), whole
+    decode = _tile_equations(jax.make_jaxpr(
+        lambda b, start, lane: pm.decode_candidate_bytes(
+            gen.radices, tabs, gen.length, b, start, lane, sub * 128))(
+                base, i32, jax.ShapeDtypeStruct(shape, jnp.int32)).jaxpr,
+        shape)
+    assert not {"div", "rem"} & set(decode), decode
+    assert sum(decode.values()) <= decode_max, decode
+    assert sum(whole.values()) <= body_max, whole
+    assert sum(decode.values()) < 0.20 * sum(whole.values())
