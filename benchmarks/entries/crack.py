@@ -43,9 +43,9 @@ import re
 import sys
 import time
 
-#: units let through as set-up before the window opens: unit 0 is
-#: always swept by the program's phase sampler, and the first fused
-#: program is compiled lazily at its first call, which is unit 1's
+#: units let through as set-up before the window opens: the fused
+#: program is compiled lazily at its first call, which is unit 0's;
+#: unit 1 is a warm one, so the window opens on a program that has run
 WARM_UNITS = 2
 #: a traced run closes its window at this share of `--seconds`.  The
 #: plan is an untraced run's (same seed, same `--seconds`, same plant),

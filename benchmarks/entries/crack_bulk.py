@@ -5,7 +5,9 @@ A bulk list's program looks every digest up in a table in HBM, and the
 TPU compiler gives an XLA gather custom calls of its own
 (`AssumeGatherIndicesInBound`, `ConcatBitcast`, `AllocateBuffer`): the
 text `crack.KERNEL_EVENT` matches, ` custom-call(`, would count those
-as calls of the hash kernel, and `mask_kernel_roofline` would raise.
+as calls of the hash kernel: three events a batch and more, where
+`work.slice_lanes` raises, and `kernel_pct` and `mask_kernel_roofline`
+would read the gathers' seconds.
 An `XLA Ops` event's text is the whole instruction, so what is matched
 here is the one attribute only a Pallas call carries.  (The kernel's
 own name, `%mask_digest_kernel.<n>`, will not do: the fusions that read

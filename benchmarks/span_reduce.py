@@ -3,8 +3,9 @@ read from the same trace as `trace_reduce.py` reads.
 
 The program opens a `jax.profiler.TraceAnnotation` `dprf:<station>` at
 each station of a unit's way through its sweep loop (`dprf_tpu/
-telemetry/trace.py`, `STATIONS`: lease, submit, probe, resolve, wait,
-decode, verify, complete), carrying the unit's id.  In a traced run
+telemetry/trace.py`, `STATIONS`: lease, submit, resolve, wait, decode,
+verify, complete, each but `lease` carrying the unit's id; `targets`
+is a job's, before its first unit).  In a traced run
 they are events of the plane `/host:CPU`, on the line of the thread
 that ran the loop, beside the harness's own `bench:` ones and on the
 clock of the device's `XLA Modules`.
@@ -99,8 +100,7 @@ def spans(obs):
 
 def idle_pct(obs, stations):
     """Chip 0's idle seconds under the named stations, over the slice,
-    in per cent (the readers of `probe_idle_pct` and
-    `dispatch_idle_pct`)."""
+    in per cent (the reader of `dispatch_idle_pct`)."""
     r = spans(obs)
     if not r:
         return None

@@ -10,9 +10,15 @@ whole slice's reduction (`span_reduce.reduce`) with what `check` found
 in it; with <seconds>, also the last <seconds> of the loaded trace to
 <out>.cut.json, which is how `spans_small.json` came to be (the last
 6 s of the slice of an `ntlm-1k.crack` run, seed 2600000209: five
-units, one of them probed, 1,186 host events), and
-`spans_small.expect.json` is the reduction of it, looked over by hand.
-Not part of a measuring run.
+units, 1,186 host events), and `spans_small.expect.json` is the
+reduction of it, looked over by hand.  Not part of a measuring run.
+
+The program of PR 26 had one station more, `probe` (the phase
+sampler's synced unit, one in 16; deleted with the sampler in PR 32),
+and one of the file's five units is such a one.  The file stays a
+sound input of the reducer, which gives any `dprf:` name its idle, so
+`check` still knows that a `decode` may lie inside a `probe`; a trace
+of today's program holds none, and every unit is a fused one.
 """
 
 import json

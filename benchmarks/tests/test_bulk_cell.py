@@ -16,7 +16,7 @@ import run
 
 END_TO_END = [{"name": n, "unit": "x"} for n in ("cand_per_s", "setup_s")]
 PER_LAYER = [{"name": n, "unit": "x"} for n in (
-    "probe_units_pct", "window_compiles", "kernel_pct",
+    "window_compiles", "kernel_pct", "kernel_sweeps",
     "mask_kernel_roofline", "target_load_s", "survivors_per_mcand",
     "probe_stage_pct")]
 CELL = "tiny-ntlm-bulk.crack"

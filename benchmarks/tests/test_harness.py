@@ -19,7 +19,7 @@ import traffic
 END_TO_END = [{"name": n, "unit": "x"}
               for n in ("cand_per_s", "ttfh_s", "setup_s")]
 PER_LAYER = [{"name": n, "unit": "x"} for n in (
-    "reach_chip_s", "lease_pct", "probe_units_pct", "unit_p95_ms",
+    "reach_chip_s", "lease_pct", "unit_p95_ms",
     "oracle_pct", "window_compiles", "kernel_pct", "device_idle_pct")]
 
 
@@ -73,7 +73,12 @@ def test_traced_run_reports_layers_and_no_device_metric_off_tpu(tmp_path):
     assert m["window_compiles"]["value"] == 0
     assert 0 < m["lease_pct"]["value"] < 100
     assert 0 < m["oracle_pct"]["value"] < 100
-    assert 0 < m["probe_units_pct"]["value"] < 100
+    # every unit, unit 0 too, went through the fused, pipelined
+    # dispatch: the job's own line counts no probed batch and has its
+    # seconds in `submit` and `wait`
+    assert "probe" not in r["ran"]["dispatch"]
+    host = dict(f.split(":") for f in r["ran"]["host"].split(","))
+    assert float(host["submit"]) > 0 and float(host["wait"]) > 0
     # no TPU plane in a CPU trace: the device readers find nothing to
     # read and return nothing, never 0
     assert "kernel_pct" not in m and "device_idle_pct" not in m
@@ -365,14 +370,40 @@ def test_mesh_cell_is_data_alone_and_correct(tmp_path):
     assert r["ran"]["out_devices"] == "0/1/2/3"
 
 
+def test_mesh_list_cell_is_data_alone_and_correct(tmp_path):
+    """`tiny-ntlm.crack`'s traffic with `chips` 4: the sharded worker
+    with a probe bitmap, its per-shard hit buffers gathered across the
+    four devices, the maybes verified on the one host."""
+    r = measure("tiny-ntlm.mesh4", 2**31 + 35, tmp_path, seconds=2.5)
+    assert r["correct"], r["compared"]
+    assert values(r)["plants_inside"] == 3
+    assert values(r)["lanes_judged"] == 4
+    assert r["ran"]["worker"] == "ShardedMaskWorker"
+    assert r["ran"]["out_devices"] == "0/1/2/3"
+    assert r["ran"]["verify"].startswith("lanes:")
+    assert r["metrics"]["ttfh_s"]["value"] > 0
+
+
+def test_the_control_is_not_correct_on_the_mesh_list(tmp_path):
+    r = measure("tiny-ntlm.mesh4", 4, tmp_path, seconds=2.5,
+                faults={"patches": faults.hits_dropped})
+    assert not r["correct"]
+    assert values(r)["plants_missed"] >= 1
+    assert values(r)["lanes_missed"] == values(r)["lanes_judged"]
+
+
+@pytest.mark.parametrize("cell,seconds", [("tiny-md5.mesh4", 1.5),
+                                          ("tiny-ntlm.mesh4", 2.5)])
 @pytest.mark.parametrize("seed", [1, 2, 2**31 + 3])
-def test_mesh_exchange_left_out_is_not_correct(tmp_path, monkeypatch, seed):
+def test_mesh_exchange_left_out_is_not_correct(tmp_path, monkeypatch, seed,
+                                               cell, seconds):
     """The exchange between chips left out: every shard keeps its own
     hits, and the host reads shard 0's.  16 lanes drawn from the seed
-    all lie on shard 0 once in 4 ** 16 seeds."""
+    all lie on shard 0 once in 4 ** 16 seeds; on the list none of the
+    4 lane units answers (three seeds alike, as on the chip)."""
     from dprf_tpu.parallel import sharded
     monkeypatch.setattr(sharded, "lax", faults.NoExchange(4))
-    r = measure("tiny-md5.mesh4", seed, tmp_path)
+    r = measure(cell, seed, tmp_path, seconds=seconds)
     assert not r["correct"]
     assert values(r)["lanes_missed"] >= 1
 

@@ -1,6 +1,6 @@
 """`span_reduce.reduce` on a hand-made trace with known answers and on
 the part of a real chip trace kept beside this file; `load` on a trace
-made here; the four readers over both."""
+made here; the three readers over both."""
 
 import importlib
 import json
@@ -13,15 +13,16 @@ import make_spans_small
 import span_reduce
 
 MS = 1_000_000          # ns
-READERS = ("idle_unnamed_pct", "probe_idle_pct", "dispatch_idle_pct",
-           "decode_pct")
+READERS = ("idle_unnamed_pct", "dispatch_idle_pct", "decode_pct")
 
 
 def synthetic():
     """A 100 ms slice.  The device runs [10, 40] and [60, 80]; the
     loop's thread leases, submits unit 1, resolves unit 0 (waits, then
     decodes with the oracle inside), probes unit 2 (one nested decode)
-    and completes; 5 ms of it under no span at all."""
+    and completes; 5 ms of it under no span at all.  (`probe` was a
+    station of the program until PR 32; here it stands for any
+    station the reducer has no list of: its idle is its own.)"""
     ev = lambda s, e, name, unit=None: [s * MS, e * MS, name, unit]
     loop = [ev(0, 2, "bench:lease"), ev(0.5, 1.5, "dprf:lease"),
             ev(2, 8, "dprf:submit", 1),
@@ -65,11 +66,10 @@ def test_idle_lands_under_the_station_open_at_the_time():
     assert sum(r["idle_by_station_s"].values()) == pytest.approx(r["idle_s"])
 
 
-def test_the_four_readers():
+def test_the_three_readers():
     r = span_reduce.reduce(synthetic())
     assert read("idle_unnamed_pct", r) == pytest.approx(12.0)   # of the idle
-    assert read("probe_idle_pct", r) == pytest.approx(10.0)     # of the slice
-    assert read("dispatch_idle_pct", r) == pytest.approx(11.0)
+    assert read("dispatch_idle_pct", r) == pytest.approx(11.0)  # of the slice
     assert read("decode_pct", r) == pytest.approx(20.0)
 
 
@@ -122,7 +122,7 @@ def test_the_trace_is_loaded_once_a_run(tmp_path, monkeypatch):
     values = [importlib.import_module("metrics." + n).read(obs)
               for n in READERS]
     assert calls == [str(tmp_path)]
-    assert values == pytest.approx([12.0, 10.0, 11.0, 20.0])
+    assert values == pytest.approx([12.0, 11.0, 20.0])
     # a work directory the trace never reached: nothing, and no raise
     monkeypatch.undo()
     assert span_reduce.spans({"trace_dir": str(tmp_path)}) is None
@@ -155,7 +155,7 @@ def test_load_reads_annotations_with_their_unit_ids(tmp_path):
 def test_a_traced_run_off_the_chip_reads_nothing_and_does_not_raise(tmp_path):
     """The whole way from `run.measure` to the readers, on the CPU:
     the trace is there and holds the stations, but no device plane, so
-    the four report nothing (never 0) and the run goes on."""
+    the three report nothing (never 0) and the run goes on."""
     import jax
     import run
     bench = {"workloads": [{"name": "tiny-md5.crack"}], "end_to_end": [],
@@ -167,8 +167,8 @@ def test_a_traced_run_off_the_chip_reads_nothing_and_does_not_raise(tmp_path):
     assert r["correct"], r["compared"]
     assert set(r["metrics"]) == {"lease_pct"}
     host = dict(f.split(":") for f in r["ran"]["host"].split(","))
-    assert {"lease", "submit", "probe", "resolve", "wait",
-            "complete"} <= set(host)
+    assert {"lease", "submit", "resolve", "wait", "complete"} <= set(host)
+    assert "probe" not in host
 
 
 def test_check_counts_what_a_sound_trace_must_not_have():

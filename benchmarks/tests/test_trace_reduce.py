@@ -132,47 +132,104 @@ def test_recorded_chip_trace():
     assert 0 <= r["close_after_program_s"] < 0.010
 
 
-# -- `mask_kernel_roofline`'s guard ------------------------------------------
+# -- `kernel_sweeps` and `mask_kernel_roofline` -------------------------------
 
 BATCH = 4194304
 
 
-def roofline_obs(trace, calls_in_flight=None):
-    """What the reader takes, around a reduced trace: an `md5-mask` job
-    on one v5e chip with that many batches' candidates in flight during
-    the slice (the trace's own kernel calls, where none is given)."""
+def kernel_obs(trace, sweeps, n_devices=1):
+    """What the two readers take, around a reduced trace: an `md5-mask`
+    job on v5e chips whose units in flight during the slice hold the
+    lanes the trace's kernel calls swept, over `sweeps`."""
     import traffic
     r = trace_reduce.reduce(trace, " custom-call(")
-    if calls_in_flight is None:
-        calls_in_flight = r["kernel_calls"]
+    in_flight = round(r["kernel_calls"] * BATCH * n_devices / sweeps)
     plant = traffic.Plant(0, b"abcdefghi", "", "tail")
-    return {"trace": r, "t_close": 100.0, "n_devices": 1,
+    return {"trace": r, "t_close": 100.0, "n_devices": n_devices,
             "device_kind": "TPU v5 lite",
             "cfg": {"engine": "md5", "targets": 1,
                     "flags": {"batch": BATCH}},
             "plan": traffic.Plan(0, "", "md5", 0, 0, 0, 0, [], [plant]),
-            "units": [(0, int(calls_in_flight) * BATCH, 99.0, None)],
-            "tail_units": [(0, BATCH, 1.0, 2.0)]}    # long completed
+            # in flight: one unit completed inside the slice, one never
+            "units": [(0, in_flight // 2, 99.0, 99.99),
+                      (0, in_flight - in_flight // 2, 99.0, None)],
+            # neither was in flight: completed long before the slice;
+            # a one-target job's tail, leased after the window's close
+            "tail_units": [(0, BATCH, 1.0, 2.0),
+                           (0, 400 * BATCH, 100.001, 110.0)]}
 
 
-@pytest.mark.parametrize("trace", [synthetic, recorded])
-def test_roofline_guard_passes_one_custom_call_a_batch(trace):
-    import metrics.mask_kernel_roofline as reader
-    value = reader.read(roofline_obs(trace()))
+@pytest.mark.parametrize("sweeps", [0.9, 1.9, None])
+@pytest.mark.parametrize("trace,n", [(synthetic, 1),
+                                     (lambda: synthetic(4), 4),
+                                     (recorded, 1)],
+                         ids=["synthetic", "synthetic4", "recorded"])
+def test_kernel_sweeps_and_the_roofline_s_numerator(trace, n, sweeps):
+    """Hashed once (the calls sweep 0.9 of the units in flight: the
+    units at the slice's edges are counted whole) or swept twice (1.9):
+    the roofline is, to the digit, calls x lanes over the kernel's
+    seconds, the kernel's share as it was called; `kernel_sweeps`
+    alone says which it was, and nothing is raised.  No kernel call in
+    the trace: both read nothing."""
+    import metrics.kernel_sweeps as sweeps_reader
+    import metrics.mask_kernel_roofline as roofline
+    import work
+    t = trace()
+    if sweeps is None:
+        for dev in t["devices"].values():
+            dev["ops"] = [e for e in dev["ops"]
+                          if " custom-call(" not in e[2]]
+        obs = kernel_obs(t, 1.0, n)
+        assert obs["trace"]["kernel_calls"] == 0
+        assert sweeps_reader.read(obs) is None
+        assert roofline.read(obs) is None
+        return
+    obs = kernel_obs(t, sweeps, n)
+    r = obs["trace"]
+    as_called = (100.0 * (r["kernel_calls"] * BATCH / r["kernel_whole_s"])
+                 * work.ops_of(obs) / work.peak_int32("TPU v5 lite"))
+    assert sweeps_reader.read(obs) == pytest.approx(sweeps, rel=1e-6)
+    value = roofline.read(obs)
     assert 0 < value < 100
+    assert value == as_called
     if trace is recorded:               # the chip's own kernel: 37.5 %
         assert 36.5 < value < 38.5
 
 
-@pytest.mark.parametrize("trace", [synthetic, recorded])
-def test_roofline_guard_raises_on_two_custom_calls_a_batch(trace):
-    """A second custom call in the programs (a gather's, say) would be
-    counted as the kernel's: twice the lanes the ledger has in flight."""
-    import metrics.mask_kernel_roofline as reader
-    t = trace()
-    calls = trace_reduce.reduce(t, " custom-call(")["kernel_calls"]
+@pytest.mark.parametrize("sweeps", [2.6, 3.2])
+def test_more_sweeps_than_any_program_makes_is_an_error(sweeps):
+    """A redrive reads at most 2.  Over `work.MAX_SWEEPS` the calls are
+    miscounted, and neither `kernels` reader gives a number."""
+    import metrics.kernel_sweeps as sweeps_reader
+    import metrics.mask_kernel_roofline as roofline
+    obs = kernel_obs(synthetic(), sweeps)
+    for reader in (sweeps_reader, roofline):
+        with pytest.raises(RuntimeError, match="KERNEL_EVENT matches"):
+            reader.read(obs)
+
+
+@pytest.mark.parametrize("extra", [1, 2])
+def test_other_custom_calls_counted_as_the_kernel_s(extra):
+    """What the guard was written for (PR 29): an entry driver whose
+    `KERNEL_EVENT` matches other custom calls beside the kernel's.  One
+    more a batch cannot be told from a window swept twice by the count
+    alone: `kernel_sweeps` reads 2 where the program sweeps once, and
+    says so in the ledger.  Two more a batch (PR 29's three events)
+    read 3, which no program does: an error."""
+    import metrics.kernel_sweeps as sweeps_reader
+    import metrics.mask_kernel_roofline as roofline
+    t = synthetic()
+    honest = trace_reduce.reduce(synthetic(), " custom-call(")["kernel_calls"]
     for dev in t["devices"].values():
-        dev["ops"] += [[s, e, "%gather.1 = s32[8] custom-call(s32[8] %p)"]
-                       for s, e, n in dev["ops"] if " custom-call(" in n]
-    with pytest.raises(RuntimeError, match="more than one custom call"):
-        reader.read(roofline_obs(t, calls))
+        dev["ops"] += [[s, e, f"%gather.{k} = s32[8] custom-call(s32[8] %p)"]
+                       for s, e, n in dev["ops"] if " custom-call(" in n
+                       for k in range(extra)]
+    obs = kernel_obs(t, 1.0 + extra)
+    assert obs["trace"]["kernel_calls"] == (1 + extra) * honest
+    if extra == 1:
+        assert sweeps_reader.read(obs) == pytest.approx(2.0)
+        assert 0 < roofline.read(obs) < 100
+    else:
+        for reader in (sweeps_reader, roofline):
+            with pytest.raises(RuntimeError, match="KERNEL_EVENT matches"):
+                reader.read(obs)

@@ -1,5 +1,6 @@
-"""Integer operations one candidate costs: the numerator of
-`kernel_roofline_pct`.
+"""Integer operations one candidate costs: with the candidates hashed,
+the numerator of `mask_kernel_roofline` (a traced slice's,
+`slice_lanes`) and of `step_mfu` (the whole window's).
 
 The count is of what no correct implementation can avoid, so that the
 share reads the same work whatever implements the kernel and cannot
@@ -106,6 +107,46 @@ def ops_of(obs):
     cfg = obs["cfg"]
     mask_len = len(obs["plan"].plants[0].plain)
     return ops_per_candidate(cfg["engine"], mask_len, cfg["targets"])
+
+
+#: `slice_lanes` refuses a slice whose kernel calls swept more than
+#: this many times the candidates in flight: no program does (a window
+#: whose hit buffer overflowed is swept a second time and never a
+#: third, so a job reads at most 2), and an entry driver's
+#: `KERNEL_EVENT` that matches other events beside the hash kernel does
+#: (three a batch read 3.2 once, PR 29)
+MAX_SWEEPS = 2.5
+
+
+def slice_lanes(obs):
+    """(swept, in_flight) of a traced run's slice, both over all chips:
+    the lanes its kernel calls swept (the calls that lie wholly inside
+    the slice, a chip, x `--batch` lanes a call and chip: that is what
+    the flag means, and the one thing read from the configuration, x
+    chips), and the candidates of the units in flight during the slice
+    by the harness's own ledger: every unit leased before the slice
+    ended (it ends with the window: a one-target job's tail is leased
+    after it) and not completed before it began, each counted whole.
+    `mask_kernel_roofline`'s numerator is the first, as the kernel was
+    called; `kernel_sweeps` is the one over the other.  Over
+    `MAX_SWEEPS` the calls were miscounted and every `kernels` metric
+    with them: an error, not a number."""
+    tr = obs["trace"]
+    swept = (tr["kernel_calls"] * obs["cfg"]["flags"]["batch"]
+             * obs["n_devices"])
+    t1 = obs["t_close"]
+    t0 = t1 - tr["window_s"]
+    in_flight = sum(n for _, n, leased, done
+                    in obs["units"] + obs["tail_units"]
+                    if leased < t1 and (done is None or done > t0))
+    if swept > MAX_SWEEPS * in_flight:
+        raise RuntimeError(
+            f"{tr['kernel_calls']} kernel calls a chip swept {swept} "
+            f"lanes where {in_flight} candidates were in flight: more "
+            f"than {MAX_SWEEPS} sweeps of each, which no program makes; "
+            f"the entry driver's KERNEL_EVENT matches more than the "
+            f"hash kernel")
+    return swept, in_flight
 
 
 def peak_int32(device_kind):
