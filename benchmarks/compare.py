@@ -20,7 +20,8 @@ potfile, its session journal, its own log) against the plain reference
     One target gives a job one lane that answers, and a fixed list the
     same lanes in every run; this puts them where the seed says.
 `potfile_wrong`   potfile lines that are not a target of the job or
-    whose plaintext the reference does not hash to that target.
+    whose plaintext the reference does not hash to that target (the
+    engine's `matches`, `engines/<engine>.py`).
 `audit_problems`  what `dprf audit` holds against the journal: an
     unreadable or dirty verdict, a coverage digest it cannot reproduce,
     a candidate covered twice, a hit recorded twice.
@@ -36,6 +37,7 @@ potfile, its session journal, its own log) against the plain reference
 Every one is a count with the limit 0: the guarantees are exact.
 """
 
+import engines
 import reference
 
 LIMITS = {"plants_missed": 0, "lanes_missed": 0, "potfile_wrong": 0,
@@ -65,9 +67,8 @@ def plants_missed(plants, swept, potfile_lines):
 
 
 def potfile_wrong(engine, target_lines, potfile_lines):
-    targets = set(target_lines)
-    return sum(h not in targets
-               or reference.digest_hex(engine, plain) != h
+    targets, matches = set(target_lines), engines.load(engine).matches
+    return sum(h not in targets or not matches(h, plain)
                for h, plain in potfile_lines)
 
 

@@ -1,5 +1,5 @@
 """Measure the chip's peak rate of 32-bit integer vector operations:
-the denominator of `kernel_roofline_pct`.
+the denominator of `mask_kernel_roofline` and `step_mfu`.
 
 Run on the chip, by hand, once (`python benchmarks/peak_int32.py`); it
 prints one JSON line and the value goes into `peaks.json` with this

@@ -5,16 +5,21 @@ with nothing of `dprf_tpu` in it.
   `??`, literals) and a mixed-radix decode with the RIGHTMOST mask
   position as the least-significant digit (the order the
   configuration files state under `index_order`);
-- MD5 from `hashlib`; MD4 written out here from RFC 1320 (OpenSSL 3
-  no longer ships it), NTLM = MD4 over the UTF-16LE password.
+- the potfile's reading: `hash line:plain`, as hashcat writes it.
 
-The comparison (`compare.py`) uses it to make the plants from the
-seed, to say which plants a run's covered intervals contain, and to
-re-hash every line the program wrote to its potfile.
+What an engine hashes a candidate to is the engine's own file,
+`engines/<engine>.py`; `md5`, `md4`, `ntlm` and `digest_hex` are still
+importable from here under their old names.
+
+The comparison (`compare.py`) uses it to say which plants a run's
+covered intervals contain and to read every line the program wrote to
+its potfile; the generator (`traffic.py`) to make the plants from the
+seed.
 """
 
-import hashlib
-import struct
+import engines
+from engines.md5 import md5                 # noqa: F401
+from engines.ntlm import md4, ntlm          # noqa: F401
 
 LOWER = bytes(range(ord("a"), ord("z") + 1))
 UPPER = bytes(range(ord("A"), ord("Z") + 1))
@@ -58,60 +63,9 @@ def candidate(mask, index):
     return bytes(reversed(out))
 
 
-# ---------------------------------------------------------------------------
-# MD4 (RFC 1320)
-
-_M32 = 0xFFFFFFFF
-
-
-def _rol(x, s):
-    return ((x << s) | (x >> (32 - s))) & _M32
-
-
-def md4(data):
-    """RFC 1320 MD4 of a byte string -> 16 digest bytes."""
-    msg = data + b"\x80" + b"\x00" * ((55 - len(data)) % 64) \
-        + struct.pack("<Q", 8 * len(data))
-    a, b, c, d = 0x67452301, 0xEFCDAB89, 0x98BADCFE, 0x10325476
-    for off in range(0, len(msg), 64):
-        x = struct.unpack("<16I", msg[off:off + 64])
-        aa, bb, cc, dd = a, b, c, d
-        for i in range(16):                       # round 1: F, k = i
-            s = (3, 7, 11, 19)[i % 4]
-            f = (b & c) | (~b & d)
-            a, b, c, d = d, _rol((a + f + x[i]) & _M32, s), b, c
-        for i in range(16):                       # round 2: G
-            k = (i % 4) * 4 + i // 4
-            s = (3, 5, 9, 13)[i % 4]
-            g = (b & c) | (b & d) | (c & d)
-            a, b, c, d = d, _rol((a + g + x[k] + 0x5A827999) & _M32, s), \
-                b, c
-        for i in range(16):                       # round 3: H
-            k = (0, 8, 4, 12, 2, 10, 6, 14, 1, 9, 5, 13, 3, 11, 7, 15)[i]
-            s = (3, 9, 11, 15)[i % 4]
-            h = b ^ c ^ d
-            a, b, c, d = d, _rol((a + h + x[k] + 0x6ED9EBA1) & _M32, s), \
-                b, c
-        a, b, c, d = ((a + aa) & _M32, (b + bb) & _M32,
-                      (c + cc) & _M32, (d + dd) & _M32)
-    return struct.pack("<4I", a, b, c, d)
-
-
-def ntlm(password):
-    """NTLM: MD4 over the password as UTF-16LE (bytes are latin-1)."""
-    return md4(password.decode("latin-1").encode("utf-16-le"))
-
-
-def md5(password):
-    return hashlib.md5(password).digest()
-
-
-#: engine name (as the configuration's `engine`) -> password -> digest
-HASHES = {"md5": md5, "ntlm": ntlm}
-
-
 def digest_hex(engine, password):
-    return HASHES[engine](password).hex()
+    """An unsalted engine's hash line of a password."""
+    return engines.load(engine).target_line(password, None, None)
 
 
 # ---------------------------------------------------------------------------

@@ -4,10 +4,12 @@
 Driven by data.  The cell's name finds `workloads/<cell>.json` (the
 traffic's parameters, the entry driver, the chips), that names
 `configs/<config>.json` (the job's sizes and flags) and
-`entries/<entry>.py` (what the window drives); every metric
-`BENCHMARK.json` lists for the cell is read by `metrics/<name>.py`.
-A new cell, configuration, entry or metric is a new file and an entry
-in `BENCHMARK.json`; nothing here names one.
+`entries/<entry>.py` (what the window drives); the configuration's
+`engine` finds `engines/<engine>.py` (hash-file lines, the potfile's
+check, the operation count); every metric `BENCHMARK.json` lists for
+the cell is read by `metrics/<name>.py`.  A new cell, configuration,
+entry, metric or engine is a new file (a cell, a configuration and a
+metric also an entry in `BENCHMARK.json`); nothing here names one.
 
 A run: find the chip (none, or too few: exit 3, nothing printed), make
 the inputs from the seed (`traffic.py`), let the entry driver warm up
@@ -106,7 +108,8 @@ def measure(cell_name, seed, seconds, traced, devs, workdir,
     cfg = traffic.load_json("configs", cell["config"] + ".json",
                             root=data_root)
     entry = importlib.import_module("entries." + cell["entry"])
-    plan = traffic.make_plan(cfg, cell, seed, seconds, entry.WARM_UNITS)
+    plan = traffic.make_plan(cfg, cell, seed, seconds, entry.WARM_UNITS,
+                             root=data_root)
     shutil.rmtree(workdir, ignore_errors=True)
     os.makedirs(workdir)
     ctx = {"cfg": cfg, "cell": cell, "plan": plan, "seconds": seconds,

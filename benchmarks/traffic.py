@@ -8,8 +8,8 @@ a data file alone:
 `sweep_start_units`  `[lo, hi)`: the job starts its sweep at a
     unit-aligned index drawn from the seed in that range of whole
     units.
-`fillers`  that many uniformly random digests, which no candidate
-    matches.
+`fillers`  that many lines no candidate matches (the engine's
+    `filler_line`: uniformly random digests).
 `window_plants`  a list of {"at_units": [lo, hi), "twin": bool}: one
     planted password each, at `window start + u * unit_size` with `u`
     drawn from the seed in `[lo, hi)`; a twin is a second plant inside
@@ -49,6 +49,7 @@ import math
 import os
 import random
 
+import engines
 import reference
 
 HERE = os.path.dirname(os.path.abspath(__file__))
@@ -86,15 +87,13 @@ class Plan:
         return [p for p in self.plants if p.where == where]
 
 
-def _plant(cfg, index, where):
-    plain = reference.candidate(cfg["mask"], index)
-    return Plant(index, plain, reference.digest_hex(cfg["engine"], plain),
-                 where)
-
-
-def make_plan(cfg, cell, seed, seconds, warm_units):
+def make_plan(cfg, cell, seed, seconds, warm_units, root=None):
     """Configuration + workload parameters + seed (+ the window's
-    length and the entry driver's warm units) -> Plan."""
+    length and the entry driver's warm units) -> Plan.  The
+    configuration's engine (`engines/<engine>.py`, searched under
+    `root` too) writes the hash file's lines; one that has no module is
+    an error here, naming the missing file."""
+    engine = engines.load(cfg["engine"], root)
     order = random.Random(int(seed))
     fixed = cell.get("list_seed")
     rng = order if fixed is None else random.Random(int(fixed))
@@ -105,23 +104,27 @@ def make_plan(cfg, cell, seed, seconds, warm_units):
     skip = rng.randrange(lo, hi) * unit
     start = skip + int(warm_units) * unit
     plants = []
+
+    def plant(index, where):
+        plain = reference.candidate(cfg["mask"], index)
+        plants.append(Plant(index, plain,
+                            engine.target_line(plain, rng, cfg), where))
+
     for spec in cell.get("window_plants", []):
         a, b = spec["at_units"]
         index = start + int(rng.uniform(a, b) * unit)
-        plants.append(_plant(cfg, index, "window"))
+        plant(index, "window")
         if spec.get("twin"):
             block = int(cell["twin_block"])
             base = index - index % block
             twin = base + rng.randrange(block - 1)
             twin += twin >= index           # any lane but the plant's
-            plants.append(_plant(cfg, twin, "window"))
+            plant(twin, "window")
     if cell.get("tail_plant"):
         behind = math.ceil(float(cell["tail_plant"]["units_per_s"])
                            * float(seconds))
-        plants.append(_plant(cfg, start + behind * unit
-                             + rng.randrange(unit), "tail"))
-    nbytes = len(reference.HASHES[cfg["engine"]](b""))
-    lines = ["%0*x" % (2 * nbytes, frng.getrandbits(8 * nbytes))
+        plant(start + behind * unit + rng.randrange(unit), "tail")
+    lines = [engine.filler_line(frng, cfg)
              for _ in range(int(cell.get("fillers", 0)))]
     lines += [p.line for p in plants]
     order.shuffle(lines)
