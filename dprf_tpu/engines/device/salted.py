@@ -488,9 +488,9 @@ class PallasSaltedMaskWorker(SaltedMaskWorker):
         key = (slen, sbatch)
         step = self._wide_ksteps.get(key)
         if step is None:
-            scale = max(1, sbatch // self.batch)
-            cap = max(self.hit_capacity,
-                      min(self.hit_capacity * scale, 1024))
+            from dprf_tpu.ops.superstep import window_capacity
+            cap = window_capacity(self.hit_capacity,
+                                  sbatch // self.batch)
             step = self._wide_ksteps[key] = \
                 pallas_ext.make_salted_crack_step(
                     self._algo, self.engine.order, self.gen,

@@ -143,9 +143,8 @@ class PallasKeccakMaskWorker(_KeccakTargetsMixin, MaskWorkerBase):
     def _make_step(self, batch: int):
         from dprf_tpu.ops.pallas_keccak import (
             make_pallas_keccak_crack_step)
-        scale = max(1, batch // self.batch)
-        cap = max(self.hit_capacity,
-                  min(self.hit_capacity * scale, 1024))
+        from dprf_tpu.ops.superstep import window_capacity
+        cap = window_capacity(self.hit_capacity, batch // self.batch)
         e = self.engine
         return make_pallas_keccak_crack_step(
             self.gen, self._tgt_words, batch, e._pad_byte,

@@ -1148,8 +1148,11 @@ def make_shard_mask_compute(engine_name: str, gen,
 
     Single target: found marks exactly-one-hit tiles; payload is tpos
     0; a tile holding 2+ hits can only report one lane, so the count
-    is inflated past hit_capacity and the workers' existing overflow
-    redrive re-covers the window exactly.  Multi target
+    is inflated past hit_capacity, the width one stride folds through:
+    the runtime carries such a stride's count past the WINDOW's width
+    (parallel/sharded._append_hits), whatever the window's buffer
+    holds, and the workers' existing overflow redrive re-covers the
+    window exactly.  Multi target
     (2..MAX_TARGETS): the compare is the blocked PR 14 probe bitmap
     (kernel_probe_rows) and every surviving lane comes back
     SENTINEL-tagged (payload == n_targets, out of range) -- the

@@ -51,6 +51,23 @@ def max_inner(batch: int, cap: int = 512) -> int:
     return 1 << (n.bit_length() - 1) if n >= 1 else 0
 
 
+#: widest hit buffer a fused window carries, unless the job's own
+#: per-batch capacity is wider still: keeps the window's reduce and
+#: gather buffers small.
+WINDOW_CAPACITY_MAX = 1024
+
+
+def window_capacity(hit_capacity: int, scale: int) -> int:
+    """Hit-buffer width of a fused window covering ``scale`` batches:
+    the ONE width policy of every window program (wide, loop and the
+    sharded superstep).  Per-candidate capacity matches the per-batch
+    step's up to WINDOW_CAPACITY_MAX slots; never below the nominal
+    capacity (a raised --hit-cap reaches every program unclamped), so
+    ``scale`` 1 is the per-batch width itself."""
+    return max(hit_capacity,
+               min(hit_capacity * scale, WINDOW_CAPACITY_MAX))
+
+
 def make_super_step(step, inner: int, batch: int, flag_fn=None):
     """Wrap `step(x, n_valid) -> tuple` in a device-side scan.
 

@@ -19,19 +19,27 @@ programs back:
   only host->device traffic is the tiny base argument -- a digit
   vector or a scalar window start -- so the packed candidate tensor
   never materializes on host and the per-sweep ``h2d`` phase collapses
-  to ~0), hits accumulate in a fixed ``hit_capacity`` **device-resident
-  buffer** carried through the loop, and exactly ONE ``psum`` +
-  ``all_gather`` round runs per superstep instead of one per batch.
+  to ~0), hits accumulate in a **device-resident buffer** carried
+  through the loop, as wide as the window asks
+  (``ops/superstep.window_capacity(hit_capacity, inner)``: the one
+  width policy of every fused window, one chip or many), and exactly
+  ONE ``psum`` + ``all_gather`` round runs per superstep instead of
+  one per batch.
 
 Hit-buffer lane values are *window-relative*: the keyspace offset of
 the hit inside the dispatched window (for wordlist steps, relative to
 ``w0 * n_rules``).  A window is bounded to int32 by the callers'
 ``ops/superstep.max_inner`` budget, so huge keyspaces never force
 64-bit lane math on device; the host adds the unit base.  A shard
-whose window collects more than ``hit_capacity`` hits reports the true
-count (the buffer truncates, the count does not), and the workers
-redrive the window through the per-batch program -- same overflow
-discipline as the wide/scan paths.
+whose window collects more hits than the window's buffer holds
+reports a count over the buffer's width (the buffer truncates, the
+count does not), and the workers redrive the window through the
+per-batch program -- same overflow discipline as the wide/scan paths.
+One stride still folds through ``hit_capacity`` slots, so a stride
+whose own count exceeds THAT width (more matches than slots, or a
+compute's ``hit_capacity + 1`` collision sentinel) pushes the window's
+count past the window's width: nothing is dropped without the count
+saying so.
 """
 
 from __future__ import annotations
@@ -44,6 +52,7 @@ from jax import lax, shard_map
 from jax.sharding import PartitionSpec as P
 
 from dprf_tpu.ops import compare as cmp_ops
+from dprf_tpu.ops.superstep import window_capacity
 from dprf_tpu.parallel.mesh import SHARD_AXIS
 
 
@@ -51,22 +60,31 @@ def _append_hits(carry, found, payload, rel, capacity: int,
                  true_count=None):
     """Fold one shard-batch's matches into the device-resident hit
     buffer carried across a superstep.  ``rel`` maps each local lane
-    to its window-relative value; slots past ``capacity`` drop (the
+    to its window-relative value.  The stride compacts to ``capacity``
+    slots (the per-batch width) and those scatter into the carry,
+    which is as wide as the WINDOW's buffer: the fold costs the same
+    whatever the window's width.  Slots past the carry's end drop (the
     count keeps the truth, so overflow is detectable on drain).
 
     ``true_count`` overrides the compacted count when the compute
     itself is the authority -- the TILE-compute (kernel) contract,
-    where per-tile collisions inflate the count past the buffer so
-    the drain path redrives the window exactly."""
+    where a per-tile collision inflates the count past ``capacity``.
+
+    A stride whose own count exceeds ``capacity`` was truncated here
+    (or holds a collision the compute could not report), so it pushes
+    the window's count past the carry's width and the drain path
+    redrives the window exactly."""
     count, lanes_buf, pay_buf = carry
+    width = lanes_buf.shape[0]
     c, lanes, pay = cmp_ops.compact_hits(found, payload, capacity)
     ok = lanes >= 0
     rel_lanes = jnp.where(ok, jnp.take(rel, jnp.maximum(lanes, 0)), -1)
     slots = jnp.where(ok, count + jnp.arange(capacity, dtype=jnp.int32),
-                      capacity)
+                      width)
     lanes_buf = lanes_buf.at[slots].set(rel_lanes, mode="drop")
     pay_buf = pay_buf.at[slots].set(pay, mode="drop")
     c = c if true_count is None else true_count
+    c = jnp.where(c > capacity, jnp.maximum(c, width + 1), c)
     return count + c, lanes_buf, pay_buf
 
 
@@ -89,9 +107,10 @@ def make_sharded_step(compute: Callable, mesh, span_per_shard: int,
     window-relative lane directly (the kernel reports one hit lane
     per grid cell, not per lane) and ``count`` is the authoritative
     hit count -- inflated past ``hit_capacity`` when a tile held more
-    hits than it can report, landing in the workers' existing
-    overflow redrive.  The arity is inspected at trace time, so
-    legacy 2-tuple computes are untouched.
+    hits than it can report, which ``_append_hits`` carries past the
+    window's width and so into the workers' existing overflow
+    redrive.  The arity is inspected at trace time, so legacy 2-tuple
+    computes are untouched.
 
     span_per_shard: span units one shard covers per batch; one step
     call covers ``n_dev * span_per_shard`` (``step.super_span``).
@@ -101,8 +120,11 @@ def make_sharded_step(compute: Callable, mesh, span_per_shard: int,
     step maps its rule-major flat lanes to keyspace offsets here).
 
     Returns the jitted per-batch step with attributes ``super_span``,
-    ``hit_capacity``, ``n_devices`` and ``superstep(inner)`` (cached
-    jitted superstep programs -- one per power-of-two ``inner``).
+    ``hit_capacity`` (the per-batch step's buffer width a shard),
+    ``n_devices`` and ``superstep(inner)`` (cached jitted superstep
+    programs -- one per power-of-two ``inner``, each with a buffer
+    ``window_capacity(hit_capacity, inner)`` wide a shard; decoders
+    read the built width from the buffers' shape).
     """
     n_dev = mesh.devices.size
     span_step = n_dev * span_per_shard
@@ -111,11 +133,13 @@ def make_sharded_step(compute: Callable, mesh, span_per_shard: int,
             return lane + offset
 
     def _program(inner: int):
+        width = window_capacity(hit_capacity, inner)
+
         def shard_fn(*args):
             dev = lax.axis_index(SHARD_AXIS)
             init = (jnp.int32(0),
-                    jnp.full((hit_capacity,), -1, jnp.int32),
-                    jnp.full((hit_capacity,), -1, jnp.int32))
+                    jnp.full((width,), -1, jnp.int32),
+                    jnp.full((width,), -1, jnp.int32))
 
             def body(i, carry):
                 offset = (i * span_step
@@ -138,16 +162,19 @@ def make_sharded_step(compute: Callable, mesh, span_per_shard: int,
                 count, lanes, payload = lax.fori_loop(0, inner, body,
                                                       init)
             # the ONE collective round of the dispatch: a scalar psum
-            # for the unit flag plus all_gathers of the fixed-size
-            # buffers, so the outputs are REPLICATED -- on a multi-host
+            # for the unit flag plus ONE all_gather of a shard's count
+            # and both buffers as one vector (three gathers of a
+            # window-wide buffer come out of the compiler as three
+            # collectives; one vector stays one all-reduce with the
+            # psum), so the outputs are REPLICATED -- on a multi-host
             # mesh every process reads the full buffers from its local
             # devices (per-shard outputs would only be addressable on
             # the owning host).
-            total = lax.psum(count, SHARD_AXIS)
-            return (total[None],
-                    lax.all_gather(count, SHARD_AXIS),
-                    lax.all_gather(lanes, SHARD_AXIS),
-                    lax.all_gather(payload, SHARD_AXIS))
+            every = lax.all_gather(
+                jnp.concatenate([count[None], lanes, payload]),
+                SHARD_AXIS)
+            return (lax.psum(count, SHARD_AXIS)[None], every[:, 0],
+                    every[:, 1:1 + width], every[:, 1 + width:])
 
         sharded = shard_map(
             shard_fn, mesh=mesh, in_specs=(P(),) * n_args,

@@ -1230,9 +1230,9 @@ class PallasWordlistWorker(DeviceWordlistWorker):
         old one -- so HBM holds at most the per-batch step's copy
         plus one wide copy, never one per cached size."""
         from dprf_tpu.ops.pallas_rules import make_rules_crack_step
-        scale = max(1, n_words // self.word_batch)
-        cap = max(self.hit_capacity,
-                  min(self.hit_capacity * scale, 1024))
+        from dprf_tpu.ops.superstep import window_capacity
+        cap = window_capacity(self.hit_capacity,
+                              n_words // self.word_batch)
         old = getattr(self, "_wide_shared", None)
         step = make_rules_crack_step(
             self.engine.name, self.gen, self._tgt_words, n_words,
@@ -1331,12 +1331,9 @@ class PallasMaskWorker(MaskWorkerBase):
         reduce buffers small."""
         from dprf_tpu.ops.pallas_mask import (make_pallas_mask_crack_step,
                                               make_pallas_multi_crack_step)
+        from dprf_tpu.ops.superstep import window_capacity
         scale = max(1, batch // self.batch)
-        # never below the user's nominal capacity (a raised --hit-cap
-        # must reach the per-batch step unclamped), never a wide
-        # buffer smaller than one batch's
-        cap = max(self.hit_capacity,
-                  min(self.hit_capacity * scale, 1024))
+        cap = window_capacity(self.hit_capacity, scale)
         if self.probe_table is not None:
             return self._with_table(self._bulk_step(batch, cap))
         if self.multi:
@@ -1380,8 +1377,8 @@ class PallasMaskWorker(MaskWorkerBase):
         applies unchanged."""
         from dprf_tpu.ops.pallas_mask import (make_pallas_mask_crack_step,
                                               make_pallas_multi_crack_step)
-        cap = max(self.hit_capacity,
-                  min(self.hit_capacity * inner, 1024))
+        from dprf_tpu.ops.superstep import window_capacity
+        cap = window_capacity(self.hit_capacity, inner)
         grid = self.batch // self._tile
         if self.probe_table is not None:
             # true hits and their table positions globalize by the

@@ -52,6 +52,19 @@ def _cpu_hits(gen, targets, unit):
                                      gen, targets).process(unit))
 
 
+def _process_noted(worker, unit):
+    """(hits, coverage notes) of one unit through the worker."""
+    from dprf_tpu.telemetry import coverage
+    notes = []
+    coverage.install_collector(
+        lambda name, start, end, attrs: notes.append((name, start, end)))
+    try:
+        hits = worker.process(unit)
+    finally:
+        coverage.install_collector(None)
+    return hits, notes
+
+
 # ---------------------------------------------------------------------------
 # sharded kernel compute: make_sharded_kernel_mask_step through
 # ShardedMaskWorker(kernel={...})
@@ -122,15 +135,9 @@ def test_sharded_kernel_multi_collided_tiles_rescan_one_tile(mesh):
                           oracle=get_engine("md5", device="cpu"),
                           kernel={"interpret": True, "sub": 8})
     unit = WorkUnit(0, 0, gen.keyspace)
-    notes = []
-    from dprf_tpu.telemetry import coverage
-    coverage.install_collector(
-        lambda name, start, end, attrs: notes.append((name, start, end)))
-    try:
-        got = sorted((h.target_index, h.cand_index, h.plaintext)
-                     for h in w.process(unit))
-    finally:
-        coverage.install_collector(None)
+    hits, notes = _process_noted(w, unit)
+    got = sorted((h.target_index, h.cand_index, h.plaintext)
+                 for h in hits)
     assert got == _cpu_hits(gen, targets, unit)
     rescans = sorted(n[1:] for n in notes if n[0] == "rescan")
     last_tile = (gen.keyspace - 1) // B * B
@@ -139,20 +146,32 @@ def test_sharded_kernel_multi_collided_tiles_rescan_one_tile(mesh):
     assert not [n for n in notes if n[0] == "redrive"]
 
 
-def test_sharded_kernel_overflow_redrives_exactly(mesh):
-    """More survivors in one shard's window than hit_capacity: the
-    buffer truncates but the count survives, and the worker must
-    redrive that window and report every hit exactly once."""
+def test_sharded_kernel_overflow_redrives_exactly(monkeypatch):
+    """More survivors in one shard's window than the WINDOW's buffer
+    holds (hit_capacity x inner slots): the buffer truncates but the
+    count stays over it, and the worker must redrive that window and
+    report every hit exactly once."""
+    monkeypatch.setenv("DPRF_SHARD_SUPER_CAP", "8")
     gen = MaskGenerator("?d?d?d?d?d")       # 100000
-    plant = [0, 1, 2, 3, 4, 5, gen.keyspace - 1]   # 6 > cap in shard 0
+    B = 4 * 8 * 128             # four sub=8 tiles a shard and stride
+    stride = 2 * B              # 12 strides: one window of 8, a tail
+    # shard 0's slices of the window: a plant in three tiles of six
+    # strides, 18 survivors into 2 x 8 = 16 slots
+    plant = sorted([i * stride + t * 1024 + 11 * i + t
+                    for i in range(6) for t in range(3)]
+                   + [gen.keyspace - 1])
     targets = _md5_targets(gen, plant)
     w = ShardedMaskWorker(get_engine("md5", device="jax"), gen, targets,
-                          mesh, batch_per_device=TILE, hit_capacity=2,
+                          make_mesh(2), batch_per_device=B,
+                          hit_capacity=2,
                           oracle=get_engine("md5", device="cpu"),
-                          kernel={"interpret": True, "sub": SUB})
-    hits = w.process(WorkUnit(0, 0, gen.keyspace))
+                          kernel={"interpret": True, "sub": 8})
+    hits, notes = _process_noted(w, WorkUnit(0, 0, gen.keyspace))
     assert sorted(h.cand_index for h in hits) == plant
     assert len(hits) == len(set(h.cand_index for h in hits))
+    assert ("window", 0, 8 * stride) in notes
+    assert [n[1:] for n in notes if n[0] == "redrive"] \
+        == [(0, 8 * stride)]
 
 
 def test_sharded_kernel_resume_resplit(mesh):

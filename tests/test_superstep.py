@@ -19,7 +19,8 @@ jnp = pytest.importorskip("jax.numpy")
 from dprf_tpu import get_engine
 from dprf_tpu.generators.mask import MaskGenerator
 from dprf_tpu.generators.wordlist import WordlistRulesGenerator
-from dprf_tpu.ops.superstep import make_super_step, max_inner
+from dprf_tpu.ops.superstep import (make_super_step, max_inner,
+                                    window_capacity)
 from dprf_tpu.runtime.worker import (DeviceMaskWorker,
                                      DeviceWordlistWorker,
                                      submit_or_process)
@@ -43,6 +44,25 @@ def test_max_inner_int32_budget():
     assert max_inner(1 << 22, 512) == 256       # 512 * 4M > 2^31
     assert max_inner(1 << 18, 512) == 512
     assert max_inner(1 << 31, 512) == 0
+
+
+@pytest.mark.parametrize("hit_capacity, scale, width", [
+    (64, 1, 64), (64, 2, 128), (64, 16, 1024), (64, 256, 1024),
+    (2, 1, 2), (2, 16, 32), (2, 256, 512),
+    # a raised --hit-cap reaches every program unclamped
+    (2048, 1, 2048), (2048, 16, 2048), (2048, 256, 2048),
+    # a window narrower than one batch keeps the batch's buffer
+    (64, 0, 64),
+])
+def test_window_capacity_is_what_the_wide_and_loop_sites_computed(
+        hit_capacity, scale, width):
+    """The one width policy: per-candidate capacity matches the
+    per-batch step's up to 1,024 slots, never under the nominal
+    capacity (the expression the wide words, wide mask and loop
+    programs each carried until it moved here)."""
+    assert window_capacity(hit_capacity, scale) == width
+    assert width == max(hit_capacity,
+                        min(hit_capacity * max(1, scale), 1024))
 
 
 def test_super_step_stacks_and_clips():
