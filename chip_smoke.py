@@ -530,6 +530,11 @@ def phase_pmkid(smoke, mask=PMKID_MASK, window=1 << 20, batch=1 << 15,
                        "--batch", batch, "--unit-size", 1 << 18,
                        "--unit-seconds", 0, "--limit", window)
     ran = check_ran(smoke, log, workers)
+    # the kernel worker is pipelined: its units reach the chip as
+    # counted per-batch dispatches of the programs warmup() compiled
+    if set(_shapes(ran["dispatch"])) != {"batch"}:
+        raise PhaseError(f"dispatch {ran['dispatch']!r}: expected the "
+                         "per-batch kernel step alone")
     check_plant(smoke, "wpa2-pmkid", line, plain, proc.stdout,
                 smoke.path("pmkid.pot"))
     return _record("pmkid", smoke, log, ran, t0, plant=plain.decode())

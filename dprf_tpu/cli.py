@@ -922,11 +922,15 @@ def _log_device(device: str, log: Log) -> None:
 def _log_ran(worker, log: Log, host: str = "", **kw) -> None:
     """One line at job end saying what ran: worker class, interpret
     flag, dispatch shapes, compile cost, every compile of this
-    process as a persistent-cache hit or miss, and (``host``, from
+    process as a persistent-cache hit or miss, (``kdf``) the key
+    derivations an iterated-KDF worker dispatched, and (``host``, from
     ``trace.format_stations``) the host's self seconds by station of
     the sweep loop."""
     from dprf_tpu import compilecache
     from dprf_tpu.runtime.worker import describe_worker
+    kdf = getattr(worker, "kdf_evals", None)
+    if kdf is not None:
+        kw["kdf"] = f"evals:{kdf}"
     log.info("ran", **kw, **describe_worker(worker),
              **compilecache.process_cache_counts(),
              **({"host": host} if host else {}))

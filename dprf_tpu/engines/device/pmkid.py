@@ -195,14 +195,23 @@ def make_sharded_pmkid_crack_step(engine: JaxPmkidEngine,
 
 class PallasPmkidWorker:
     """Per-target PMKID sweep over the fused Pallas PBKDF2 kernel
-    (ops/pallas_pbkdf2.py) -- measured 156.5 kH/s at 4096 iterations
-    on TPU v5 lite vs 17.4 kH/s through the XLA step (9x; ~2.56 G
-    SHA-1 compressions/s, the sha1 kernel's rate).
+    (ops/pallas_pbkdf2.py) -- measured 229.9 kH/s at 4096 iterations
+    on TPU v5 lite (16,390 SHA-1 compressions a candidate: 3.77 G
+    compressions/s) vs 17.4 kH/s through the XLA step.
 
     The kernel recomputes the PMK per target, so jobs where many
     targets share one ESSID (where the XLA step amortizes the KDF)
     route here only while the per-essid target count stays under the
-    kernel's speedup factor -- see maybe_pallas_pmkid_worker."""
+    kernel's speedup factor -- see maybe_pallas_pmkid_worker.
+
+    Submit-based: every (target, batch) dispatch of a unit is enqueued
+    up front with one device-accumulated flag, so UnitPipeline resolves
+    a unit behind the next unit's kernel.  What it dispatches is the
+    per-batch programs warmup() compiles, one per ESSID length, and
+    nothing else: ESSID, PMKID message and digest are ARGUMENTS of the
+    program, never constants of it, so every target of one ESSID length
+    runs (and finds in the persistent cache) the same compiled
+    program."""
 
     def __init__(self, engine, gen, targets: Sequence[Target],
                  batch: int = 1 << 15, hit_capacity: int = 64,
@@ -221,6 +230,10 @@ class PallasPmkidWorker:
                                                  hit_capacity)
                        for n in lens}
         self.batch = self.stride = next(iter(self._steps.values())).batch
+        #: PBKDF2 evaluations dispatched: one a valid lane and target
+        #: (the kernel shares no PMK between targets of one ESSID); the
+        #: job's `ran` line prints it as `kdf=evals:`
+        self.kdf_evals = 0
 
     #: the steps are always the compiled kernel (describe_worker):
     #: maybe_pallas_pmkid_worker builds this worker on a real chip only
@@ -238,51 +251,50 @@ class PallasPmkidWorker:
                     essid, msg5, tgt))
         self.compile_seconds, self.compile_cache = obs.seconds, obs.cache
 
-    def process(self, unit) -> list:
-        from dprf_tpu.runtime.worker import CpuWorker, Hit
+    def submit(self, unit):
+        from dprf_tpu.runtime.worker import PendingUnit
         iters = jnp.int32(self.engine.iterations)
-        hits: list = []
+        batches = [(b, jnp.asarray(self.gen.digits(b), dtype=jnp.int32),
+                    min(self.stride, unit.end - b))
+                   for b in range(unit.start, unit.end, self.stride)]
+        queued, flag = [], None
         for ti, (el, essid, msg5, tgt) in enumerate(self._targs):
-            step = self._steps[el]
-            queued = []
-            flag = None
-            for bstart in range(unit.start, unit.end, self.stride):
-                n_valid = min(self.stride, unit.end - bstart)
-                base = jnp.asarray(self.gen.digits(bstart),
-                                   dtype=jnp.int32)
-                result = step(base, jnp.int32(n_valid), iters, essid,
-                              msg5, tgt)
-                # device-accumulated unit flag; one readback per
-                # (target, unit) -- see MaskWorkerBase.process
+            for bstart, base, n_valid in batches:
+                result = self._steps[el](base, jnp.int32(n_valid), iters,
+                                         essid, msg5, tgt)
+                # device-accumulated unit flag: one readback a unit
                 flag = result[0] if flag is None else flag + result[0]
-                queued.append((bstart, result))
-            if flag is None or int(flag) == 0:
-                continue
-            for bstart, (count, lanes, _) in queued:
-                count = int(count)
-                if count == 0:
-                    continue
-                if count > self.hit_capacity:
-                    if self.oracle is None:
-                        raise RuntimeError(
-                            "hit buffer overflow and no oracle to "
-                            "rescan with; raise hit_capacity")
-                    end = min(bstart + self.stride, unit.end)
-                    sub = type(unit)(-1, bstart, end - bstart)
-                    hits.extend(Hit(ti, h.cand_index, h.plaintext)
-                                for h in CpuWorker(
-                                    self.oracle, self.gen,
-                                    [self.targets[ti]]).process(sub))
-                    continue
-                for lane in np.asarray(lanes):
-                    if lane < 0:
-                        continue
-                    gidx = bstart + int(lane)
-                    hits.append(Hit(ti, gidx, self.gen.candidate(gidx)))
-        return hits
-    # this sweep overlaps internally (queue-then-decode); an
-    # inherited submit() would bypass the override
-    process._serial_only = True
+                queued.append(("batch", (ti, bstart), result))
+                self.kdf_evals += n_valid
+        if flag is not None:
+            flag.copy_to_host_async()
+        return PendingUnit(self, unit, queued, flag)
+
+    def _decode_queued(self, kind: str, start, result, unit) -> list:
+        from dprf_tpu.runtime.worker import CpuWorker, Hit
+        ti, bstart = start
+        count, lanes, _ = result
+        count = int(count)
+        if count == 0:
+            return []
+        if count > self.hit_capacity:
+            if self.oracle is None:
+                raise RuntimeError(
+                    "hit buffer overflow and no oracle to "
+                    "rescan with; raise hit_capacity")
+            end = min(bstart + self.stride, unit.end)
+            sub = type(unit)(-1, bstart, end - bstart)
+            return [Hit(ti, h.cand_index, h.plaintext)
+                    for h in CpuWorker(self.oracle, self.gen,
+                                       [self.targets[ti]]).process(sub)]
+        gidx = [bstart + int(lane) for lane in np.asarray(lanes)
+                if lane >= 0]
+        return [Hit(ti, g, self.gen.candidate(g)) for g in gidx]
+
+    def process(self, unit) -> list:
+        return self.submit(unit).resolve()
+
+    process._submit_based = True   # safe to pipeline via submit()
 
 
 def maybe_pallas_pmkid_worker(engine, gen, targets, batch: int,

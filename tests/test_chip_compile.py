@@ -267,6 +267,33 @@ def test_bcrypt_eks_advance_512(one_chip):
                        _sds(one_chip, (18,), u32), _sds(one_chip)))
 
 
+def test_pmkid_kernel_step_is_one_program_for_every_target(one_chip):
+    """Config 5's kernel worker (`?d` x 8, batch 32,768): what it
+    dispatches for two targets of one ESSID length lowers to the same
+    program, so the second target's compile is the first one's
+    persistent-cache entry.  A target's ESSID, MACs or digest baked in
+    as a constant would make a program, and half a minute of Mosaic
+    compile, a target.  Lowering only: nothing is compiled."""
+    from dprf_tpu.engines.device.pmkid import PallasPmkidWorker
+    eng = get_engine("wpa2-pmkid", device="jax")
+    cpu = get_engine("wpa2-pmkid", device="cpu")
+    gen = MaskGenerator("?d" * 8)
+    texts = []
+    for line in ("00112233445566778899aabbccddeeff*0a1b2c3d4e5f*"
+                 "a0b1c2d3e4f5*" + b"net-1a2b3c4d".hex(),
+                 "ffeeddccbbaa99887766554433221100*020000000001*"
+                 "0c0000000002*" + b"HomeNet-2.4G".hex()):
+        w = PallasPmkidWorker(eng, gen, [cpu.parse_target(line)],
+                              batch=1 << 15, hit_capacity=64, oracle=cpu)
+        ((n, *targs),) = w._targs
+        args = (jnp.asarray(gen.digits(0), jnp.int32), jnp.int32(0),
+                jnp.int32(eng.iterations), *targs)
+        texts.append(w._steps[n].lower(
+            *[_sds(one_chip, np.shape(a), a.dtype) for a in args]).as_text())
+    assert "tpu_custom_call" in texts[0]
+    assert texts[0] == texts[1]
+
+
 @pytest.mark.parametrize("inner", [1, 16])
 def test_sharded_kernel_step_on_four_described_chips(mesh4, inner):
     """`dprf crack --devices 4` on config 2: the fused kernel as the

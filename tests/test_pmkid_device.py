@@ -9,6 +9,7 @@ import hashlib
 import hmac as hmac_mod
 import random
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -232,3 +233,223 @@ def test_pmkid_kernel_eligibility():
     assert pmkid_kernel_eligible(g, [8, 12])
     assert not pmkid_kernel_eligible(g, [0])
     assert not pmkid_kernel_eligible(g, [40])
+
+
+# ---------------------------------------------------------------------------
+# PallasPmkidWorker's pipelined sweep on the CPU.  The compiled kernel
+# is TPU-only (and its interpret mode takes minutes here), so its
+# per-batch step is stood in for by a step of the same signature and
+# outputs over the XLA crack step's PBKDF2 and HMAC (ops/hmac_sha1.py).
+
+STUB_ITERS = 3
+AP, STA = bytes.fromhex("aabbccddeeff"), bytes.fromhex("112233445566")
+#: (charsets, batch, essid, iterations, hit capacity) -> jitted sweep,
+#: shared by the tests: an XLA:CPU compile is the slow part
+_stub_sweeps: dict = {}
+
+
+def _stub_kernel_step(gen, batch, essid_len, hit_capacity=64,
+                      interpret=False, sub=None):
+    """make_pmkid_kernel_step's contract: step(base_digits, n_valid,
+    iters, essid int32[essid_len], msg5 int32[5], target int32[4]) ->
+    (count, lanes, tpos), and step.batch.  The XLA PBKDF2 takes the
+    ESSID and the iteration count as constants, so the stand-in keeps
+    one jitted sweep of each; message and digest stay arguments, as
+    they are the kernel's."""
+
+    def step(base_digits, n_valid, iters, essid, msg5, target):
+        salt = bytes(np.asarray(essid).astype(np.uint8))
+        assert len(salt) == essid_len
+        key = (tuple(gen.charsets), batch, salt, int(iters), hit_capacity)
+        if key not in _stub_sweeps:
+            _stub_sweeps[key] = _stub_sweep(gen, batch, salt, int(iters),
+                                            hit_capacity)
+        return _stub_sweeps[key](base_digits, n_valid, msg5, target)
+
+    step.batch = batch
+    return step
+
+
+def _stub_sweep(gen, batch, essid, iters, hit_capacity):
+    from dprf_tpu.ops import compare as cmp_ops
+    flat = gen.flat_charsets
+
+    @jax.jit
+    def sweep(base_digits, n_valid, msg5, target):
+        cand = gen.decode_batch(base_digits, flat, batch)
+        pmk = pbkdf2_sha1_pmk(
+            pack_ops.pack_raw(cand, gen.length, big_endian=True), essid,
+            iters)
+        istate, ostate = hmac_key_states(
+            jnp.zeros((batch, 16), jnp.uint32).at[:, :8].set(pmk))
+        pmkid = hmac_sha1_20(istate, ostate, jnp.broadcast_to(
+            msg5.astype(jnp.uint32), (batch, 5)))[:, :4]
+        found = (jnp.all(pmkid == target.astype(jnp.uint32), axis=-1)
+                 & (jnp.arange(batch) < n_valid))
+        return cmp_ops.compact_hits(found, jnp.zeros(batch, jnp.int32),
+                                    hit_capacity)
+
+    return sweep
+
+
+@pytest.fixture
+def stub_kernel(monkeypatch):
+    """(device engine, CPU oracle) at STUB_ITERS iterations, with the
+    kernel step stood in for."""
+    from dprf_tpu.ops import pallas_pbkdf2
+    monkeypatch.setattr(pallas_pbkdf2, "make_pmkid_kernel_step",
+                        _stub_kernel_step)
+    eng = get_engine("wpa2-pmkid", device="jax")
+    cpu = get_engine("wpa2-pmkid", device="cpu")
+    monkeypatch.setattr(eng, "iterations", STUB_ITERS)
+    monkeypatch.setattr(cpu, "iterations", STUB_ITERS)
+    return eng, cpu
+
+
+def _pmkid(pw, essid, ap=AP, sta=STA):
+    pmk = hashlib.pbkdf2_hmac("sha1", pw, essid, STUB_ITERS, 32)
+    return hmac_mod.new(pmk, b"PMK Name" + ap + sta,
+                        hashlib.sha1).digest()[:16]
+
+
+def _targets(cpu, *pairs):
+    return [cpu.parse_target(f"{_pmkid(pw, essid).hex()}*{AP.hex()}*"
+                             f"{STA.hex()}*{essid.hex()}")
+            for pw, essid in pairs]
+
+
+def _reference_hits(gen, targets, unit):
+    """What hashlib.pbkdf2_hmac + hmac find in the unit, target by
+    target: sorted (target, index, plaintext)."""
+    out = []
+    for ti, t in enumerate(targets):
+        p = t.params
+        for i in range(unit.start, unit.end):
+            pw = gen.candidate(i)
+            if _pmkid(pw, p["essid"], p["mac_ap"], p["mac_sta"]) == t.digest:
+                out.append((ti, i, pw))
+    return sorted(out)
+
+
+def _said(hits):
+    return sorted((h.target_index, h.cand_index, h.plaintext) for h in hits)
+
+
+def _pallas_worker(eng, cpu, gen, targets, hit_capacity=4):
+    from dprf_tpu.engines.device.pmkid import PallasPmkidWorker
+    return PallasPmkidWorker(eng, gen, targets, batch=64,
+                             hit_capacity=hit_capacity, oracle=cpu)
+
+
+#: a unit of four whole batches of 64 and a short fifth one of 17
+UNIT = WorkUnit(7, 320, 4 * 64 + 17)
+
+
+@pytest.mark.parametrize("where", [323, 320 + 2 * 64 + 41, 320 + 4 * 64 + 16],
+                         ids=["first-batch", "middle-batch",
+                              "short-last-batch"])
+def test_pallas_pmkid_submit_matches_hashlib(stub_kernel, where):
+    """A plant in the first, a middle and the short last batch of a
+    unit: submit().resolve() and process() say what hashlib says, and
+    the unit's every batch is one counted dispatch."""
+    from dprf_tpu.runtime.worker import describe_worker
+    eng, cpu = stub_kernel
+    gen = MaskGenerator("pw?d?d?d")
+    targets = _targets(cpu, (gen.candidate(where), b"HomeNet-2G"))
+    w = _pallas_worker(eng, cpu, gen, targets)
+    want = _reference_hits(gen, targets, UNIT)
+    assert want == [(0, where, gen.candidate(where))]
+    assert _said(w.submit(UNIT).resolve()) == want
+    assert _said(w.process(UNIT)) == want
+    assert describe_worker(w)["dispatch"] == "batch:10"
+    assert w.kdf_evals == 2 * UNIT.length
+
+
+def test_pallas_pmkid_two_essid_lengths(stub_kernel):
+    """Two targets of two ESSID lengths: one step a length, each
+    target swept with its own arguments, one PMK a lane and target."""
+    from dprf_tpu.runtime.worker import describe_worker
+    eng, cpu = stub_kernel
+    gen = MaskGenerator("pw?d?d?d")
+    targets = _targets(cpu, (gen.candidate(400), b"NetA"),
+                       (gen.candidate(401), b"HomeNet-2G"),
+                       (gen.candidate(580), b"NetA"))
+    w = _pallas_worker(eng, cpu, gen, targets)
+    assert sorted(w._steps) == [4, 10]
+    want = _reference_hits(gen, targets, UNIT)
+    assert [i for _, i, _ in want] == [400, 401, 580]
+    assert _said(w.process(UNIT)) == want
+    assert describe_worker(w)["dispatch"] == "batch:15"
+    assert w.kdf_evals == 3 * UNIT.length
+
+
+def test_pallas_pmkid_overflow_goes_to_the_oracle(stub_kernel):
+    """A batch with more hits than its buffer holds (the kernel's
+    count then passes the capacity, as it does for a tile holding two):
+    the batch is rescanned by the oracle and every hit comes back.  A
+    charset that repeats a byte makes two neighbouring candidates one
+    passphrase."""
+    eng, cpu = stub_kernel
+    gen = MaskGenerator("pw?d?d?1", custom={1: b"xx"})
+    plain = gen.candidate(130)
+    assert gen.candidate(131) == plain
+    targets = _targets(cpu, (plain, b"HomeNet-2G"))
+    unit = WorkUnit(3, 64, 128)
+    calls = []
+    real = cpu.hash_batch
+    cpu.hash_batch = lambda *a, **kw: calls.append(1) or real(*a, **kw)
+    try:
+        w = _pallas_worker(eng, cpu, gen, targets, hit_capacity=1)
+        got = _said(w.submit(unit).resolve())
+    finally:
+        del cpu.hash_batch
+    assert got == _reference_hits(gen, targets, unit) == [
+        (0, 130, plain), (0, 131, plain)]
+    assert calls            # the oracle rescanned the batch
+
+
+def test_pallas_pmkid_pipeline_depth_two(stub_kernel):
+    """UnitPipeline at depth 2 holds two submitted units and resolves
+    them oldest first: each unit's hits are its own."""
+    from dprf_tpu.runtime.worker import UnitPipeline, _ResolvedUnit
+    eng, cpu = stub_kernel
+    gen = MaskGenerator("pw?d?d?d")
+    targets = _targets(cpu, (gen.candidate(150), b"NetA"),
+                       (gen.candidate(420), b"HomeNet-2G"))
+    w = _pallas_worker(eng, cpu, gen, targets)
+    units = [WorkUnit(i, 100 * i, 100) for i in range(6)]
+    pipe = UnitPipeline(w, 2)
+    said = {}
+    for u in units:
+        pipe.submit(u)
+        if pipe.full:
+            unit, pending, _, _ = pipe.pop()
+            assert not isinstance(pending, _ResolvedUnit)
+            said[unit.unit_id] = _said(pending.resolve())
+    while len(pipe):
+        unit, pending, _, _ = pipe.pop()
+        said[unit.unit_id] = _said(pending.resolve())
+    assert said == {u.unit_id: _reference_hits(gen, targets, u)
+                    for u in units}
+    assert said[1] == [(0, 150, gen.candidate(150))]
+    assert said[4] == [(1, 420, gen.candidate(420))]
+
+
+def test_ran_line_counts_kdf_evals(stub_kernel):
+    """The job's `ran` line names the worker, its per-batch dispatches
+    and the PBKDF2 evaluations it dispatched."""
+    import io
+
+    from dprf_tpu.cli import _log_ran
+    from dprf_tpu.utils.logging import Log
+    eng, cpu = stub_kernel
+    gen = MaskGenerator("pw?d?d?d")
+    w = _pallas_worker(eng, cpu, gen,
+                       _targets(cpu, (gen.candidate(5), b"NetA")))
+    w.process(UNIT)
+    buf = io.StringIO()
+    _log_ran(w, Log(stream=buf))
+    line = buf.getvalue()
+    assert "worker=PallasPmkidWorker" in line and "interpret=False" in line
+    assert "dispatch=batch:5" in line
+    assert f"kdf=evals:{UNIT.length}" in line
