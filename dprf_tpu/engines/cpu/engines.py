@@ -12,9 +12,11 @@ import hmac
 import struct
 from typing import Optional, Sequence
 
+import numpy as np
+
 from dprf_tpu.engines import register
 from dprf_tpu.engines.base import HashEngine, Target
-from dprf_tpu.engines.cpu.md4 import md4
+from dprf_tpu.engines.cpu.md4 import md4, md4_blocks
 from dprf_tpu.engines.cpu import bcrypt as _bcrypt
 
 
@@ -642,13 +644,40 @@ class NtlmEngine(HashEngine):
 
     def hash_batch(self, candidates: Sequence[bytes],
                    params: Optional[dict] = None) -> list[bytes]:
-        out = []
-        for c in candidates:
-            # Candidates are raw bytes; treat them as latin-1 text so the
-            # UTF-16LE widening is the byte-interleave NTLM expects for
-            # the ASCII masks (?l/?u/?d/?s/?a) used by the benchmarks.
-            out.append(md4(c.decode("latin-1").encode("utf-16-le")))
-        return out
+        # Candidates are raw bytes; treat them as latin-1 text so the
+        # UTF-16LE widening is the byte-interleave NTLM expects for the
+        # ASCII masks (?l/?u/?d/?s/?a) used by the benchmarks.
+        if (len(candidates) < NTLM_ARRAY_MIN
+                or max(map(len, candidates)) > self.max_candidate_len):
+            return [md4(c.decode("latin-1").encode("utf-16-le"))
+                    for c in candidates]
+        return _ntlm_array(candidates)
+
+
+#: batch length from which NtlmEngine.hash_batch hashes as arrays.  On
+#: an x86 host the NumPy MD4 costs 0.31-0.33 ms a call up to 64
+#: candidates (0.48 ms at 261), the scalar md4 28 us a candidate (7.1 ms
+#: at 261): the two forms cost the same at about 11 candidates
+NTLM_ARRAY_MIN = 12
+
+
+def _ntlm_array(candidates: Sequence[bytes]) -> list[bytes]:
+    """NTLM of a batch of candidates of at most max_candidate_len
+    bytes (one MD4 block each), byte for byte `md4()` over each one's
+    UTF-16LE form, through md4_blocks.  Every row carries its own
+    length, so a batch of mixed lengths is exact."""
+    width, n = NtlmEngine.max_candidate_len, len(candidates)
+    lens = np.fromiter(map(len, candidates), dtype=np.int64, count=n)
+    chars = np.frombuffer(b"".join(c.ljust(width, b"\0")
+                                   for c in candidates),
+                          dtype=np.uint8).reshape(n, width)
+    block = np.zeros((n, 64), dtype=np.uint8)
+    block[:, 0:2 * width:2] = chars     # latin-1 -> UTF-16LE
+    block[np.arange(n), 2 * lens] = 0x80
+    words = block.view("<u4").astype(np.uint32)
+    words[:, 14] = lens * 16            # message bits (< 2^32)
+    digests = md4_blocks(words).astype("<u4").tobytes()
+    return [digests[i:i + 16] for i in range(0, 16 * n, 16)]
 
 
 @register("bcrypt")

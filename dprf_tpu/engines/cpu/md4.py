@@ -1,4 +1,5 @@
-"""Pure-Python MD4 (RFC 1320).
+"""MD4 (RFC 1320): one message in pure Python, or a batch of one-block
+messages as NumPy arrays (`md4_blocks`).
 
 hashlib's OpenSSL backend no longer ships md4, but NTLM is MD4 over the
 UTF-16LE password, so the oracle needs its own implementation.  Written
@@ -9,6 +10,8 @@ appendix test vectors in tests/test_cpu_engines.py.
 from __future__ import annotations
 
 import struct
+
+import numpy as np
 
 _MASK = 0xFFFFFFFF
 
@@ -58,3 +61,30 @@ def md4(data: bytes) -> bytes:
 
 def md4_hex(data: bytes) -> str:
     return md4(data).hex()
+
+
+def md4_blocks(x: np.ndarray) -> np.ndarray:
+    """MD4 of n messages that each pad to ONE block: x is the (n, 16)
+    uint32 array of their padded blocks' little-endian words, the
+    result the (n, 4) uint32 digest words.  The RFC's 48 steps as
+    array operations over the n lanes; uint32 arithmetic wraps as the
+    scalar `_compress`'s masks do."""
+    cols = [np.ascontiguousarray(x[:, k]) for k in range(16)]
+    init = (0x67452301, 0xEFCDAB89, 0x98BADCFE, 0x10325476)
+    a, b, c, d = (np.full(x.shape[0], v, dtype=np.uint32) for v in init)
+
+    def rotl(v, n):
+        return (v << n) | (v >> (32 - n))
+
+    for i, k in enumerate(_R1_ORDER):
+        a = rotl(a + ((b & c) | (~b & d)) + cols[k], _R1_SHIFTS[i % 4])
+        a, b, c, d = d, a, b, c
+    for i, k in enumerate(_R2_ORDER):
+        g = (b & c) | (b & d) | (c & d)
+        a = rotl(a + g + cols[k] + np.uint32(0x5A827999), _R2_SHIFTS[i % 4])
+        a, b, c, d = d, a, b, c
+    for i, k in enumerate(_R3_ORDER):
+        a = rotl(a + (b ^ c ^ d) + cols[k] + np.uint32(0x6ED9EBA1),
+                 _R3_SHIFTS[i % 4])
+        a, b, c, d = d, a, b, c
+    return np.stack([a, b, c, d], axis=1) + np.asarray(init, dtype=np.uint32)

@@ -247,7 +247,6 @@ class ShardedMaskWorker(_ShardedSuperstepMixin, MaskWorkerBase):
             return self._rescan(bstart, unit, window)
         lanes_np = np.asarray(lanes)
         tpos_np = np.asarray(tpos)
-        hits: list[Hit] = []
         # kernel multi-target compute: payload n_targets + 1 marks a
         # COLLIDED tile (2+ probe survivors, one reportable lane) by
         # its first lane -- re-probe exactly that tile for its
@@ -257,10 +256,11 @@ class ShardedMaskWorker(_ShardedSuperstepMixin, MaskWorkerBase):
         rescan = (tpos_np == len(self.targets) + 1) & (lanes_np >= 0) \
             if tile else np.zeros_like(lanes_np, bool)
         tiles = self._reprobe_tiles(
-            [bstart + int(lane) for lane in lanes_np[rescan]], unit)
-        for d in range(lanes_np.shape[0]):
-            hits.extend(self._decode_lanes(
-                bstart, np.where(rescan[d], -1, lanes_np[d]), tpos_np[d]))
+            [bstart + lane for lane in lanes_np[rescan].tolist()], unit)
+        # every shard's lanes are window-relative: the window's maybes,
+        # all shards together, go to the oracle in one call
+        hits = self._decode_lanes(bstart, np.where(rescan, -1, lanes_np),
+                                  tpos_np)
         hits.extend(self._tile_hits(tiles, unit))
         return hits
 
@@ -399,6 +399,7 @@ class ShardedWordlistWorker(_ShardedSuperstepMixin, WordlistWorkerBase):
         R = self.gen.n_rules
         base = ws * R
         hits: list[Hit] = []
+        maybes: list[int] = []
         for lane, tp in zip(np.asarray(lanes).ravel(),
                             np.asarray(tpos).ravel()):
             if lane < 0:
@@ -408,11 +409,13 @@ class ShardedWordlistWorker(_ShardedSuperstepMixin, WordlistWorkerBase):
                 continue
             if self.multi and not 0 <= int(tp) < len(self._order):
                 # probe-table survivor left unverified on device (see
-                # sharded.probe_lane_compare): one oracle hash each
-                hits.extend(self._verify_probe_lane(gidx))
+                # sharded.probe_lane_compare): the window's maybes go
+                # to the oracle in one call
+                maybes.append(gidx)
                 continue
             ti = int(self._order[int(tp)]) if self.multi else 0
             hits.append(Hit(ti, gidx, self.gen.candidate(gidx)))
+        hits.extend(self._verify_probe_lanes(maybes))
         return hits
 
     def _redrive_sharded_words(self, ws: int, nw: int,

@@ -422,8 +422,9 @@ class MaskWorkerBase:
         digests = [t.digest for t in self.targets]
         self.multi = len(digests) > 1
         if self.multi:
-            #: what the host verified (describe_worker's `verify=`)
-            self.verify_counts = {"lanes": 0, "tiles": 0,
+            #: what the host verified (describe_worker's `verify=`):
+            #: maybe lanes hashed, the oracle calls they took
+            self.verify_counts = {"lanes": 0, "batches": 0, "tiles": 0,
                                   "host_tiles": 0}
         if self.multi and probe_ok:
             ptable = self._setup_probe(digests)
@@ -853,35 +854,50 @@ class MaskWorkerBase:
             lambda bstart, row: self._batch_hits(bstart, row, unit))
 
     def _decode_lanes(self, bstart: int, lanes_np, tpos_np) -> list[Hit]:
-        """Hit-buffer arrays -> Hit records (lane -1 = unused slot).
+        """Hit-buffer arrays (any shape; lane -1 = unused slot) -> Hit
+        records: the lanes the device confirmed, in slot order, then
+        the verified maybes.
 
         Probe-table steps emit an OUT-OF-RANGE target pos for lanes
-        the device did not verify exactly (the degraded host-verify
-        layout, or a sharded survivor-buffer overflow): those lanes
-        are Bloom survivors, not confirmed hits, and resolve here
-        with one oracle hash each -- false positives drop (the
-        PallasMaskWorker multi-target maybe idiom)."""
-        hits = []
-        for lane, tp in zip(lanes_np, tpos_np):
-            if lane < 0:
-                continue
-            gidx = bstart + int(lane)
-            if self.multi and not 0 <= int(tp) < len(self._order):
-                hits.extend(self._verify_probe_lane(gidx))
-                continue
-            ti = int(self._order[int(tp)]) if self.multi else 0
-            hits.append(Hit(ti, gidx, self.gen.candidate(gidx)))
+        the device did not verify exactly (the in-kernel bitmap, the
+        degraded host-verify layout, or a sharded survivor-buffer
+        overflow): those lanes are Bloom survivors, not confirmed
+        hits, and resolve in ONE oracle call (_verify_probe_lanes) --
+        false positives drop."""
+        lanes_np, tpos_np = np.ravel(lanes_np), np.ravel(tpos_np)
+        valid = lanes_np >= 0
+        # Python ints: a keyspace index may pass int64
+        gidxs = [bstart + lane for lane in lanes_np[valid].tolist()]
+        if not self.multi:
+            return [Hit(0, g, self.gen.candidate(g)) for g in gidxs]
+        tpos = tpos_np[valid]
+        sure = ((tpos >= 0) & (tpos < len(self._order))).tolist()
+        hits = [Hit(int(self._order[tp]), g, self.gen.candidate(g))
+                for g, tp, ok in zip(gidxs, tpos.tolist(), sure) if ok]
+        hits.extend(self._verify_probe_lanes(
+            [g for g, ok in zip(gidxs, sure) if not ok]))
         return hits
 
-    def _verify_probe_lane(self, gidx: int) -> list[Hit]:
+    def _verify_probe_lanes(self, gidxs: Sequence[int]) -> list[Hit]:
+        """The one verifier of maybe lanes: their plaintexts hashed in
+        ONE oracle call, each digest looked up in the target map; the
+        hits in lane order, false positives dropped."""
+        if not gidxs:
+            return []
         if self.oracle is None:
             raise RuntimeError(
                 "unverified probe-table survivor and no oracle engine "
                 "to resolve it with")
-        plain = self.gen.candidate(gidx)
-        self.verify_counts["lanes"] += 1
-        ti = self._digest_map.get(self.oracle.hash_batch([plain])[0])
-        return [Hit(ti, gidx, plain)] if ti is not None else []
+        plains = [self.gen.candidate(g) for g in gidxs]
+        self.verify_counts["lanes"] += len(plains)
+        self.verify_counts["batches"] += 1
+        hits = []
+        for g, plain, digest in zip(gidxs, plains,
+                                    self.oracle.hash_batch(plains)):
+            ti = self._digest_map.get(digest)
+            if ti is not None:
+                hits.append(Hit(ti, g, plain))
+        return hits
 
     def _setup_tile_reprobe(self, twords, sub: int,
                             probe_fp: Optional[float] = None) -> None:
@@ -920,32 +936,33 @@ class MaskWorkerBase:
         return pending
 
     def _tile_hits(self, pending: list, unit: WorkUnit) -> list[Hit]:
-        """Read the re-probes back: every maybe lane of a collided
-        tile takes the single-maybe path (one oracle hash each).  A
-        lane that fails the probe bitmap cannot be a target (the
-        bitmap has no false negatives), so that is the tile's exact
-        answer.  A re-probe that disagrees with the kernel (fewer than
-        the two lanes that made the tile collided) or overflowed its
-        buffer, and a step with no re-probe, take the exact host
-        rescan of the tile."""
+        """Read the re-probes back: the maybe lanes of every collided
+        tile go to the verifier together, in one oracle call.  A lane
+        that fails the probe bitmap cannot be a target (the bitmap has
+        no false negatives), so that is the tile's exact answer.  A
+        re-probe that disagrees with the kernel (fewer than the two
+        lanes that made the tile collided) or overflowed its buffer,
+        and a step with no re-probe, take the exact host rescan of the
+        tile."""
         import jax
         hits: list[Hit] = []
+        maybes: list[int] = []
         # one readback for all of them: the device has nothing queued
         # behind the last re-probe until this unit is finished
         outs = jax.device_get([out for _, _, out in pending])
         for (start, end, _), out in zip(pending, outs):
             if out is not None and 2 <= out[0] <= out[1].shape[0]:
                 self.verify_counts["tiles"] += 1
-                for lane in out[1]:
-                    if lane >= 0:
-                        hits.extend(
-                            self._verify_probe_lane(start + int(lane)))
+                lanes = np.asarray(out[1])
+                maybes.extend(start + lane
+                              for lane in lanes[lanes >= 0].tolist())
                 continue
             self.verify_counts["host_tiles"] += 1
             coverage.note("rescan", start, end, unit=unit.unit_id,
                           kind="host")
             hits.extend(CpuWorker(self.oracle, self.gen, self.targets)
                         .process(WorkUnit(-1, start, end - start)))
+        hits.extend(self._verify_probe_lanes(maybes))
         return hits
 
     def _rescan(self, bstart: int, unit: WorkUnit,
@@ -1021,6 +1038,7 @@ class WordlistWorkerBase(MaskWorkerBase):
         """Flat rule-major step lanes -> in-unit Hit records."""
         R = self.gen.n_rules
         hits: list[Hit] = []
+        maybes: list[int] = []
         for lane, tp in zip(lanes_np, tpos_np):
             if lane < 0:
                 continue
@@ -1031,11 +1049,12 @@ class WordlistWorkerBase(MaskWorkerBase):
             if self.multi and not 0 <= int(tp) < len(self._order):
                 # probe-table survivor the device did not verify
                 # exactly (host-verify layout / survivor overflow):
-                # one oracle hash resolves it, false positives drop
-                hits.extend(self._verify_probe_lane(gidx))
+                # the window's maybes go to the oracle in one call
+                maybes.append(gidx)
                 continue
             ti = int(self._order[int(tp)]) if self.multi else 0
             hits.append(Hit(ti, gidx, self.gen.candidate(gidx)))
+        hits.extend(self._verify_probe_lanes(maybes))
         return hits
 
     def _rescan_words(self, ws: int, nw: int, unit: WorkUnit) -> list[Hit]:
@@ -1257,12 +1276,13 @@ class PallasMaskWorker(MaskWorkerBase):
 
     Multi target (config 2's 1k-hash list): the kernel's compare is
     the blocked-probe bitmap (ops/pallas_mask.kernel_probe_rows, sized
-    by DPRF_PALLAS_PROBE_FP); each single-maybe lane is
-    verified here with ONE oracle hash against the target digest map,
-    and each collided tile (>= 2 maybes, including any tile with two
-    real hits) is re-probed on the device (_reprobe_tiles: the
-    kernel's body over that one tile, returning its maybe lanes), each
-    of which is verified the same way.  The exact host rescan of a
+    by DPRF_PALLAS_PROBE_FP); a window's single-maybe lanes are
+    verified here in ONE oracle call against the target digest map
+    (_verify_probe_lanes), and each collided tile (>= 2 maybes,
+    including any tile with two real hits) is re-probed on the device
+    (_reprobe_tiles: the kernel's body over that one tile, returning
+    its maybe lanes), all of which are verified in one more call.
+    `verify=` counts the lanes and the calls.  The exact host rescan of a
     tile's whole TILE-candidate range remains for a re-probe that
     disagrees with the kernel or overflows TILE_LANES, and for the
     ops/pallas_ext steps (engines outside CORES), which have no
@@ -1437,12 +1457,11 @@ class PallasMaskWorker(MaskWorkerBase):
         tiles = self._reprobe_tiles(
             [bstart + int(t) * self._tile for t in np.asarray(ctiles)
              if t >= 0], unit)
-        hits: list[Hit] = []
-        for lane in np.asarray(lanes):
-            if lane >= 0:
-                # one oracle hash verifies a probe maybe exactly (and
-                # resolves its target index); false positives drop
-                hits.extend(self._verify_probe_lane(bstart + int(lane)))
+        # one oracle call verifies the window's probe maybes exactly
+        # (and resolves their target indices); false positives drop
+        lanes = np.asarray(lanes)
+        hits = self._verify_probe_lanes(
+            [bstart + lane for lane in lanes[lanes >= 0].tolist()])
         hits.extend(self._tile_hits(tiles, unit))
         return hits
 

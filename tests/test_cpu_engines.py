@@ -1,10 +1,13 @@
 """CPU oracle engines against published RFC/FIPS/OpenBSD test vectors."""
 
+import random
+
 import pytest
 
 pytestmark = pytest.mark.smoke
 
 from dprf_tpu import get_engine
+from dprf_tpu.engines.cpu import engines
 from dprf_tpu.engines.cpu.md4 import md4
 from dprf_tpu.engines.cpu import bcrypt as bc
 
@@ -70,6 +73,54 @@ def test_fast_hash_vectors(engine, vectors):
     for (msg, expect), got in zip(vectors, digests):
         assert got.hex() == expect, f"{engine}({msg!r})"
         assert len(got) == eng.digest_size
+
+
+def _ntlm_scalar(c: bytes) -> bytes:
+    return md4(c.decode("latin-1").encode("utf-16-le"))
+
+
+def _seeded_candidates(n: int, lo: int = 0, hi: int = 27,
+                       seed: int = 4000) -> list:
+    rng = random.Random(seed)
+    return [bytes(rng.randrange(256) for _ in range(rng.randint(lo, hi)))
+            for _ in range(n)]
+
+
+#: NtlmEngine.hash_batch's batches: each case is (candidates, known
+#: digests or None); every digest must be md4() over the UTF-16LE form
+NTLM_BATCHES = {
+    "mixed_lengths_2000": lambda: (_seeded_candidates(2000), None),
+    "every_byte_value": lambda: (
+        [bytes([b]) * (1 + b % 27) for b in range(256)]
+        + [bytes(range(i, i + 27)) for i in range(0, 256 - 27, 27)]
+        + [bytes(range(256 - 27, 256))], None),
+    "empty": lambda: ([], None),
+    "one": lambda: (_seeded_candidates(1), None),
+    "under_crossover": lambda: (
+        _seeded_candidates(engines.NTLM_ARRAY_MIN - 1), None),
+    "at_crossover": lambda: (
+        _seeded_candidates(engines.NTLM_ARRAY_MIN), None),
+    "over_crossover": lambda: (
+        _seeded_candidates(engines.NTLM_ARRAY_MIN + 1), None),
+    "longer_than_one_block": lambda: (
+        _seeded_candidates(40, 20, 40), None),
+    # the RFC 1320 messages (one of 80 bytes, two blocks) and NTLM's
+    # published digests in one batch past the crossover
+    "rfc1320_and_known_digests": lambda: (
+        [m for m, _ in MD4_VECTORS + NTLM_VECTORS] * 2,
+        [bytes.fromhex(h) for _, h in NTLM_VECTORS]),
+}
+
+
+@pytest.mark.parametrize("case", sorted(NTLM_BATCHES))
+def test_ntlm_batch_equals_scalar_md4(case):
+    cands, known = NTLM_BATCHES[case]()
+    got = get_engine("ntlm").hash_batch(cands)
+    assert got == [_ntlm_scalar(c) for c in cands]
+    assert all(type(d) is bytes and len(d) == 16 for d in got)
+    if known:
+        n = len(MD4_VECTORS)
+        assert got[n:n + len(known)] == known
 
 
 def test_parse_target_roundtrip():

@@ -12,6 +12,7 @@ shows results and counts, never a rate.
 import random
 import re
 
+import numpy as np
 import pytest
 
 # interpret-mode kernel compiles: tier-1, outside the smoke budget
@@ -21,7 +22,7 @@ from dprf_tpu.engines import get_engine
 from dprf_tpu.generators.mask import MaskGenerator
 from dprf_tpu.parallel import make_mesh
 from dprf_tpu.parallel.worker import ShardedMaskWorker
-from dprf_tpu.runtime.worker import (CpuWorker, PallasMaskWorker,
+from dprf_tpu.runtime.worker import (CpuWorker, Hit, PallasMaskWorker,
                                      describe_worker)
 from dprf_tpu.runtime.workunit import WorkUnit
 from dprf_tpu.telemetry import coverage
@@ -99,9 +100,11 @@ def case_twins_in_one_tile():
     assert got == _cpu_hits("ntlm", gen, targets, unit)
     assert [g[1] for g in got] == sorted(PLANTS)
     assert "loop" in w.dispatches
-    # 5/6, the triple, 10*BATCH+1/+4000 and the last tile's pair
-    assert w.verify_counts == {"lanes": len(PLANTS), "tiles": 4,
-                               "host_tiles": 0}
+    # 5/6, the triple, 10*BATCH+1/+4000 and the last tile's pair; the
+    # fused window's two tiles in one oracle call, the tail's three
+    # batches one call each for their singles and their tiles' lanes
+    assert w.verify_counts == {"lanes": len(PLANTS), "batches": 5,
+                               "tiles": 4, "host_tiles": 0}
     rescans = sorted(n[1:] for n in notes if n[0] == "rescan")
     last = (gen.keyspace - 1) // TILE * TILE
     assert rescans == [(0, TILE, "device"),
@@ -144,14 +147,15 @@ def case_seeded_range_equals_cpu_worker():
 
 
 def case_oracle_hashes_maybe_lanes_only():
-    """hash_batch is called once a maybe lane, one candidate a call,
-    and never over a tile's width."""
+    """hash_batch is called with the maybe lanes of a decoded window,
+    its singles in one call and its re-probed tiles' lanes in one
+    more, and never over a tile's width."""
     oracle = CountingOracle(get_engine("ntlm", device="cpu"))
     w, gen, _ = _worker(oracle=oracle)
     hits = w.process(WorkUnit(0, 0, gen.keyspace))
     assert len(hits) == len(PLANTS)
-    assert set(oracle.calls) == {1}
-    assert len(oracle.calls) == w.verify_counts["lanes"]
+    assert len(oracle.calls) == w.verify_counts["batches"] == 5
+    assert sum(oracle.calls) == w.verify_counts["lanes"]
     # every plant is a maybe; the filter may pass a few lanes more
     assert len(PLANTS) <= sum(oracle.calls) < TILE // 8
 
@@ -249,11 +253,104 @@ def case_describe_worker_says_what_was_verified():
     w, gen, _ = _worker()
     w.process(WorkUnit(0, 0, gen.keyspace))
     ran = describe_worker(w)
-    assert ran["verify"] == f"lanes:{len(PLANTS)},tiles:4,host_tiles:0"
+    assert ran["verify"] == \
+        f"lanes:{len(PLANTS)},batches:5,tiles:4,host_tiles:0"
     kinds = {f.split(":")[0] for f in ran["dispatch"].split(",")}
     assert kinds == {"loop", "batch"}
     single, _, _ = _worker(idxs=PLANTS[:1])
     assert "verify" not in describe_worker(single)
+
+
+def _loose_targets(engine, gen, idxs, n_fill=49):
+    """The plants' targets and n_fill seeded random digests: under a
+    kernel bitmap sized for LOOSE_FP the list passes false maybes."""
+    rng = random.Random(4000)
+    cpu = get_engine(engine, device="cpu")
+    return _targets(engine, gen, idxs) + [
+        cpu.parse_target(rng.randbytes(16).hex()) for _ in range(n_fill)]
+
+
+#: a kernel bitmap budget loose enough that 60 targets pass about 40
+#: false maybes over MASK's 100,000 candidates
+LOOSE_FP = 1e-2
+
+
+def case_mesh_verifies_a_window_in_one_call():
+    """Four shards, planted targets and false maybes: a window's single
+    maybes, every shard's together, take ONE oracle call and its
+    re-probed tiles' lanes one more; the hits are the exact sweep's,
+    and `verify=` counts the calls."""
+    gen = MaskGenerator(MASK)
+    targets = _loose_targets("ntlm", gen, PLANTS)
+    oracle = CountingOracle(get_engine("ntlm", device="cpu"))
+    w = ShardedMaskWorker(
+        get_engine("ntlm", device="jax"), gen, targets, make_mesh(4),
+        batch_per_device=TILE, hit_capacity=16, oracle=oracle,
+        kernel={"interpret": True, "sub": SUB, "probe_fp": LOOSE_FP})
+    windows = []
+    real = w._decode_queued
+
+    def spy(kind, start, result, unit):
+        calls, tiles = len(oracle.calls), w.verify_counts["tiles"]
+        out = real(kind, start, result, unit)
+        _, _, lanes, tpos = (np.asarray(a) for a in result)
+        single = (lanes >= 0) & (tpos != len(targets) + 1)
+        windows.append((len(oracle.calls) - calls,
+                        int(single.any(axis=1).sum()),
+                        w.verify_counts["tiles"] - tiles))
+        return out
+
+    w._decode_queued = spy
+    unit = WorkUnit(0, 0, gen.keyspace)
+    assert _hits(w, unit) == _cpu_hits("ntlm", gen, targets, unit)
+    assert w.verify_counts["host_tiles"] == 0
+    # (oracle calls, shards with single maybes, re-probed tiles)
+    assert all(calls == (shards > 0) + (tiles > 0)
+               for calls, shards, tiles in windows), windows
+    assert max(shards for _, shards, _ in windows) >= 2, windows
+    assert len(oracle.calls) == w.verify_counts["batches"]
+    assert sum(oracle.calls) == w.verify_counts["lanes"] > len(PLANTS)
+    said = re.fullmatch(r"lanes:(\d+),batches:(\d+),tiles:\d+,host_tiles:0",
+                        describe_worker(w)["verify"])
+    assert said and int(said[1]) > int(said[2])
+
+
+def case_confirmed_lanes_skip_the_oracle():
+    """A lane whose target pos is in range was confirmed by the device:
+    it is reported as it stands and never hashed; the out-of-range
+    lanes of the same buffer go to the oracle in one call."""
+    oracle = CountingOracle(get_engine("ntlm", device="cpu"))
+    w, gen, _ = _worker(oracle=oracle)
+    pos = {int(t): p for p, t in enumerate(w._order)}
+    n, base = len(w._order), 3 * TILE
+    # PLANTS[2] and PLANTS[4] confirmed, PLANTS[3] and two non-targets
+    # maybes, one slot unused
+    lanes = np.array([10, -1, 500, 7, 1000, 9])
+    tpos = np.array([pos[2], 0, n, n + 1, pos[4], n])
+    hits = w._decode_lanes(base, lanes, tpos)
+    assert hits == [Hit(i, PLANTS[i], gen.candidate(PLANTS[i]))
+                    for i in (2, 4, 3)]
+    assert oracle.calls == [3]
+    assert w.verify_counts["lanes"] == 3
+    assert w.verify_counts["batches"] == 1
+
+
+def case_verifier_keeps_lane_order():
+    """_verify_probe_lanes over shuffled lanes, the plants among them:
+    exactly the exact sweep's hits, in the order the lanes came, from
+    one oracle call."""
+    oracle = CountingOracle(get_engine("ntlm", device="cpu"))
+    w, gen, targets = _worker(oracle=oracle)
+    rng = random.Random(4001)
+    gidxs = PLANTS + [rng.randrange(gen.keyspace) for _ in range(40)]
+    rng.shuffle(gidxs)
+    exact = {h.cand_index: h for h in
+             CpuWorker(get_engine("ntlm", device="cpu"), gen, targets)
+             .process(WorkUnit(0, 0, gen.keyspace))}
+    assert w._verify_probe_lanes(gidxs) == \
+        [exact[g] for g in gidxs if g in exact]
+    assert oracle.calls == [len(gidxs)]
+    assert w._verify_probe_lanes([]) == [] and len(oracle.calls) == 1
 
 
 CASES = {name[len("case_"):]: fn for name, fn in sorted(globals().items())
@@ -286,9 +383,11 @@ def test_the_ran_line_carries_verify(tmp_path, capsys, monkeypatch):
     assert rc == 0 and len(ran) == 1, cap.err
     kv = dict(f.split("=", 1) for f in ran[0].split() if "=" in f)
     assert kv["worker"] == "PallasMaskWorker"
-    assert re.fullmatch(r"lanes:\d+,tiles:\d+,host_tiles:0", kv["verify"])
+    assert re.fullmatch(r"lanes:\d+,batches:\d+,tiles:\d+,host_tiles:0",
+                        kv["verify"])
     verify = dict(f.split(":") for f in kv["verify"].split(","))
     assert int(verify["tiles"]) == 2 and int(verify["lanes"]) >= 5
+    assert 1 <= int(verify["batches"]) < int(verify["lanes"])
     assert {f.split(":")[0] for f in kv["dispatch"].split(",")} <= \
         {"probe", "batch", "loop"}
     for w in words:
