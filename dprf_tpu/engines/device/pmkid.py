@@ -399,7 +399,7 @@ class PmkidDeviceWorker(DeviceMaskWorker):
         if int(n_multi):
             return self._resolve_all_targets(bstart, np.asarray(lanes))
         return self._decode_lanes(bstart, np.asarray(lanes),
-                                  np.asarray(tpos))
+                                  np.asarray(tpos), unit)
 
 
 class ShardedPmkidWorker(PmkidDeviceWorker):
@@ -426,4 +426,4 @@ class ShardedPmkidWorker(PmkidDeviceWorker):
         if int(n_multi):
             return self._resolve_all_targets(bstart, lanes_np)
         return self._decode_lanes(bstart, lanes_np,
-                                  np.asarray(tpos).ravel())
+                                  np.asarray(tpos).ravel(), unit)

@@ -260,7 +260,7 @@ class ShardedMaskWorker(_ShardedSuperstepMixin, MaskWorkerBase):
         # every shard's lanes are window-relative: the window's maybes,
         # all shards together, go to the oracle in one call
         hits = self._decode_lanes(bstart, np.where(rescan, -1, lanes_np),
-                                  tpos_np)
+                                  tpos_np, unit)
         hits.extend(self._tile_hits(tiles, unit))
         return hits
 
@@ -415,7 +415,7 @@ class ShardedWordlistWorker(_ShardedSuperstepMixin, WordlistWorkerBase):
                 continue
             ti = int(self._order[int(tp)]) if self.multi else 0
             hits.append(Hit(ti, gidx, self.gen.candidate(gidx)))
-        hits.extend(self._verify_probe_lanes(maybes))
+        hits.extend(self._verify_probe_lanes(maybes, unit))
         return hits
 
     def _redrive_sharded_words(self, ws: int, nw: int,

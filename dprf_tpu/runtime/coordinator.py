@@ -146,21 +146,23 @@ class Coordinator:
     def _all_found(self) -> bool:
         return len(self.found) >= len(self.targets)
 
-    def _record(self, hit: Hit) -> bool:
-        """Record one verified hit; returns False (and records nothing)
-        if the oracle re-hash rejects it."""
+    def _record(self, hit: Hit, unit_id: int) -> bool:
+        """Record one verified hit of unit `unit_id`; returns False (and
+        records nothing) if the oracle re-hash rejects it."""
         if hit.target_index in self.found:
             return True
         target = self.targets[hit.target_index]
-        if self.oracle is not None and not self.oracle.verify(hit.plaintext,
-                                                              target):
-            from dprf_tpu.utils.logging import DEFAULT as log
-            self.rejected += 1
-            self._m_rejects.inc()
-            log.warn("rejected unverifiable device hit; rescanning unit "
-                     "with the CPU oracle", target=target.raw[:32],
-                     cand_index=hit.cand_index)
-            return False
+        if self.oracle is not None:
+            with self.tracer.station("oracle", unit=unit_id):
+                ok = self.oracle.verify(hit.plaintext, target)
+            if not ok:
+                from dprf_tpu.utils.logging import DEFAULT as log
+                self.rejected += 1
+                self._m_rejects.inc()
+                log.warn("rejected unverifiable device hit; rescanning "
+                         "unit with the CPU oracle", target=target.raw[:32],
+                         cand_index=hit.cand_index)
+                return False
         self.found[hit.target_index] = hit.plaintext
         self._m_hits.inc()
         self._g_found.set(len(self.found))
@@ -190,7 +192,7 @@ class Coordinator:
         construction) before the unit may count as covered."""
         rejected = False
         for hit in hits:
-            rejected |= not self._record(hit)
+            rejected |= not self._record(hit, unit.unit_id)
         if rejected:
             from dprf_tpu.runtime.worker import CpuWorker, OrderedWorker
             rescan = CpuWorker(self.oracle, self.worker.gen,
@@ -200,8 +202,10 @@ class Coordinator:
                 # rank-ordered job: the unit's span is ranks, and the
                 # rescan must decode it through the same bijection
                 rescan = OrderedWorker(rescan, order)
-            for hit in rescan.process(unit):
-                self._record(hit)   # oracle-produced: verifies trivially
+            with self.tracer.station("oracle", unit=unit.unit_id):
+                found = rescan.process(unit)
+            for hit in found:
+                self._record(hit, unit.unit_id)   # oracle-produced
 
     def run(self) -> JobResult:
         from dprf_tpu.runtime.worker import UnitPipeline, pipeline_depth

@@ -59,9 +59,14 @@ class PendingUnit:
             flag = int(self.flag)
         if flag == 0:
             return []
+        import jax
         hits: list[Hit] = []
         with tracer.station("decode", unit=uid):
-            for kind, start, result in self.queued:
+            # every dispatch's hit buffers in one transfer: the
+            # decoders read host arrays, a fused program's rows too
+            with tracer.station("readback", unit=uid):
+                queued = jax.device_get(self.queued)
+            for kind, start, result in queued:
                 hits.extend(self.worker._decode_queued(
                     kind, start, result, self.unit))
         return hits
@@ -853,7 +858,8 @@ class MaskWorkerBase:
             result, start, self.stride,
             lambda bstart, row: self._batch_hits(bstart, row, unit))
 
-    def _decode_lanes(self, bstart: int, lanes_np, tpos_np) -> list[Hit]:
+    def _decode_lanes(self, bstart: int, lanes_np, tpos_np,
+                      unit: WorkUnit) -> list[Hit]:
         """Hit-buffer arrays (any shape; lane -1 = unused slot) -> Hit
         records: the lanes the device confirmed, in slot order, then
         the verified maybes.
@@ -875,10 +881,11 @@ class MaskWorkerBase:
         hits = [Hit(int(self._order[tp]), g, self.gen.candidate(g))
                 for g, tp, ok in zip(gidxs, tpos.tolist(), sure) if ok]
         hits.extend(self._verify_probe_lanes(
-            [g for g, ok in zip(gidxs, sure) if not ok]))
+            [g for g, ok in zip(gidxs, sure) if not ok], unit))
         return hits
 
-    def _verify_probe_lanes(self, gidxs: Sequence[int]) -> list[Hit]:
+    def _verify_probe_lanes(self, gidxs: Sequence[int],
+                            unit: WorkUnit) -> list[Hit]:
         """The one verifier of maybe lanes: their plaintexts hashed in
         ONE oracle call, each digest looked up in the target map; the
         hits in lane order, false positives dropped."""
@@ -888,15 +895,16 @@ class MaskWorkerBase:
             raise RuntimeError(
                 "unverified probe-table survivor and no oracle engine "
                 "to resolve it with")
-        plains = [self.gen.candidate(g) for g in gidxs]
-        self.verify_counts["lanes"] += len(plains)
+        self.verify_counts["lanes"] += len(gidxs)
         self.verify_counts["batches"] += 1
         hits = []
-        for g, plain, digest in zip(gidxs, plains,
-                                    self.oracle.hash_batch(plains)):
-            ti = self._digest_map.get(digest)
-            if ti is not None:
-                hits.append(Hit(ti, g, plain))
+        with get_tracer().station("oracle", unit=unit.unit_id):
+            plains = [self.gen.candidate(g) for g in gidxs]
+            for g, plain, digest in zip(gidxs, plains,
+                                        self.oracle.hash_batch(plains)):
+                ti = self._digest_map.get(digest)
+                if ti is not None:
+                    hits.append(Hit(ti, g, plain))
         return hits
 
     def _setup_tile_reprobe(self, twords, sub: int,
@@ -919,20 +927,24 @@ class MaskWorkerBase:
         the next unit's program."""
         import jax.numpy as jnp
         pending = []
-        for start in starts:
-            end = min(start + self._tile, unit.end)
-            if end <= start:
-                continue
-            out = None
-            if self._reprobe is not None:
-                # coverage note (ISSUE 19): the tile is swept a second
-                # time, on the device -- deliberate re-coverage
-                coverage.note("rescan", start, end, unit=unit.unit_id,
-                              kind="device")
-                out = self._reprobe(
-                    jnp.asarray(self.gen.digits(start), dtype=jnp.int32),
-                    jnp.int32(end - start))
-            pending.append((start, end, out))
+        if not starts:
+            return pending
+        with get_tracer().station("reprobe", unit=unit.unit_id):
+            for start in starts:
+                end = min(start + self._tile, unit.end)
+                if end <= start:
+                    continue
+                out = None
+                if self._reprobe is not None:
+                    # coverage note: the tile is swept a second time,
+                    # on the device -- deliberate re-coverage
+                    coverage.note("rescan", start, end,
+                                  unit=unit.unit_id, kind="device")
+                    out = self._reprobe(
+                        jnp.asarray(self.gen.digits(start),
+                                    dtype=jnp.int32),
+                        jnp.int32(end - start))
+                pending.append((start, end, out))
         return pending
 
     def _tile_hits(self, pending: list, unit: WorkUnit) -> list[Hit]:
@@ -945,11 +957,15 @@ class MaskWorkerBase:
         and a step with no re-probe, take the exact host rescan of the
         tile."""
         import jax
+        if not pending:
+            return []
+        tracer = get_tracer()
         hits: list[Hit] = []
         maybes: list[int] = []
         # one readback for all of them: the device has nothing queued
         # behind the last re-probe until this unit is finished
-        outs = jax.device_get([out for _, _, out in pending])
+        with tracer.station("reprobe_wait", unit=unit.unit_id):
+            outs = jax.device_get([out for _, _, out in pending])
         for (start, end, _), out in zip(pending, outs):
             if out is not None and 2 <= out[0] <= out[1].shape[0]:
                 self.verify_counts["tiles"] += 1
@@ -960,9 +976,10 @@ class MaskWorkerBase:
             self.verify_counts["host_tiles"] += 1
             coverage.note("rescan", start, end, unit=unit.unit_id,
                           kind="host")
-            hits.extend(CpuWorker(self.oracle, self.gen, self.targets)
-                        .process(WorkUnit(-1, start, end - start)))
-        hits.extend(self._verify_probe_lanes(maybes))
+            with tracer.station("oracle", unit=unit.unit_id):
+                hits.extend(CpuWorker(self.oracle, self.gen, self.targets)
+                            .process(WorkUnit(-1, start, end - start)))
+        hits.extend(self._verify_probe_lanes(maybes, unit))
         return hits
 
     def _rescan(self, bstart: int, unit: WorkUnit,
@@ -981,7 +998,9 @@ class MaskWorkerBase:
         # deliberate, not a double-lease
         coverage.note("rescan", bstart, end, unit=unit.unit_id)
         sub = WorkUnit(-1, bstart, end - bstart)
-        return CpuWorker(self.oracle, self.gen, self.targets).process(sub)
+        with get_tracer().station("oracle", unit=unit.unit_id):
+            return CpuWorker(self.oracle, self.gen,
+                             self.targets).process(sub)
 
     def _batch_hits(self, bstart: int, result, unit: WorkUnit,
                     window: int = 0) -> list[Hit]:
@@ -995,7 +1014,8 @@ class MaskWorkerBase:
             if window > self.stride:
                 return self._redrive_wide(bstart, window, unit)
             return self._rescan(bstart, unit, window)
-        return self._decode_lanes(bstart, np.asarray(lanes), np.asarray(tpos))
+        return self._decode_lanes(bstart, np.asarray(lanes),
+                                  np.asarray(tpos), unit)
 
     def _redrive_wide(self, bstart: int, window: int,
                       unit: WorkUnit) -> list[Hit]:
@@ -1054,7 +1074,7 @@ class WordlistWorkerBase(MaskWorkerBase):
                 continue
             ti = int(self._order[int(tp)]) if self.multi else 0
             hits.append(Hit(ti, gidx, self.gen.candidate(gidx)))
-        hits.extend(self._verify_probe_lanes(maybes))
+        hits.extend(self._verify_probe_lanes(maybes, unit))
         return hits
 
     def _rescan_words(self, ws: int, nw: int, unit: WorkUnit) -> list[Hit]:
@@ -1069,7 +1089,9 @@ class WordlistWorkerBase(MaskWorkerBase):
         # overflowed word window, in candidate-index coordinates
         coverage.note("rescan", start, end, unit=unit.unit_id)
         sub = WorkUnit(-1, start, end - start)
-        return CpuWorker(self.oracle, self.gen, self.targets).process(sub)
+        with get_tracer().station("oracle", unit=unit.unit_id):
+            return CpuWorker(self.oracle, self.gen,
+                             self.targets).process(sub)
 
 
 class DeviceWordlistWorker(WordlistWorkerBase):
@@ -1461,7 +1483,7 @@ class PallasMaskWorker(MaskWorkerBase):
         # (and resolves their target indices); false positives drop
         lanes = np.asarray(lanes)
         hits = self._verify_probe_lanes(
-            [bstart + lane for lane in lanes[lanes >= 0].tolist()])
+            [bstart + lane for lane in lanes[lanes >= 0].tolist()], unit)
         hits.extend(self._tile_hits(tiles, unit))
         return hits
 

@@ -75,11 +75,17 @@ SPAN_NAMES = ("lease", "rpc", "warmup", "sweep", "hit_verify",
 #: before its first unit: hash-file parse (cli._setup_job), a bulk
 #: list's table build and upload (MaskWorkerBase._setup_probe).  A
 #: unit passes the others in this order; ``wait`` and ``decode`` open
-#: inside ``resolve``.  A station is a unit or a dispatch, never a lane
-#: or a batch of a fused program: a span each would cost what it
-#: measures.
+#: inside ``resolve``.  The host's verification opens its own inside
+#: ``decode`` and ``verify`` only: ``readback`` (the unit's hit buffers
+#: to host arrays), ``reprobe`` (collided tiles' re-probes enqueued),
+#: ``reprobe_wait`` (the host blocked on them) and ``oracle`` (every
+#: call into the CPU oracle).  None opens inside ``lease``, ``submit``
+#: or ``complete``, whose idle the benchmark reads as the dispatch's.
+#: A station is a unit or a dispatch, never a lane or a batch of a
+#: fused program: a span each would cost what it measures.
 STATIONS = ("targets", "lease", "submit", "resolve", "wait",
-            "decode", "verify", "complete")
+            "decode", "readback", "reprobe", "reprobe_wait", "oracle",
+            "verify", "complete")
 _STATION_LABELS = {name: "dprf:" + name for name in STATIONS}
 
 #: suffix appended to a session journal path for its span stream

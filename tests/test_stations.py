@@ -96,7 +96,7 @@ def test_a_station_left_by_an_exception_is_counted_and_closed(clock):
 def test_an_undeclared_station_name_is_refused(name):
     """`probe`: a unit is swept one way, so no station stands for a
     second."""
-    assert len(STATIONS) == 8
+    assert len(STATIONS) == 12
     with pytest.raises(KeyError):
         recorder(enabled=True).station(name)
 
@@ -295,11 +295,14 @@ def test_self_seconds_sum_to_the_loops_elapsed(fam):
     assert 0.95 * result.elapsed <= named <= result.elapsed
     units = -(-gen.keyspace // unit_size)
     # once a unit; `decode` and `verify` where a unit reported: one
-    # did.  (`lease` also when it finds nothing left to hand out.)
+    # did.  `oracle` once a call into it: the worker's calls for maybe
+    # lanes, and the hit `verify` re-hashes.  (`lease` also when it
+    # finds nothing left to hand out.)
+    batches = getattr(worker, "verify_counts", {}).get("batches", 0)
     want_passed = {"submit": units, "resolve": units, "verify": 1,
-                   "complete": units}
+                   "oracle": 1 + batches, "complete": units}
     if fam.shapes:
-        want_passed.update(wait=units, decode=1)
+        want_passed.update(wait=units, decode=1, readback=1)
     assert {name: n for name, (n, _) in table.items()
             if n and name != "lease"} == want_passed
     assert table["lease"][0] >= units
@@ -335,10 +338,13 @@ def test_the_ran_line_carries_host_by_station(tmp_path, capsys,
     assert PLANT.decode() in out
     assert ran["worker"] == fam.worker
     host = dict(f.split(":") for f in ran["host"].split(","))
-    # `targets` is the job's own, open around the hash file's parse
+    # `targets` is the job's own, open around the hash file's parse;
+    # one target: no maybe lane, no collided tile to re-probe.  The
+    # oracle re-hashes a device's hit; a CPU job's hit is its own
     assert set(host) == {"targets", "lease", "submit", "resolve",
                          "verify", "complete"} | (
-                             {"wait", "decode"} if fam.shapes else set())
+                             {"wait", "decode", "readback", "oracle"}
+                             if fam.shapes else set())
     assert list(host) == [s for s in STATIONS if s in host]
     assert all(re.fullmatch(r"\d+\.\d{3}", v) for v in host.values())
     assert float(host["submit"]) > 0
@@ -384,16 +390,22 @@ def test_the_unit_id_rides_every_span_but_lease(tmp_path, capsys, fam):
                            for e in line.events
                            if e.name.startswith("dprf:")]
     names = {e[0] for e in events}
+    device_only = ("wait", "decode", "readback", "oracle")
     assert names == {"dprf:" + s for s in STATIONS
-                     if fam.shapes or s not in ("wait", "decode")}
+                     if s not in ("reprobe", "reprobe_wait")
+                     and (fam.shapes or s not in device_only)}
     for name, _, _, unit in events:
         assert (unit is None) == (name in ("dprf:lease",
                                            "dprf:targets")), name
     resolves = [e for e in events if e[0] == "dprf:resolve"]
+    inside = {"dprf:wait": ("dprf:resolve",),
+              "dprf:decode": ("dprf:resolve",),
+              "dprf:readback": ("dprf:decode",),
+              "dprf:oracle": ("dprf:decode", "dprf:verify")}
     for name, s, e, unit in events:
-        if name in ("dprf:wait", "dprf:decode"):
-            assert any(r[1] <= s and e <= r[2] and r[3] == unit
-                       for r in resolves)
+        if name in inside:
+            assert any(o[0] in inside[name] and o[1] <= s and e <= o[2]
+                       and o[3] == unit for o in events), name
     # a unit or a dispatch each, never a batch: every unit passes
     # `submit`, `resolve`, `complete` (and `wait`, where it is pending)
     # once, under its own id; the plant lies in the last, which alone
@@ -404,7 +416,8 @@ def test_the_unit_id_rides_every_span_but_lease(tmp_path, capsys, fam):
                                           else ()):
         assert sorted(e[3] for e in events
                       if e[0] == "dprf:" + once) == units, once
-    for last in ("verify",) + (("decode",) if fam.shapes else ()):
+    for last in ("verify",) + (("decode", "readback", "oracle")
+                               if fam.shapes else ()):
         assert [e[3] for e in events
                 if e[0] == "dprf:" + last] == units[-1:], last
 

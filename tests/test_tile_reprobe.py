@@ -327,7 +327,7 @@ def case_confirmed_lanes_skip_the_oracle():
     # maybes, one slot unused
     lanes = np.array([10, -1, 500, 7, 1000, 9])
     tpos = np.array([pos[2], 0, n, n + 1, pos[4], n])
-    hits = w._decode_lanes(base, lanes, tpos)
+    hits = w._decode_lanes(base, lanes, tpos, WorkUnit(0, base, TILE))
     assert hits == [Hit(i, PLANTS[i], gen.candidate(PLANTS[i]))
                     for i in (2, 4, 3)]
     assert oracle.calls == [3]
@@ -347,10 +347,11 @@ def case_verifier_keeps_lane_order():
     exact = {h.cand_index: h for h in
              CpuWorker(get_engine("ntlm", device="cpu"), gen, targets)
              .process(WorkUnit(0, 0, gen.keyspace))}
-    assert w._verify_probe_lanes(gidxs) == \
+    unit = WorkUnit(0, 0, gen.keyspace)
+    assert w._verify_probe_lanes(gidxs, unit) == \
         [exact[g] for g in gidxs if g in exact]
     assert oracle.calls == [len(gidxs)]
-    assert w._verify_probe_lanes([]) == [] and len(oracle.calls) == 1
+    assert w._verify_probe_lanes([], unit) == [] and len(oracle.calls) == 1
 
 
 CASES = {name[len("case_"):]: fn for name, fn in sorted(globals().items())
@@ -360,6 +361,118 @@ CASES = {name[len("case_"):]: fn for name, fn in sorted(globals().items())
 @pytest.mark.parametrize("case", sorted(CASES))
 def test_tile_reprobe(case):
     CASES[case]()
+
+
+#: the host's verification, opened inside `decode` and `verify` only
+VERIFY_STATIONS = ("readback", "reprobe", "reprobe_wait", "oracle")
+
+
+@pytest.mark.parametrize("chips", [1, 2])
+def test_a_list_job_names_its_verification(monkeypatch, chips):
+    """A list job through `Coordinator.run`, on one chip and on a mesh:
+    the host's verification opens its stations, each as often as the
+    work it names happens, inside `decode` or `verify` and never inside
+    `lease`, `submit` or `complete` (whose idle the benchmark reads as
+    the dispatch's).  The clock the stations read moves only in the
+    oracle (a second a call) and in a device readback (a quarter), so
+    what they hold is exactly not `decode`'s or `verify`'s own."""
+    import contextlib
+    import jax
+    from dprf_tpu.runtime.coordinator import Coordinator, JobSpec
+    from dprf_tpu.runtime.dispatcher import Dispatcher
+    from dprf_tpu.telemetry import trace as trace_mod
+    from dprf_tpu.telemetry.registry import MetricsRegistry
+    clock = [0.0]
+    monkeypatch.setattr(trace_mod, "_perf", lambda: clock[0])
+
+    class TickingOracle(CountingOracle):
+        def hash_batch(self, cands, **kw):
+            clock[0] += 1.0
+            return super().hash_batch(cands, **kw)
+
+        def verify(self, plain, target):
+            clock[0] += 1.0
+            return self._inner.verify(plain, target)
+
+    real_get = jax.device_get
+
+    def ticking_get(x):
+        clock[0] += 0.25
+        return real_get(x)
+
+    monkeypatch.setattr(jax, "device_get", ticking_get)
+    opened, stack = [], []
+    real_station = trace_mod.TraceRecorder.station
+
+    def spy(self, name, unit=None):
+        ctx = real_station(self, name, unit)
+
+        @contextlib.contextmanager
+        def held():
+            opened.append((name, tuple(stack)))
+            stack.append(name)
+            try:
+                with ctx:
+                    yield
+            finally:
+                stack.pop()
+        return held()
+
+    monkeypatch.setattr(trace_mod.TraceRecorder, "station", spy)
+    gen = MaskGenerator(MASK)
+    targets = _targets("ntlm", gen, PLANTS)
+    oracle = TickingOracle(get_engine("ntlm", device="cpu"))
+    if chips == 1:
+        w = PallasMaskWorker(get_engine("ntlm", device="jax"), gen,
+                             targets, batch=BATCH, hit_capacity=16,
+                             oracle=oracle, interpret=True, sub=SUB)
+    else:
+        w = ShardedMaskWorker(
+            get_engine("ntlm", device="jax"), gen, targets,
+            make_mesh(chips), batch_per_device=BATCH // chips,
+            hit_capacity=16, oracle=oracle,
+            kernel={"interpret": True, "sub": SUB})
+    w.warmup()
+    with_tiles = []
+    real_tile_hits = w._tile_hits
+
+    def tile_hits(pending, unit):
+        with_tiles.append(bool(pending))
+        return real_tile_hits(pending, unit)
+
+    w._tile_hits = tile_hits
+    rec, reg = trace_mod.get_tracer(), MetricsRegistry()
+    disp = Dispatcher(gen.keyspace, 8 * BATCH, registry=reg, recorder=rec)
+    spec = JobSpec(engine="ntlm", device="jax", attack="mask",
+                   attack_arg=MASK, keyspace=gen.keyspace,
+                   fingerprint="verify-stations")
+    before = rec.station_table()
+    result = Coordinator(spec, targets, disp, w, registry=reg,
+                         recorder=rec, oracle=oracle).run()
+    table = {name: (n - before.get(name, (0, 0.0))[0],
+                    s - before.get(name, (0, 0.0))[1])
+             for name, (n, s) in rec.station_table().items()}
+    assert result.exhausted and len(result.found) == len(PLANTS)
+    assert w.verify_counts["host_tiles"] == 0
+    # one call into the oracle a station: the worker's maybe batches
+    # and one re-hash a hit
+    calls = w.verify_counts["batches"] + len(PLANTS)
+    assert table["oracle"] == (calls, pytest.approx(1.0 * calls))
+    # every unit reported: one readback each
+    decoded = table["decode"][0]
+    assert decoded == 2
+    assert table["readback"] == (decoded, pytest.approx(0.25 * decoded))
+    tiles = sum(with_tiles)
+    assert tiles >= 1 and len(with_tiles) >= tiles
+    assert table["reprobe"][0] == tiles
+    assert table["reprobe_wait"] == (tiles, pytest.approx(0.25 * tiles))
+    assert table["decode"][1] == pytest.approx(0.0)
+    assert table["verify"][1] == pytest.approx(0.0)
+    for name, outer in opened:
+        if name in VERIFY_STATIONS:
+            assert {"decode", "verify"} & set(outer), (name, outer)
+            assert not {"lease", "submit", "complete"} & set(outer), \
+                (name, outer)
 
 
 def test_the_ran_line_carries_verify(tmp_path, capsys, monkeypatch):
