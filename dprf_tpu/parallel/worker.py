@@ -250,19 +250,18 @@ class ShardedMaskWorker(_ShardedSuperstepMixin, MaskWorkerBase):
         # kernel multi-target compute: payload n_targets + 1 marks a
         # COLLIDED tile (2+ probe survivors, one reportable lane) by
         # its first lane -- re-probe exactly that tile for its
-        # survivors (dispatched first, read back last: the other
-        # lanes are verified while the re-probes wait on the device)
+        # survivors (dispatched first, read back with the unit's,
+        # PendingUnit.resolve_tiles: the other lanes are verified
+        # while the re-probes run)
         tile = getattr(self.step, "tile", 0) if self.multi else 0
         rescan = (tpos_np == len(self.targets) + 1) & (lanes_np >= 0) \
             if tile else np.zeros_like(lanes_np, bool)
-        tiles = self._reprobe_tiles(
+        self._reprobe_tiles(
             [bstart + lane for lane in lanes_np[rescan].tolist()], unit)
         # every shard's lanes are window-relative: the window's maybes,
         # all shards together, go to the oracle in one call
-        hits = self._decode_lanes(bstart, np.where(rescan, -1, lanes_np),
+        return self._decode_lanes(bstart, np.where(rescan, -1, lanes_np),
                                   tpos_np, unit)
-        hits.extend(self._tile_hits(tiles, unit))
-        return hits
 
 
 class ShardedCombinatorWorker(ShardedMaskWorker):

@@ -82,7 +82,7 @@ def restore_hits_into(found: dict, hits: list) -> None:
 #: `dprf check` retrace analyzer: loops in these functions drive the
 #: device per work unit -- host syncs and shape-varying jit calls
 #: inside them are silent perf bugs the compile cache can't see.
-HOT_PATHS = ("Coordinator.run",)
+HOT_PATHS = ("Coordinator.run", "Coordinator._refill")
 
 
 class Coordinator:
@@ -207,6 +207,40 @@ class Coordinator:
             for hit in found:
                 self._record(hit, unit.unit_id)   # oracle-produced
 
+    def _refill(self, pipeline) -> int:
+        """Lease and submit until the pipeline is full, the dispatcher
+        is done or a lease comes back empty (a dispatcher may hold
+        leases back while units are in flight); returns the units
+        submitted."""
+        submitted = 0
+        while not pipeline.full and not self.dispatcher.done():
+            unit = self.dispatcher.lease()
+            if unit is None:
+                break
+            if self._ensure_warm is not None:
+                # join the background compile before the first step
+                # dispatch (submitting mid-compile would race the jit
+                # tracer against itself)
+                self._ensure_warm()
+            if self._warm_pending:
+                # trace the overlapped compile at its REAL cost
+                # (compile_seconds), parented onto the first lease so
+                # the cold start is legible per unit
+                self._warm_pending = False
+                warm_s = getattr(self.worker, "compile_seconds", None)
+                ctx = self.dispatcher.trace_context(unit.unit_id)
+                if warm_s is not None:
+                    self.tracer.record(
+                        "warmup", dur=float(warm_s),
+                        trace=ctx[0] if ctx else None,
+                        parent=ctx[1] if ctx else None,
+                        proc="local", engine=self.spec.engine,
+                        cache=getattr(self.worker, "compile_cache", None),
+                        overlapped=True)
+            pipeline.submit(unit)
+            submitted += 1
+        return submitted
+
     def run(self) -> JobResult:
         from dprf_tpu.runtime.worker import UnitPipeline, pipeline_depth
 
@@ -221,7 +255,8 @@ class Coordinator:
         warmup_async = getattr(self.worker, "warmup_async", None)
         if warmup_async is not None:
             warmup_async()
-        ensure_warm = getattr(self.worker, "ensure_warm", None)
+        self._ensure_warm = getattr(self.worker, "ensure_warm", None)
+        self._warm_pending = self._ensure_warm is not None
         if self.session is not None:
             self.session.open(self.spec.as_dict(),
                               default_job=self.dispatcher.job_id)
@@ -231,7 +266,6 @@ class Coordinator:
         # tail's compute.
         pipeline = UnitPipeline(self.worker,
                                 pipeline_depth(self.PIPELINE_DEPTH))
-        warm_pending = ensure_warm is not None
         t_last_resolve = None
         # DPRF_JAX_PROFILE=<dir>: kernel-level drill-down beside the
         # span timeline (no-op when unset; degrades safely if a
@@ -240,33 +274,7 @@ class Coordinator:
         profile.__enter__()
         try:
             while not self._all_found():
-                while not pipeline.full and not self.dispatcher.done():
-                    unit = self.dispatcher.lease()
-                    if unit is None:
-                        break
-                    if ensure_warm is not None:
-                        # join the background compile before the first
-                        # step dispatch (submitting mid-compile would
-                        # race the jit tracer against itself)
-                        ensure_warm()
-                    if warm_pending:
-                        # trace the overlapped compile at its REAL cost
-                        # (compile_seconds), parented onto the first
-                        # lease so the cold start is legible per unit
-                        warm_pending = False
-                        warm_s = getattr(self.worker, "compile_seconds",
-                                         None)
-                        ctx = self.dispatcher.trace_context(unit.unit_id)
-                        if warm_s is not None:
-                            self.tracer.record(
-                                "warmup", dur=float(warm_s),
-                                trace=ctx[0] if ctx else None,
-                                parent=ctx[1] if ctx else None,
-                                proc="local", engine=self.spec.engine,
-                                cache=getattr(self.worker,
-                                              "compile_cache", None),
-                                overlapped=True)
-                    pipeline.submit(unit)
+                self._refill(pipeline)
                 if not len(pipeline):
                     if self.dispatcher.done() or \
                             self.dispatcher.outstanding_count() == 0:
@@ -276,7 +284,16 @@ class Coordinator:
                 unit, p, t_submit, _ = pipeline.pop()
                 ctx = self.dispatcher.trace_context(unit.unit_id)
                 with self.tracer.station("resolve", unit=unit.unit_id):
-                    hits = p.resolve()
+                    hits = getattr(p, "resolve_head", p.resolve)()
+                if getattr(p, "tiles", None):
+                    # the unit's re-probes run behind the queued
+                    # unit's program: refill before waiting for them,
+                    # so the device has a program behind them while
+                    # the host reads them back and verifies their lanes
+                    ahead = self._refill(pipeline) > 0
+                    with self.tracer.station("resolve",
+                                             unit=unit.unit_id):
+                        hits.extend(p.resolve_tiles(ahead))
                 now_resolve = time.monotonic()
                 unit_s = now_resolve - t_submit
                 # inter-completion interval: the loop's true drain

@@ -39,19 +39,39 @@ class PendingUnit:
 
     Callers that hold a PendingUnit while submitting the NEXT unit
     overlap the flag's readback with that unit's compute (see
-    Coordinator.run / bench.run_config)."""
+    Coordinator.run / bench.run_config).
 
-    __slots__ = ("worker", "unit", "queued", "flag")
+    ``resolve()`` is two steps back to back, which a caller may split:
+    ``resolve_head()`` decodes the unit and dispatches the re-probes of
+    its collided tiles, never blocking on them; ``resolve_tiles()``
+    reads them back.  Between the two, ``tiles`` is non-empty, and
+    Coordinator.run submits the next unit there, so the re-probes run
+    ahead of that unit's program instead of in front of an empty
+    device."""
+
+    __slots__ = ("worker", "unit", "queued", "flag", "tiles")
 
     def __init__(self, worker, unit, queued, flag):
         self.worker = worker
         self.unit = unit
         self.queued = queued
         self.flag = flag
+        #: the re-probes resolve_head dispatched and resolve_tiles
+        #: reads back: one list of (start, end, output) a decoded
+        #: window that had collided tiles
+        self.tiles: list = []
         for kind, _, _ in queued:
             count_dispatches(worker, kind)
 
     def resolve(self) -> list["Hit"]:
+        hits = self.resolve_head()
+        hits.extend(self.resolve_tiles())
+        return hits
+
+    def resolve_head(self) -> list["Hit"]:
+        """Wait for the flag, read back and decode every dispatch:
+        the hits of every lane but those of collided tiles, whose
+        re-probes go out on the device and into ``tiles``."""
         if self.flag is None:
             return []
         tracer, uid = get_tracer(), self.unit.unit_id
@@ -61,15 +81,33 @@ class PendingUnit:
             return []
         import jax
         hits: list[Hit] = []
+        w = self.worker
         with tracer.station("decode", unit=uid):
             # every dispatch's hit buffers in one transfer: the
             # decoders read host arrays, a fused program's rows too
             with tracer.station("readback", unit=uid):
                 queued = jax.device_get(self.queued)
-            for kind, start, result in queued:
-                hits.extend(self.worker._decode_queued(
-                    kind, start, result, self.unit))
+            w._reprobes = self.tiles
+            try:
+                for kind, start, result in queued:
+                    hits.extend(w._decode_queued(kind, start, result,
+                                                 self.unit))
+            finally:
+                w._reprobes = None
         return hits
+
+    def resolve_tiles(self, ahead: bool = False) -> list["Hit"]:
+        """Read back the re-probes resolve_head dispatched and verify
+        their lanes.  ``ahead``: the next unit was submitted in
+        between, which `verify=` counts under ``ahead``."""
+        tiles, self.tiles = self.tiles, []
+        if not tiles:
+            return []
+        if ahead:
+            counts = self.worker.verify_counts
+            counts["ahead"] = counts.get("ahead", 0) + 1
+        with get_tracer().station("decode", unit=self.unit.unit_id):
+            return self.worker._tile_hits(tiles, self.unit)
 
 
 def submit_or_process(worker, unit) -> "PendingUnit":
@@ -412,6 +450,9 @@ class MaskWorkerBase:
     #: None where the step has none (single target, XLA steps,
     #: pallas_ext steps): collided tiles are then rescanned on the host
     _reprobe = None
+    #: where _reprobe_tiles hands a window's re-probes while a
+    #: PendingUnit decodes (PendingUnit.resolve_head); None otherwise
+    _reprobes = None
 
     def _setup_targets(self, engine, gen, targets: Sequence[Target],
                        hit_capacity: int, oracle: Optional[HashEngine],
@@ -919,16 +960,18 @@ class MaskWorkerBase:
                 self.engine.name, self.gen, twords, sub,
                 self.TILE_LANES, probe_fp)
 
-    def _reprobe_tiles(self, starts, unit: WorkUnit) -> list:
+    def _reprobe_tiles(self, starts, unit: WorkUnit) -> None:
         """Dispatch the device re-probe of each collided tile (a tile
         of self._tile candidates from `start`, clipped to the unit)
-        and return the pending entries for _tile_hits.  Enqueue only:
-        the caller verifies its single maybes while these wait behind
-        the next unit's program."""
+        and hand the pending entries to the unit being decoded
+        (PendingUnit.resolve_head).  Enqueue only: they are read back
+        by PendingUnit.resolve_tiles, after the window's single maybes
+        are verified and, in Coordinator.run, after the next unit is
+        submitted."""
         import jax.numpy as jnp
-        pending = []
         if not starts:
-            return pending
+            return
+        pending = []
         with get_tracer().station("reprobe", unit=unit.unit_id):
             for start in starts:
                 end = min(start + self._tile, unit.end)
@@ -945,41 +988,41 @@ class MaskWorkerBase:
                                     dtype=jnp.int32),
                         jnp.int32(end - start))
                 pending.append((start, end, out))
-        return pending
+        if pending:
+            self._reprobes.append(pending)
 
-    def _tile_hits(self, pending: list, unit: WorkUnit) -> list[Hit]:
-        """Read the re-probes back: the maybe lanes of every collided
-        tile go to the verifier together, in one oracle call.  A lane
-        that fails the probe bitmap cannot be a target (the bitmap has
-        no false negatives), so that is the tile's exact answer.  A
-        re-probe that disagrees with the kernel (fewer than the two
-        lanes that made the tile collided) or overflowed its buffer,
-        and a step with no re-probe, take the exact host rescan of the
-        tile."""
+    def _tile_hits(self, tiles: list, unit: WorkUnit) -> list[Hit]:
+        """Read the re-probes back, in ONE transfer for the whole unit:
+        the maybe lanes of a window's collided tiles go to the verifier
+        together, in one oracle call a window.  A lane that fails the
+        probe bitmap cannot be a target (the bitmap has no false
+        negatives), so that is the tile's exact answer.  A re-probe
+        that disagrees with the kernel (fewer than the two lanes that
+        made the tile collided) or overflowed its buffer, and a step
+        with no re-probe, take the exact host rescan of the tile."""
         import jax
-        if not pending:
-            return []
         tracer = get_tracer()
         hits: list[Hit] = []
-        maybes: list[int] = []
-        # one readback for all of them: the device has nothing queued
-        # behind the last re-probe until this unit is finished
         with tracer.station("reprobe_wait", unit=unit.unit_id):
-            outs = jax.device_get([out for _, _, out in pending])
-        for (start, end, _), out in zip(pending, outs):
-            if out is not None and 2 <= out[0] <= out[1].shape[0]:
-                self.verify_counts["tiles"] += 1
-                lanes = np.asarray(out[1])
-                maybes.extend(start + lane
-                              for lane in lanes[lanes >= 0].tolist())
-                continue
-            self.verify_counts["host_tiles"] += 1
-            coverage.note("rescan", start, end, unit=unit.unit_id,
-                          kind="host")
-            with tracer.station("oracle", unit=unit.unit_id):
-                hits.extend(CpuWorker(self.oracle, self.gen, self.targets)
-                            .process(WorkUnit(-1, start, end - start)))
-        hits.extend(self._verify_probe_lanes(maybes, unit))
+            outs = jax.device_get([[out for _, _, out in window]
+                                   for window in tiles])
+        for window, window_outs in zip(tiles, outs):
+            maybes: list[int] = []
+            for (start, end, _), out in zip(window, window_outs):
+                if out is not None and 2 <= out[0] <= out[1].shape[0]:
+                    self.verify_counts["tiles"] += 1
+                    lanes = np.asarray(out[1])
+                    maybes.extend(start + lane
+                                  for lane in lanes[lanes >= 0].tolist())
+                    continue
+                self.verify_counts["host_tiles"] += 1
+                coverage.note("rescan", start, end, unit=unit.unit_id,
+                              kind="host")
+                with tracer.station("oracle", unit=unit.unit_id):
+                    hits.extend(CpuWorker(self.oracle, self.gen,
+                                          self.targets)
+                                .process(WorkUnit(-1, start, end - start)))
+            hits.extend(self._verify_probe_lanes(maybes, unit))
         return hits
 
     def _rescan(self, bstart: int, unit: WorkUnit,
@@ -1473,19 +1516,17 @@ class PallasMaskWorker(MaskWorkerBase):
             if window > self.stride:
                 return self._redrive_wide(bstart, window, unit)
             return self._rescan(bstart, unit, window)  # pathological
-        # the re-probes go out first and are read back last: they
-        # queue behind the next unit's program, and the single maybes
-        # are verified inside that wait
-        tiles = self._reprobe_tiles(
+        # the re-probes go out first and are read back with the
+        # unit's (PendingUnit.resolve_tiles): the single maybes are
+        # verified while they run
+        self._reprobe_tiles(
             [bstart + int(t) * self._tile for t in np.asarray(ctiles)
              if t >= 0], unit)
         # one oracle call verifies the window's probe maybes exactly
         # (and resolves their target indices); false positives drop
         lanes = np.asarray(lanes)
-        hits = self._verify_probe_lanes(
+        return self._verify_probe_lanes(
             [bstart + lane for lane in lanes[lanes >= 0].tolist()], unit)
-        hits.extend(self._tile_hits(tiles, unit))
-        return hits
 
 
 class DeviceCombinatorWorker(MaskWorkerBase):

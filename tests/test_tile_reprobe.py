@@ -287,27 +287,36 @@ def case_mesh_verifies_a_window_in_one_call():
         get_engine("ntlm", device="jax"), gen, targets, make_mesh(4),
         batch_per_device=TILE, hit_capacity=16, oracle=oracle,
         kernel={"interpret": True, "sub": SUB, "probe_fp": LOOSE_FP})
-    windows = []
-    real = w._decode_queued
+    windows, tiled = [], []
+    real, real_tile_hits = w._decode_queued, w._tile_hits
 
     def spy(kind, start, result, unit):
-        calls, tiles = len(oracle.calls), w.verify_counts["tiles"]
+        calls, groups = len(oracle.calls), len(w._reprobes)
         out = real(kind, start, result, unit)
         _, _, lanes, tpos = (np.asarray(a) for a in result)
         single = (lanes >= 0) & (tpos != len(targets) + 1)
         windows.append((len(oracle.calls) - calls,
                         int(single.any(axis=1).sum()),
-                        w.verify_counts["tiles"] - tiles))
+                        len(w._reprobes) - groups))
         return out
 
-    w._decode_queued = spy
+    def tile_hits(tiles, unit):
+        calls = len(oracle.calls)
+        out = real_tile_hits(tiles, unit)
+        tiled.append((len(oracle.calls) - calls, len(tiles)))
+        return out
+
+    w._decode_queued, w._tile_hits = spy, tile_hits
     unit = WorkUnit(0, 0, gen.keyspace)
     assert _hits(w, unit) == _cpu_hits("ntlm", gen, targets, unit)
     assert w.verify_counts["host_tiles"] == 0
-    # (oracle calls, shards with single maybes, re-probed tiles)
-    assert all(calls == (shards > 0) + (tiles > 0)
-               for calls, shards, tiles in windows), windows
+    # (oracle calls, shards with single maybes, windows of re-probed
+    # tiles): the singles while decoding, the tiles' lanes one call a
+    # window once the unit's re-probes are read back
+    assert all(calls == (shards > 0) and groups <= 1
+               for calls, shards, groups in windows), windows
     assert max(shards for _, shards, _ in windows) >= 2, windows
+    assert tiled == [(sum(g for _, _, g in windows),) * 2], tiled
     assert len(oracle.calls) == w.verify_counts["batches"]
     assert sum(oracle.calls) == w.verify_counts["lanes"] > len(PLANTS)
     said = re.fullmatch(r"lanes:(\d+),batches:(\d+),tiles:\d+,host_tiles:0",
@@ -367,6 +376,32 @@ def test_tile_reprobe(case):
 VERIFY_STATIONS = ("readback", "reprobe", "reprobe_wait", "oracle")
 
 
+def _spy_stations(monkeypatch):
+    """Every station opened from now on, in order: (name, the names
+    of the stations open around it, unit id)."""
+    import contextlib
+    from dprf_tpu.telemetry import trace as trace_mod
+    opened, stack = [], []
+    real_station = trace_mod.TraceRecorder.station
+
+    def spy(self, name, unit=None):
+        ctx = real_station(self, name, unit)
+
+        @contextlib.contextmanager
+        def held():
+            opened.append((name, tuple(stack), unit))
+            stack.append(name)
+            try:
+                with ctx:
+                    yield
+            finally:
+                stack.pop()
+        return held()
+
+    monkeypatch.setattr(trace_mod.TraceRecorder, "station", spy)
+    return opened
+
+
 @pytest.mark.parametrize("chips", [1, 2])
 def test_a_list_job_names_its_verification(monkeypatch, chips):
     """A list job through `Coordinator.run`, on one chip and on a mesh:
@@ -376,7 +411,6 @@ def test_a_list_job_names_its_verification(monkeypatch, chips):
     the dispatch's).  The clock the stations read moves only in the
     oracle (a second a call) and in a device readback (a quarter), so
     what they hold is exactly not `decode`'s or `verify`'s own."""
-    import contextlib
     import jax
     from dprf_tpu.runtime.coordinator import Coordinator, JobSpec
     from dprf_tpu.runtime.dispatcher import Dispatcher
@@ -401,24 +435,7 @@ def test_a_list_job_names_its_verification(monkeypatch, chips):
         return real_get(x)
 
     monkeypatch.setattr(jax, "device_get", ticking_get)
-    opened, stack = [], []
-    real_station = trace_mod.TraceRecorder.station
-
-    def spy(self, name, unit=None):
-        ctx = real_station(self, name, unit)
-
-        @contextlib.contextmanager
-        def held():
-            opened.append((name, tuple(stack)))
-            stack.append(name)
-            try:
-                with ctx:
-                    yield
-            finally:
-                stack.pop()
-        return held()
-
-    monkeypatch.setattr(trace_mod.TraceRecorder, "station", spy)
+    opened = _spy_stations(monkeypatch)
     gen = MaskGenerator(MASK)
     targets = _targets("ntlm", gen, PLANTS)
     oracle = TickingOracle(get_engine("ntlm", device="cpu"))
@@ -436,9 +453,9 @@ def test_a_list_job_names_its_verification(monkeypatch, chips):
     with_tiles = []
     real_tile_hits = w._tile_hits
 
-    def tile_hits(pending, unit):
-        with_tiles.append(bool(pending))
-        return real_tile_hits(pending, unit)
+    def tile_hits(tiles, unit):
+        with_tiles.append(len(tiles))
+        return real_tile_hits(tiles, unit)
 
     w._tile_hits = tile_hits
     rec, reg = trace_mod.get_tracer(), MetricsRegistry()
@@ -458,50 +475,226 @@ def test_a_list_job_names_its_verification(monkeypatch, chips):
     # and one re-hash a hit
     calls = w.verify_counts["batches"] + len(PLANTS)
     assert table["oracle"] == (calls, pytest.approx(1.0 * calls))
-    # every unit reported: one readback each
-    decoded = table["decode"][0]
-    assert decoded == 2
-    assert table["readback"] == (decoded, pytest.approx(0.25 * decoded))
-    tiles = sum(with_tiles)
-    assert tiles >= 1 and len(with_tiles) >= tiles
-    assert table["reprobe"][0] == tiles
-    assert table["reprobe_wait"] == (tiles, pytest.approx(0.25 * tiles))
+    # every unit reported: one readback each, and a unit with
+    # re-probes one `decode` more, around its one `reprobe_wait`
+    reported = table["readback"][0]
+    assert reported == 2
+    assert table["readback"] == (reported, pytest.approx(0.25 * reported))
+    tiled = len(with_tiles)
+    assert tiled >= 1 and all(with_tiles)
+    assert table["decode"][0] == reported + tiled
+    assert table["reprobe"][0] == sum(with_tiles)
+    assert table["reprobe_wait"] == (tiled, pytest.approx(0.25 * tiled))
     assert table["decode"][1] == pytest.approx(0.0)
     assert table["verify"][1] == pytest.approx(0.0)
-    for name, outer in opened:
+    for name, outer, _ in opened:
         if name in VERIFY_STATIONS:
             assert {"decode", "verify"} & set(outer), (name, outer)
             assert not {"lease", "submit", "complete"} & set(outer), \
                 (name, outer)
 
 
-def test_the_ran_line_carries_verify(tmp_path, capsys, monkeypatch):
-    """`dprf crack` on a small NTLM list with twins in one tile: the
-    job's own `ran` line says the tile was resolved on the device, and
-    `dispatch=` holds only the kinds it held."""
+#: a million candidates, of which a job sweeps the first units of U:
+#: one fused window each, on one chip (8 strides of BATCH) and on two
+#: (8 strides of 2 x TILE)
+MASK6, U = "?d?d?d?d?d?d", 8 * BATCH
+#: twins and a triple in unit 0, twins in unit 2, one single in each
+#: other unit: the units with re-probes have a unit two behind them to
+#: submit, and unit 1 has one and no collided tile
+SPLIT_PLANTS = [5, 6, 3 * TILE + 10, 3 * TILE + 500, 3 * TILE + 1000,
+                U + 100, 2 * U + 5 * TILE + 1, 2 * U + 5 * TILE + 2,
+                3 * U + 7, 4 * U + 99]
+
+
+def _list_worker(chips, gen, targets, oracle):
+    if chips == 1:
+        return PallasMaskWorker(get_engine("ntlm", device="jax"), gen,
+                                targets, batch=BATCH, hit_capacity=16,
+                                oracle=oracle, interpret=True, sub=SUB)
+    return ShardedMaskWorker(
+        get_engine("ntlm", device="jax"), gen, targets, make_mesh(chips),
+        batch_per_device=BATCH // chips, hit_capacity=16, oracle=oracle,
+        kernel={"interpret": True, "sub": SUB})
+
+
+class _OneStep:
+    """A worker whose units resolve in one step, `PendingUnit.resolve`:
+    the loop has no re-probes to refill ahead of, as before it split
+    the resolve."""
+
+    def __init__(self, worker):
+        self._worker = worker
+
+    def submit(self, unit):
+        import types
+        return types.SimpleNamespace(
+            resolve=self._worker.submit(unit).resolve)
+
+    def process(self, unit):
+        return self._worker.process(unit)
+
+    process._submit_based = True
+
+    def __getattr__(self, name):
+        return getattr(self._worker, name)
+
+
+def _list_job(worker, targets, oracle, units=5):
+    """`Coordinator.run` over `units` units of U from index 0: the
+    result, the dispatcher, and (unit id, sorted hit indices) for each
+    unit `_finish_unit` was handed hits of."""
+    from dprf_tpu.runtime.coordinator import Coordinator, JobSpec
+    from dprf_tpu.runtime.dispatcher import Dispatcher
+    disp = Dispatcher(units * U, U)
+    spec = JobSpec(engine="ntlm", device="jax", attack="mask",
+                   attack_arg=MASK6, keyspace=units * U,
+                   fingerprint="split-resolve")
+    coord = Coordinator(spec, targets, disp, worker, oracle=oracle)
+    finished, real = [], coord._finish_unit
+
+    def finish(unit, hits):
+        finished.append((unit.unit_id,
+                         sorted(h.cand_index for h in hits)))
+        real(unit, hits)
+
+    coord._finish_unit = finish
+    return coord.run(), disp, finished
+
+
+def _spy_tile_hits(w):
+    """The ids of the units whose re-probes are read back, in order."""
+    tiled, real = [], w._tile_hits
+
+    def tile_hits(tiles, unit):
+        tiled.append(unit.unit_id)
+        return real(tiles, unit)
+
+    w._tile_hits = tile_hits
+    return tiled
+
+
+@pytest.mark.parametrize("chips", [1, 2])
+def test_the_loop_submits_before_it_waits_for_reprobes(monkeypatch,
+                                                       chips):
+    """A list job through `Coordinator.run`, on one chip and on a mesh:
+    a unit with re-probes is decoded, then the loop leases and submits
+    the unit two behind it at top level (never inside `resolve`,
+    `decode` or `verify`), and only then reads the re-probes back; a
+    unit with no collided tile keeps the loop's order.  The work is
+    the one-step resolve's: the same hits a unit, the same coverage
+    and `verify=` counts, and one `ahead` a unit with re-probes."""
+    monkeypatch.delenv("DPRF_PIPELINE_DEPTH", raising=False)
+    gen = MaskGenerator(MASK6)
+    targets = _targets("ntlm", gen, SPLIT_PLANTS)
+    oracle = get_engine("ntlm", device="cpu")
+    w = _list_worker(chips, gen, targets, oracle)
+    tiled = _spy_tile_hits(w)
+    opened = _spy_stations(monkeypatch)
+    split, _, finished = _list_job(w, targets, oracle)
+    events, tiled_split, counts = opened[:], tiled[:], dict(w.verify_counts)
+    w.verify_counts = dict.fromkeys(("lanes", "batches", "tiles",
+                                     "host_tiles"), 0)
+    one, _, one_finished = _list_job(_OneStep(w), targets, oracle)
+    assert tiled_split == tiled[len(tiled_split):] == [0, 2]
+    assert split.exhausted and len(split.found) == len(SPLIT_PLANTS)
+    assert split.found == one.found
+    assert split.coverage_digest == one.coverage_digest
+    assert sorted(finished) == sorted(one_finished)
+    assert [i for _, hits in finished for i in hits] == SPLIT_PLANTS
+    assert counts.pop("ahead") == len(tiled_split)
+    assert counts == w.verify_counts and counts["tiles"] == 3
+    assert "ahead" not in w.verify_counts
+
+    def at(name, unit):
+        return next(i for i, e in enumerate(events)
+                    if e[0] == name and e[2] == unit)
+
+    for name, outer, _ in events:
+        if name in ("lease", "submit", "complete"):
+            assert not {"resolve", "decode", "verify"} & set(outer), \
+                (name, outer)
+    for n in tiled_split:
+        assert at("decode", n) < at("submit", n + 2) \
+            < at("reprobe_wait", n) < at("complete", n)
+        # the re-probes' wait and their lanes' oracle call in a second
+        # `resolve`, inside a `decode` of their own
+        assert events[at("reprobe_wait", n)][1] == ("resolve", "decode")
+    unit1 = events[at("resolve", 1):at("complete", 1)]
+    assert not [e for e in unit1 if e[0] in ("lease", "submit")]
+    assert at("complete", 1) < at("submit", 3)
+
+
+def test_a_job_ends_on_a_hit_in_a_reprobed_tile():
+    """The last targets are twins in a collided tile of unit 1: the
+    loop has leased and submitted unit 3 before it reads that tile's
+    re-probe back.  The job ends with every hit recorded once; units 2
+    and 3 are left in flight, never completed nor covered."""
+    gen = MaskGenerator(MASK6)
+    plants = [100, U + 5 * TILE + 1, U + 5 * TILE + 2]
+    targets = _targets("ntlm", gen, plants)
+    oracle = get_engine("ntlm", device="cpu")
+    w = _list_worker(1, gen, targets, oracle)
+    tiled = _spy_tile_hits(w)
+    result, disp, finished = _list_job(w, targets, oracle)
+    assert tiled == [1] and w.verify_counts["ahead"] == 1
+    assert result.found == {i: gen.candidate(p)
+                            for i, p in enumerate(plants)}
+    assert finished == [(0, plants[:1]), (1, plants[1:])]
+    assert not result.exhausted and result.tested == 2 * U
+    assert disp.completed_intervals() == [(0, 2 * U)]
+    assert sorted(u["unit"] for u in disp.outstanding_leases()) == [2, 3]
+
+
+#: the twins of a tile in unit 0 and in unit 4 of 2 x BATCH, a single
+#: between
+RAN_PLANTS = (5, 6, 50_000, 70_000, 70_001)
+
+
+def _crack_list(tmp_path, capsys, monkeypatch, unit_size):
+    """`dprf crack` on the NTLM list of RAN_PLANTS, units of
+    `unit_size`: the `ran` line's fields, and what it printed."""
     from dprf_tpu.cli import main as cli_main
     monkeypatch.setenv("DPRF_PALLAS", "1")
     gen = MaskGenerator(MASK)
     cpu = get_engine("ntlm", device="cpu")
-    words = [gen.candidate(i) for i in (5, 6, 50_000, 70_000, 70_001)]
+    words = [gen.candidate(i) for i in RAN_PLANTS]
     hashes = tmp_path / "h.txt"
     hashes.write_text("".join(d.hex() + "\n"
                               for d in cpu.hash_batch(words)))
     rc = cli_main(["crack", "--engine", "ntlm", "-a", "mask", MASK,
                    str(hashes), "--batch", str(BATCH), "--unit-size",
-                   str(8 * BATCH), "--unit-seconds", "0",
+                   str(unit_size), "--unit-seconds", "0",
                    "--no-potfile"])
     cap = capsys.readouterr()
     ran = [ln for ln in cap.err.splitlines() if " ran " in ln]
     assert rc == 0 and len(ran) == 1, cap.err
-    kv = dict(f.split("=", 1) for f in ran[0].split() if "=" in f)
+    for w in words:
+        assert w.decode() in cap.out
+    return dict(f.split("=", 1) for f in ran[0].split() if "=" in f)
+
+
+def test_the_ran_line_carries_verify(tmp_path, capsys, monkeypatch):
+    """`dprf crack` on a small NTLM list with twins in one tile: the
+    job's own `ran` line says the tile was resolved on the device, and
+    `dispatch=` holds only the kinds it held."""
+    kv = _crack_list(tmp_path, capsys, monkeypatch, 8 * BATCH)
     assert kv["worker"] == "PallasMaskWorker"
-    assert re.fullmatch(r"lanes:\d+,batches:\d+,tiles:\d+,host_tiles:0",
-                        kv["verify"])
+    assert re.fullmatch(
+        r"lanes:\d+,batches:\d+,tiles:\d+,host_tiles:0(,ahead:\d+)?",
+        kv["verify"])
     verify = dict(f.split(":") for f in kv["verify"].split(","))
     assert int(verify["tiles"]) == 2 and int(verify["lanes"]) >= 5
     assert 1 <= int(verify["batches"]) < int(verify["lanes"])
     assert {f.split(":")[0] for f in kv["dispatch"].split(",")} <= \
         {"probe", "batch", "loop"}
-    for w in words:
-        assert w.decode() in cap.out
+
+
+def test_the_ran_line_counts_reprobes_read_ahead(tmp_path, capsys,
+                                                 monkeypatch):
+    """Seven units: each of the two with a collided tile has a unit two
+    behind it, submitted before its re-probe is read back, and
+    `verify=` counts both under `ahead`."""
+    kv = _crack_list(tmp_path, capsys, monkeypatch, 2 * BATCH)
+    assert re.fullmatch(
+        r"lanes:\d+,batches:\d+,tiles:2,host_tiles:0,ahead:2",
+        kv["verify"]), kv["verify"]
